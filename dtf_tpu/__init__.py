@@ -32,5 +32,4 @@ except ImportError:
     HAVE_JAX = False
 else:
     HAVE_JAX = True
-    from dtf_tpu import _jax_compat  # noqa: F401  (backfills jax.shard_map etc.)
     from dtf_tpu.core.mesh import MeshConfig, make_mesh, AXIS_DATA, AXIS_SEQ, AXIS_MODEL  # noqa: F401,E501

@@ -45,18 +45,23 @@ from typing import NamedTuple
 
 import jax
 import numpy as np
+from jax.extend import core as jex_core
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dtf_tpu.analysis.findings import Finding
 from dtf_tpu.analysis.jaxpr import _sub_jaxprs
 
-#: collectives that discharge a partial-sum obligation over their axes.
-_REDUCING = frozenset({"psum", "pmean", "psum_scatter", "reduce_scatter"})
+#: collectives that discharge a partial-sum obligation over their axes
+#: (``psum_invariant`` is what ``psum`` binds under a vma-checked
+#: shard_map, and what its transpose rule inserts for a replicated input).
+_REDUCING = frozenset({"psum", "psum_invariant", "pmean", "psum_scatter",
+                       "reduce_scatter"})
 
 #: collectives whose axis names must exist in the enclosing mesh.
 _AXIS_COLLECTIVES = frozenset({
-    "psum", "pmean", "pmax", "pmin", "ppermute", "pbroadcast", "pgather",
-    "all_gather", "all_to_all", "psum_scatter", "reduce_scatter",
+    "psum", "psum_invariant", "pmean", "pmax", "pmin", "ppermute",
+    "pbroadcast", "pvary", "pgather", "all_gather", "all_gather_invariant",
+    "all_to_all", "psum_scatter", "reduce_scatter",
 })
 
 #: primitives through which per-dim sharding tracking survives untouched.
@@ -184,7 +189,7 @@ class _Interp:
 
     # -- record store ------------------------------------------------------
     def _read(self, env: dict, atom) -> _Rec:
-        if not hasattr(atom, "aval") or isinstance(atom, jax.core.Literal):
+        if not hasattr(atom, "aval") or isinstance(atom, jex_core.Literal):
             return _Rec.empty(_rank(atom))
         return env.get(id(atom), _Rec.empty(_rank(atom)))
 
@@ -474,6 +479,14 @@ def _iter_shard_maps(jaxpr):
             yield from _iter_shard_maps(sub)
 
 
+def _spec_axes(spec) -> list[frozenset]:
+    """Mesh axis names per dimension of one shard_map ``PartitionSpec``
+    (an entry is None, a name, or a tuple of names)."""
+    return [frozenset() if entry is None else frozenset(
+        str(n) for n in (entry if isinstance(entry, (tuple, list))
+                         else (entry,))) for entry in spec]
+
+
 def lint_collectives(closed_jaxpr, *, config: str) -> list[Finding]:
     """All shard_map-body soundness checks over one traced step."""
     findings: list[Finding] = []
@@ -493,28 +506,15 @@ def lint_collectives(closed_jaxpr, *, config: str) -> list[Finding]:
         body = getattr(body, "jaxpr", body)
         if body is None or not axis_sizes:
             continue
-        in_names = eqn.params.get("in_names")
         in_recs = []
-        for i, var in enumerate(body.invars):
+        for var, spec in zip(body.invars, eqn.params["in_specs"]):
             rank = _rank(var)
-            dims = [frozenset()] * rank
-            if in_names is not None and i < len(in_names):
-                for d, names in dict(in_names[i]).items():
-                    if d < rank:
-                        dims[d] = frozenset(
-                            str(n) for n in (names if isinstance(
-                                names, (tuple, list)) else (names,)))
+            dims = (_spec_axes(spec) + [frozenset()] * rank)[:rank]
             in_recs.append(_Rec(tuple(dims), frozenset(), frozenset()))
-        out_names = eqn.params.get("out_names")
         interp = _Interp(axis_sizes, report)
         outs = interp.run(body, in_recs)
-        for i, rec in enumerate(outs):
-            out_axes: set = set()
-            if out_names is not None and i < len(out_names):
-                for names in dict(out_names[i]).values():
-                    out_axes.update(
-                        str(n) for n in (names if isinstance(
-                            names, (tuple, list)) else (names,)))
+        for i, (rec, spec) in enumerate(zip(outs, eqn.params["out_specs"])):
+            out_axes = frozenset().union(*_spec_axes(spec))
             offending = rec.partial - rec.ringed - out_axes
             if offending:
                 report(

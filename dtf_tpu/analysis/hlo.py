@@ -53,6 +53,18 @@ _COLLECTIVE_RE = re.compile(
     r"(?P<op>" + "|".join(re.escape(o) for o in COLLECTIVE_OPS) + r")"
     r"(?P<async>-start)?\(")
 
+#: XLA numbers the elements of a long tuple type in comments
+#: (``/*index=5*/``); their ``=`` would end the type slot of the matcher
+#: above, and a combined all-reduce of more than five operands — the
+#: whole gradient reduction, since the all-reduce combiner — went
+#: uncounted. Matchers run on text with the comments taken out.
+_COMMENT_RE = re.compile(r"/\*.*?\*/")
+
+
+def strip_comments(hlo_text: str) -> str:
+    return _COMMENT_RE.sub("", hlo_text)
+
+
 #: dtype tokens are alphanumeric runs (f8e4m3fn, s4, bf16 — not just
 #: letters+digits: the fp8 family interleaves them).
 _SHAPE_RE = re.compile(r"(?P<dtype>[a-z][a-z0-9]*)\[(?P<dims>[0-9,]*)\]")
@@ -96,7 +108,7 @@ def collective_stats(hlo_text: str) -> dict:
     """
     stats = {op: {"count": 0, "bytes": 0} for op in COLLECTIVE_OPS}
     unknown: set[str] = set()
-    for m in _COLLECTIVE_RE.finditer(hlo_text):
+    for m in _COLLECTIVE_RE.finditer(strip_comments(hlo_text)):
         op = m.group("op")
         nbytes, unk = _shape_bytes(m.group("type"))
         stats[op]["count"] += 1

@@ -28,14 +28,15 @@ from __future__ import annotations
 from typing import Any, Callable
 
 import jax
+from jax.extend import core as jex_core
 
 from dtf_tpu.analysis.findings import Finding
 
 #: primitives legal only inside shard_map (axis-env consumers).
 AXIS_PRIMS = frozenset({
-    "psum", "pmean", "pmax", "pmin", "ppermute", "pbroadcast", "pgather",
-    "all_gather", "all_to_all", "psum_scatter", "reduce_scatter",
-    "axis_index",
+    "psum", "psum_invariant", "pmean", "pmax", "pmin", "ppermute",
+    "pbroadcast", "pvary", "pgather", "all_gather", "all_gather_invariant",
+    "all_to_all", "psum_scatter", "reduce_scatter", "axis_index",
 })
 
 #: primitive-name fragments that mean "host round-trip inside the step".
@@ -55,9 +56,9 @@ def _sub_jaxprs(eqn):
     for val in eqn.params.values():
         vals = val if isinstance(val, (tuple, list)) else (val,)
         for v in vals:
-            if isinstance(v, jax.core.ClosedJaxpr):
+            if isinstance(v, jex_core.ClosedJaxpr):
                 yield v.jaxpr
-            elif isinstance(v, jax.core.Jaxpr):
+            elif isinstance(v, jex_core.Jaxpr):
                 yield v
 
 

@@ -28,10 +28,7 @@ class).  This pass closes both holes on the CPU sim:
   must be aliased to an output in the executable
   (``input_output_alias`` in the optimized HLO header) — a donation
   XLA dropped is a ``dropped-donation`` finding.  This turns the BN
-  freeze from a bisected runtime mystery into a CPU-sim lint;
-  :func:`donation_gate` additionally asserts (rather than assumes) the
-  ``_jax_compat.BACKFILLED`` gate in ``core/train.py``: registry
-  programs must donate NOTHING on backfilled jax.
+  freeze from a bisected runtime mystery into a CPU-sim lint.
 - **fit planner** (:func:`fit`): inverts the resident model under a
   per-chip HBM budget — max KV slots and page-pool size for serve
   configs (bf16 AND int8 KV, real-scale ``eval_shape`` pricing, no
@@ -430,30 +427,6 @@ def donation_soundness(config_name: str, lowered, compiled,
     return findings
 
 
-def donation_gate(config_name: str, lowered) -> list[Finding]:
-    """Assert the ``_jax_compat.BACKFILLED`` donation gate.
-
-    On backfilled (pre-0.5) jax a donated executable deserialized from
-    the persistent compile cache drops its aliasing (core/train.py
-    version-gates donation off there).  A registry program that donates
-    anyway means the gate was bypassed — the exact setup of the PR 1 BN
-    freeze, caught here statically instead of by a warm-cache bisect.
-    """
-    from dtf_tpu import _jax_compat as _compat
-
-    if not _compat.BACKFILLED:
-        return []
-    n = sum(donated_flags(lowered))
-    if not n:
-        return []
-    return [Finding(
-        config_name, "memory", "donation-on-backfilled-jax", "error",
-        f"{n} argument leaf/leaves donated on BACKFILLED jax — the "
-        f"core/train.py donation gate was bypassed; donated executables "
-        f"deserialized from the persistent cache drop aliased outputs "
-        f"here (tests/conftest.py note)")]
-
-
 def lint_program(config, view, lowered, compiled,
                  golden_budget: Mapping[str, Any] | None,
                  budget: Mapping[str, Any] | None = None) -> list[Finding]:
@@ -466,7 +439,6 @@ def lint_program(config, view, lowered, compiled,
     findings += state_accounting(config.name, view, compiled)
     findings += donation_soundness(config.name, lowered, compiled,
                                    arg_paths=paths)
-    findings += donation_gate(config.name, lowered)
     return findings
 
 

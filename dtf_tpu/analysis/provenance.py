@@ -1,8 +1,10 @@
 """Source-attributed comms provenance — who introduced each collective.
 
-XLA op metadata (``source_file``/``source_line``) survives lowering into
-the optimized HLO, so every collective the comms-budget fence counts can
-be attributed to the Python line that introduced it. That turns a
+XLA op metadata survives lowering into the optimized HLO — a
+``stack_frame_id`` into the module's own FileNames / FileLocations /
+StackFrames tables (older XLA: inline ``source_file``/``source_line``) —
+so every collective the comms-budget fence counts can be attributed to
+the Python line that introduced it. That turns a
 ``collective-count-drift`` finding from "all-reduce 126→127" into
 "all-reduce +1 at dtf_tpu/core/train.py:396", and gives PR review a
 per-line delta view (``python -m dtf_tpu.analysis --diff``).
@@ -31,6 +33,8 @@ _ANCHORS = ("dtf_tpu", "tests", "scripts")
 
 _META_RE = re.compile(
     r'source_file="(?P<file>[^"]+)"\s+source_line=(?P<line>\d+)')
+_FRAME_RE = re.compile(r"stack_frame_id=(\d+)")
+_TABLE_ROW_RE = re.compile(r"^(\d+) (.*)$")
 
 #: instruction name on the LHS of an HLO line: `%all-reduce.2 = ...` —
 #: the SAME name the profiler stamps into XPlane op events as ``hlo_op``,
@@ -44,6 +48,49 @@ def _rel(path: str) -> str:
         if parts[i] in _ANCHORS:
             return "/".join(parts[i:])
     return parts[-1]
+
+
+def frame_sites(hlo_text: str) -> dict:
+    """``{stack_frame_id: "file:line"}`` from the tables at the head of
+    the module text: a frame names a file location, a location names a
+    file and a line. The frame an op carries is the innermost user frame
+    (jax and flax exclude their own), the site the inline metadata of
+    older XLA named."""
+    tables: dict[str, dict[int, str]] = {}
+    current = None
+    for line in hlo_text.splitlines():
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            current = tables.setdefault(line, {})
+        elif current is not None:
+            row = _TABLE_ROW_RE.match(line)
+            if row:
+                current[int(row.group(1))] = row.group(2)
+            elif line.strip():
+                break          # the first computation: tables are over
+    files = {i: v.strip('"') for i, v in tables.get("FileNames", {}).items()}
+    locs = {}
+    for i, v in tables.get("FileLocations", {}).items():
+        f = re.search(r"file_name_id=(\d+)", v)
+        ln = re.search(r"\bline=(\d+)", v)
+        if f and ln and int(f.group(1)) in files:
+            locs[i] = f"{_rel(files[int(f.group(1))])}:{ln.group(1)}"
+    sites = {}
+    for i, v in tables.get("StackFrames", {}).items():
+        loc = re.search(r"file_location_id=(\d+)", v)
+        if loc and int(loc.group(1)) in locs:
+            sites[i] = locs[int(loc.group(1))]
+    return sites
+
+
+def _site(line: str, frames: Mapping[int, str]) -> str:
+    meta = _META_RE.search(line)
+    if meta:
+        return f"{_rel(meta.group('file'))}:{meta.group('line')}"
+    frame = _FRAME_RE.search(line)
+    if frame:
+        return frames.get(int(frame.group(1)), "<unattributed>")
+    return "<unattributed>"
 
 
 def instruction_sites(hlo_text: str, *, ops=None) -> dict:
@@ -61,8 +108,9 @@ def instruction_sites(hlo_text: str, *, ops=None) -> dict:
     """
     from dtf_tpu.analysis import hlo as hlo_pass
 
+    frames = frame_sites(hlo_text)
     sites: dict[str, dict] = {}
-    for line in hlo_text.splitlines():
+    for line in hlo_pass.strip_comments(hlo_text).splitlines():
         m = hlo_pass._COLLECTIVE_RE.search(line)
         if not m:
             continue
@@ -72,11 +120,9 @@ def instruction_sites(hlo_text: str, *, ops=None) -> dict:
         nm = _INSTR_RE.match(line)
         if nm is None:
             continue
-        meta = _META_RE.search(line)
-        loc = (f"{_rel(meta.group('file'))}:{meta.group('line')}"
-               if meta else "<unattributed>")
         nbytes, _ = hlo_pass._shape_bytes(m.group("type"))
-        sites[nm.group("name")] = {"op": op, "loc": loc, "bytes": nbytes}
+        sites[nm.group("name")] = {"op": op, "loc": _site(line, frames),
+                                   "bytes": nbytes}
     return sites
 
 
@@ -90,18 +136,16 @@ def collective_provenance(hlo_text: str) -> dict:
     """
     from dtf_tpu.analysis import hlo as hlo_pass
 
+    frames = frame_sites(hlo_text)
     prov: dict[str, dict[str, dict]] = {}
-    for line in hlo_text.splitlines():
+    for line in hlo_pass.strip_comments(hlo_text).splitlines():
         m = hlo_pass._COLLECTIVE_RE.search(line)
         if not m:
             continue
         op = m.group("op")
         nbytes, _ = hlo_pass._shape_bytes(m.group("type"))
-        meta = _META_RE.search(line)
-        loc = (f"{_rel(meta.group('file'))}:{meta.group('line')}"
-               if meta else "<unattributed>")
         slot = prov.setdefault(op, {}).setdefault(
-            loc, {"count": 0, "bytes": 0})
+            _site(line, frames), {"count": 0, "bytes": 0})
         slot["count"] += 1
         slot["bytes"] += nbytes
     return prov

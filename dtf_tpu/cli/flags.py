@@ -14,6 +14,8 @@ from typing import NamedTuple
 
 from absl import flags
 
+from dtf_tpu.telemetry.accounting import DEVICE_PEAKS
+
 FLAGS = flags.FLAGS
 
 
@@ -383,16 +385,18 @@ def resolve_grad_shard(FLAGS, mesh, *, blockers=()):
     return True
 
 
-#: v5e HBM per chip; the loss-path picker budgets against a fraction of it
-#: because params + optimizer state + activations share the pool.
-HBM_BYTES_PER_CHIP = 16e9
+#: v5e HBM per chip (the one table of published peaks); the loss-path
+#: picker budgets against a fraction of it because params + optimizer
+#: state + activations share the pool. A planning constant: the tuner
+#: buckets its banked rows by it on machines with no chip at all.
+HBM_BYTES_PER_CHIP = DEVICE_PEAKS["TPU v5 lite"]["hbm_bytes"]
 #: monolithic [B,T,V] f32 logits + their cotangent must fit inside this
 #: fraction of HBM to pick the fast path. Calibrated against the on-chip
-#: map (PERF.md §0c): GPT-2-small b8 s1024 (3.3 GB) fits and runs 9 MFU
+#: map (PERF.md §5): GPT-2-small b8 s1024 (3.3 GB) fits and runs 9 MFU
 #: points faster unchunked; b16 (6.6 GB) is where throughput falls over.
 LOGITS_HBM_FRACTION = 0.25
 #: the token-chunk width the sweep banked as the fast bounded-memory shape
-#: (one full-vocab MXU matmul per block — PERF.md §0c).
+#: (one full-vocab MXU matmul per block — PERF.md §5).
 AUTO_LOSS_CHUNK_TOKENS = 4096
 
 
@@ -414,7 +418,7 @@ def resolve_lm_loss(FLAGS, *, batch: int, seq_len: int, vocab_size: int,
 
     The vocab-chunked loss is a MEMORY lever, not a speed lever: it costs
     ~9 MFU points on GPT and ~5 on BERT versus the monolithic [B,T,V]
-    matmul+CE that XLA fuses (PERF.md §0c). So: when no fused-loss flag
+    matmul+CE that XLA fuses (PERF.md §5). So: when no fused-loss flag
     is set and the full logits plus their cotangent fit comfortably per
     device, keep the monolithic path; when they don't, take the banked
     loss-path winner from the kernel-tune cache
@@ -462,17 +466,17 @@ def resolve_lm_loss(FLAGS, *, batch: int, seq_len: int, vocab_size: int,
             absl_logging.warning(
                 "%s forces a fused LM loss but the monolithic [B,T,V] "
                 "logits fit (est %.2f GB/device of %.0f GB HBM): the "
-                "chunked path costs ~9 GPT MFU points (PERF.md 0c) — "
+                "chunked path costs ~9 GPT MFU points (PERF.md §5) — "
                 "drop the flag to let the HBM estimate pick", which,
                 est / 1e9, hbm_bytes / 1e9)
         elif lchunk and (winner is None or winner.path != "chunk_vocab"):
             absl_logging.warning(
                 "--loss_chunk_vocab forces the measured-slower chunking "
                 "axis (the serialized vocab scan costs ~9 GPT MFU "
-                "points, PERF.md 0c); the banked winner here is %s (%s) "
+                "points, PERF.md §5); the banked winner here is %s (%s) "
                 "— drop the flag to follow it",
                 winner.path if winner else "the token-chunked fused CE",
-                winner.source if winner else "PERF.md 0b chunk-axis "
+                winner.source if winner else "PERF.md §5 chunk-axis "
                 "ordering")
         return LmLossPath(lchunk, tchunk, lpallas, source="explicit")
     if (mesh_shape.get("model", 1) > 1 or mesh_shape.get("pipe", 1) > 1):

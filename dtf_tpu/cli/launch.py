@@ -9,6 +9,7 @@ script a ready (mesh, cluster_info) pair. See SURVEY.md §7 "Hard parts" #1.
 from __future__ import annotations
 
 import logging
+import os
 import sys
 
 import jax
@@ -17,6 +18,59 @@ from dtf_tpu.core import dist
 from dtf_tpu.core.mesh import MeshConfig, make_mesh, mesh_summary
 
 log = logging.getLogger("dtf_tpu")
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache():
+    """Place JAX's persistent compile cache. ``JAX_COMPILATION_CACHE_DIR``
+    decides when it is set (JAX reads it itself; nothing is set in code);
+    otherwise the cache is ``<checkout>/.jax_cache`` — a fixed path,
+    because the path is part of the cache key. Every launcher, bench.py,
+    chip_smoke.py and tests/conftest.py call this, so processes of one
+    run share what they compile."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(_CHECKOUT, ".jax_cache"))
+
+
+def init_backend(backend: str, info=None):
+    """Bring JAX up on the platform ``--backend`` names, or fail.
+
+    ``cpu`` pins the simulated mesh; anything else must be what JAX
+    actually found — a launcher asked for the TPU never trains on a CPU
+    that JAX fell back to. With ``info`` (a multi-worker launch) the
+    distributed bootstrap runs in between, before the first device
+    query, and the possibly-updated info is returned.
+    """
+    enable_compile_cache()
+    if backend == "cpu":
+        # Local-sim path: the test/dev equivalent of a multi-worker cluster.
+        jax.config.update("jax_platforms", "cpu")
+    if info is not None:
+        info = dist.initialize_or_fake(info, backend)
+    platform = jax.devices()[0].platform
+    if platform != backend:
+        raise RuntimeError(
+            f"--backend={backend} but JAX came up on {platform!r} "
+            f"({jax.devices()[0].device_kind}): refusing to run on a "
+            f"device that was not asked for (pass --backend=cpu for the "
+            f"simulated mesh)")
+    return info
+
+
+def device_report() -> dict:
+    """What the process ran on, as JAX reports it, for every result a
+    launcher prints: a number without its device is not a measurement.
+    ``peak_hbm_bytes`` where the backend keeps memory statistics."""
+    dev = jax.devices()[0]
+    out = {"device": {"platform": dev.platform, "kind": dev.device_kind,
+                      "count": len(jax.devices())}}
+    peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
+    if peak is not None:
+        out["peak_hbm_bytes"] = int(peak)
+    return out
 
 
 def setup(FLAGS):
@@ -48,10 +102,7 @@ def setup(FLAGS):
             "--issync=0 (async PS SGD) is not reproduced on the TPU backend: "
             "hogwild updates are an anti-pattern under SPMD. Proceeding with "
             "synchronous aggregation (same convergence, no stale gradients).")
-    if FLAGS.backend == "cpu":
-        # Local-sim path: the test/dev equivalent of a multi-worker cluster.
-        jax.config.update("jax_platforms", "cpu")
-    info = dist.initialize_or_fake(info, FLAGS.backend)
+    info = init_backend(FLAGS.backend, info)
     devices = None
     dph = getattr(FLAGS, "devices_per_host", 0)
     # cpu only (a real chip's devices are what they are): sizes the
@@ -230,7 +281,7 @@ def emit_run_report(tel, info, extra=None):
         return None
     import json
 
-    report = tel.finish(extra)
+    report = tel.finish({**device_report(), **(extra or {})})
     if info.is_chief:
         print(json.dumps(report))
     return report

@@ -19,10 +19,9 @@ that owns all four concerns:
 - **pins** — ``jit_kw`` carries ``in_shardings``/``out_shardings``
   verbatim; the executor adds nothing and removes nothing, so a
   program's compiled layout contract is exactly what its builder wrote.
-- **donation** — ``donate=`` routes through
-  :func:`dtf_tpu.core.train.donation_enabled`, the single version gate
-  the analyzer's memory pass asserts (BACKFILLED jax must never donate:
-  deserialized donated executables drop aliased outputs there).
+- **donation** — ``donate=`` donates ``donate_args`` (the state, by
+  default); the analyzer's memory pass checks every donated leaf is
+  aliased to an output (``dropped-donation``).
 - **step view** — ``abstract_args`` + ``arg_shardings`` register what
   the analysis registry needs: :meth:`Program.lower` with no arguments
   lowers against the registered abstracts, and
@@ -64,13 +63,8 @@ def fenced(name: str, body: Callable, counts: Optional[MutableMapping]):
 
 def donation_argnums(donate: bool, argnums: tuple = (0,)) -> tuple:
     """The donation decision for a program: ``argnums`` when the caller
-    asked AND :func:`dtf_tpu.core.train.donation_enabled` allows it on
-    this jax, else ``()``. The gate itself stays in core/train.py — the
-    analyzer's memory pass asserts it there by name."""
-    # lazy: core/train.py imports this module at module level.
-    from dtf_tpu.core.train import donation_enabled
-
-    return tuple(argnums) if donation_enabled(donate) else ()
+    asked, else ``()``."""
+    return tuple(argnums) if donate else ()
 
 
 class Program:

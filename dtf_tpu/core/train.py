@@ -31,7 +31,6 @@ import optax
 from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dtf_tpu import _jax_compat as _compat
 from dtf_tpu.core import executor
 from dtf_tpu.core import sharding as shd
 from dtf_tpu.core.comms import (batch_sharding, global_norm,
@@ -180,20 +179,6 @@ def abstract_train_state(
     shardings = state_shardings_from_specs(specs, mesh)
     abstract = jax.eval_shape(_full_init(init_fn, tx), rng)
     return abstract, shardings
-
-
-def donation_enabled(donate: bool = True) -> bool:
-    """The ONE donation gate: whether train steps may donate their state.
-
-    On backfilled (pre-0.5) jax a DONATED executable deserialized from
-    the persistent compile cache drops aliased outputs (warm-run BN
-    stats freeze — see tests/conftest.py), so donation is version-gated
-    off there.  Exposed as a hook so the static analyzer's memory pass
-    can ASSERT the gate (``donation-on-backfilled-jax``: a registry
-    program donating anything on backfilled jax means this gate was
-    bypassed) instead of assuming a comment still matches the code.
-    """
-    return donate and not _compat.BACKFILLED
 
 
 def make_train_step(
@@ -407,9 +392,6 @@ def make_train_step(
         # fence pins Trainer.trace_counts["train_step"] at 1 in steady
         # state, the DecodeEngine.trace_counts contract for training.
         step_fn = telemetry.count_traces("train_step", step_fn)
-    # donation is version-gated through donation_enabled() — the
-    # analyzer's memory pass asserts the gate; the executor routes
-    # donate= through it (executor.donation_argnums).
     return executor.program(
         "train_step", step_fn, donate=donate,
         jit_kw=dict(in_shardings=(shardings, batch_sh),

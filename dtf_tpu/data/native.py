@@ -31,10 +31,27 @@ _lib = None
 _lib_lock = threading.Lock()
 
 
+def _stale() -> bool:
+    """Build unless the library is strictly newer than BOTH its source and
+    the Makefile. The library is not committed: a fresh checkout has none,
+    and a copied tree keeps no mtimes worth trusting — its ``-march=native``
+    code would not travel between machines anyway."""
+    if not os.path.exists(_SO_PATH):
+        return True
+    built = os.path.getmtime(_SO_PATH)
+    return any(os.path.getmtime(os.path.join(_NATIVE_DIR, f)) >= built
+               for f in ("dtfio.cpp", "Makefile"))
+
+
 def _build() -> bool:
+    # built under a name of this process's own, then renamed into place:
+    # several test workers may find the library stale at the same moment,
+    # and none may ever load a half-written one
+    tmp = f"libdtfio.{os.getpid()}.tmp.so"
     try:
-        subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                       capture_output=True, text=True)
+        subprocess.run(["make", "-C", _NATIVE_DIR, f"TARGET={tmp}"],
+                       check=True, capture_output=True, text=True)
+        os.replace(os.path.join(_NATIVE_DIR, tmp), _SO_PATH)
         return True
     except (subprocess.CalledProcessError, FileNotFoundError) as e:
         out = getattr(e, "stderr", "")
@@ -47,12 +64,8 @@ def _load():
     with _lib_lock:
         if _lib is not None:
             return _lib
-        src = os.path.join(_NATIVE_DIR, "dtfio.cpp")
-        if not os.path.exists(_SO_PATH) or (
-                os.path.exists(src)
-                and os.path.getmtime(src) > os.path.getmtime(_SO_PATH)):
-            if not _build():
-                return None
+        if _stale() and not _build():
+            return None
         try:
             lib = ctypes.CDLL(_SO_PATH)
         except OSError as e:

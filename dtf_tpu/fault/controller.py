@@ -19,7 +19,7 @@ The two verdicts and their policies (docs/RESILIENCE.md):
 - **run-wedged** — every host process is alive but no step completes: a
   host's stall watchdog flagged its heartbeat ``stalled``, or heartbeats
   went stale, or a launch never produced one. Nothing is gone, something
-  is stuck (dead tunnel, deadlocked collective): dump postmortems
+  is stuck (a hung backend, a deadlocked collective): dump postmortems
   everywhere (the SIGTERM chain does — flight dump first, then the
   checkpoint), kill, relaunch at the SAME size.
 

@@ -100,13 +100,16 @@ class LoggingHook(Hook):
         self.throughput_name = throughput_name
         if peak_flops is None:
             # model_flops_per_step covers the whole global batch, so the
-            # MFU denominator is the MESH's peak, not one chip's
+            # MFU denominator is the MESH's peak, not one chip's. The
+            # per-chip peak is the running device's published one; on the
+            # CPU there is none and no mfu is logged.
             if telemetry is not None:
-                peak_flops = telemetry.peak_flops * telemetry.n_devices
+                chip, n = telemetry.peak_flops, telemetry.n_devices
             else:
-                from dtf_tpu.telemetry.accounting import V5E_PEAK_BF16_FLOPS
+                from dtf_tpu.telemetry.accounting import device_peak_flops
 
-                peak_flops = V5E_PEAK_BF16_FLOPS * jax.device_count()
+                chip, n = device_peak_flops(), jax.device_count()
+            peak_flops = chip * n if chip else None
         self.peak_flops = peak_flops
         self.telemetry = telemetry
         self._t0 = None
@@ -127,7 +130,7 @@ class LoggingHook(Hook):
         scalars["steps_per_sec"] = sps
         if self.tokens_per_step:
             scalars[self.throughput_name] = sps * self.tokens_per_step
-        if self.model_flops_per_step:
+        if self.model_flops_per_step and self.peak_flops:
             scalars["mfu"] = (sps * self.model_flops_per_step
                               / self.peak_flops)
         if self.lr_schedule is not None:

@@ -755,6 +755,11 @@ class CausalSelfAttention(nn.Module):
                 out = att.ring_attention_sharded(q, k, v, self.mesh,
                                                  causal=True)
         elif impl == "flash":
+            # interpret mode (here and at every other `default_backend()
+            # != "tpu"` in models/, ops/ and parallel/) is reachable only
+            # when the CPU was ASKED for: a launcher given --backend=tpu
+            # refuses whatever else JAX came up on (cli/launch.py
+            # init_backend), so the chip path never interprets a kernel.
             out = fa.flash_attention_sharded(
                 q, k, v, self.mesh, causal=True, window=self.window,
                 block_h=cfg.flash_block_h,

@@ -502,13 +502,8 @@ def ring_inventory() -> tuple[RingOp, ...]:
     ]
     # the quantized-payload twins ride the SAME perm fwd and the full-
     # precision rings bwd — registering them holds the dequant-after-
-    # ppermute paths to the identical mirrored-ring invariant. fp8 rings
-    # exist only where the jax has the e4m3 dtype (same feature gate the
-    # resolver demotes through), so the inventory never traces a dtype
-    # the install can't represent.
-    from dtf_tpu.ops import quant
-
-    for qd in ("int8",) + (("fp8",) if quant.fp8_supported() else ()):
+    # ppermute paths to the identical mirrored-ring invariant.
+    for qd in ("int8", "fp8"):
         ops.append(RingOp(
             f"ag_matmul_{qd}",
             (lambda axis_name, x, w, _q=qd:

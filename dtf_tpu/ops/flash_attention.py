@@ -31,7 +31,6 @@ test suite (tests/test_flash_attention.py).
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 from typing import Optional
 
@@ -40,18 +39,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# 512x1024 blocks, picked by the on-chip block-shape sweep (ATTN_BENCH.json
-# block_sweep, v5e, seq 8k causal fwd): 512x1024 ran 1.61 ms vs 512x512's
-# 4.44/5.76 ms, 1024x1024's 2.98 ms and 512x2048's 2.74 ms — with the bwd
-# also fastest (9.05 vs 13.4 ms). History: 128x128 was grid-overhead-bound
-# (~10 TF/s flat, r3); 512x512 fixed that (42-62 TF/s); doubling only the
-# k-extent halves the grid's inner trip count again and keeps the f32
-# score tile at [512,1024] = 2 MB, k/v residents 2x256 KB — far under the
-# ~16 MB VMEM budget. Since the kernel-tune cache landed these are the
-# LAST-RESORT fallback only: block args left at 0 resolve through
-# dtf_tpu.tune.resolver (the banked per-shape winners in
-# KERNEL_TUNE.json, seeded from this very sweep — docs/TUNING.md), and
-# callers can still pin per-shape explicitly.
+# 512x1024 blocks, picked by a block-shape sweep on a v5e in round 5
+# (before PR 1, another JAX; the record is gone, PERF.md §5 keeps the
+# figures): seq 8k causal fwd, 512x1024 ran 1.61 ms vs 512x512's 4.44/5.76
+# ms, 1024x1024's 2.98 ms and 512x2048's 2.74 ms — with the bwd also
+# fastest (9.05 vs 13.4 ms). 128x128 was grid-overhead-bound (~10 TF/s
+# flat); doubling only the k-extent halves the grid's inner trip count and
+# keeps the f32 score tile at [512,1024] = 2 MB, k/v residents 2x256 KB —
+# far under the 16 MiB scoped-VMEM limit. Block args left at 0 resolve
+# through dtf_tpu.tune.resolver first (per-shape winners in
+# KERNEL_TUNE.json — none is measured for flash on the present chip and
+# JAX, so today these defaults are what runs; docs/TUNING.md), and callers
+# can still pin per-shape explicitly.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 1024
 _NEG_INF = float("-inf")
@@ -59,12 +58,7 @@ _STAT_LANES = 128  # scratch stat arrays are [block_q, 128] (TPU lane width)
 
 
 def _compiler_params(dims: tuple[str, ...]):
-    # pre-0.5 jax spells it TPUCompilerParams; same dataclass either way.
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    fields = {f.name for f in dataclasses.fields(cls)}
-    if "dimension_semantics" in fields:
-        return cls(dimension_semantics=dims)
-    return cls()
+    return pltpu.CompilerParams(dimension_semantics=dims)
 
 
 def _positions(i, j, block_q, block_k):
@@ -224,7 +218,7 @@ def _fwd_kernel_hfold(q_ref, k_ref, v_ref, *rest, sm_scale, causal, window,
     """Head-folded forward: the grid's bh dim advances ``block_h`` heads per
     step, so one grid step runs block_h batched [bq,d]x[d,bk] MXU
     contractions back-to-back — amortizing the fixed per-step overhead
-    (PERF.md §3 measured ~1 us/step vs sub-us of matmul work at d=128) by
+    (PERF.md §5 measured ~1 us/step vs sub-us of matmul work at d=128) by
     the fold factor. Separate from :func:`_fwd_kernel` on purpose: the 2-D
     kernel is the on-chip-proven default; this one is opt-in
     (``block_h > 1``) until the block sweep measures it.

@@ -29,8 +29,11 @@ head is bias-free; BERT's MLM path should gather masked positions
 first (``--mlm_gather``), after which N is small and chunking is moot.
 
 VMEM sizing: one tile holds x [bn, D] + w [D, bv] + logits f32 [bn, bv]
-+ f32 accumulators; the 512x1024 default fits comfortably at D <= 1024
-(~8 MB). For much wider models shrink ``block_v``.
++ f32 accumulators. The forward and dx kernels fit the 512x1024 default
+under the chip's 16 MiB scoped limit up to D = 1024 with f32 master
+weights; the dW kernel holds three [D, bv] blocks (w in, dW out, f32
+accumulator) and does not, so it picks its own vocab block from the shapes
+(:func:`_dw_block_v`). For much wider models shrink ``block_v``.
 """
 
 from __future__ import annotations
@@ -50,6 +53,9 @@ _STAT_LANES = 128
 # kernel-tune cache first (dtf_tpu.tune.resolver; docs/TUNING.md).
 DEFAULT_BLOCK_N = 512
 DEFAULT_BLOCK_V = 1024
+#: the scoped-VMEM limit Mosaic gives one kernel on a v5e (its compiler
+#: refuses a kernel whose tiles and temporaries need more)
+_SCOPED_VMEM_BYTES = 16 * 2 ** 20
 
 
 def _col_ids(j, shape, block_v):
@@ -152,6 +158,27 @@ def _dw_kernel(x_ref, w_ref, lab_ref, lse_ref, dce_ref, dw_ref, acc_scr,
         dw_ref[...] = acc_scr[...].astype(dw_ref.dtype)
 
 
+def _dw_block_v(block_n, d, block_v, x_dtype, w_dtype):
+    """The dW kernel's vocab block: ``block_v`` halved until its tile set
+    fits scoped VMEM. The bound — x, w and dW blocks double-buffered, the
+    f32 accumulator, the f32 logits/dlogits tiles, an f32 copy of x for a
+    sub-f32 w — was checked against what the v5e compiler itself asks for
+    at 30 shapes (D 768-2048, f32/bf16 w, blocks 256-1024): never under,
+    at most ~1.5x over. At GPT-2 widths with f32 master weights 1024
+    becomes 512 (1024 needs 18-23 MiB, 512 needs 10-13)."""
+    xb, wb = jnp.dtype(x_dtype).itemsize, jnp.dtype(w_dtype).itemsize
+
+    def need(bv):
+        return (2 * block_n * d * xb + 4 * d * bv * wb + d * bv * 4
+                + 2 * block_n * bv * 4
+                + (block_n * d * 4 if wb < 4 else 0))
+
+    bv = block_v
+    while need(bv) > _SCOPED_VMEM_BYTES and bv % 256 == 0:
+        bv //= 2
+    return bv
+
+
 def _prep(x, w, labels, block_n, block_v):
     n, d = x.shape
     v = w.shape[1]
@@ -195,9 +222,8 @@ def _run_bwd(x, w, labels, lse, dce, block_n, block_v, interpret):
                                                 block_v)
     lsep = _pad(lse, block_n, 0).reshape(num_n, 1, block_n)
     dcep = _pad(dce, block_n, 0).reshape(num_n, 1, block_n)
-    common = dict(v=v, block_v=block_v)
     dx = pl.pallas_call(
-        functools.partial(_dx_kernel, num_v=num_v, **common),
+        functools.partial(_dx_kernel, num_v=num_v, v=v, block_v=block_v),
         grid=(num_n, num_v),
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
@@ -212,19 +238,21 @@ def _run_bwd(x, w, labels, lse, dce, block_n, block_v, interpret):
         compiler_params=_compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
     )(xp, wp, labp, lsep, dcep)
+    # w is padded to a multiple of block_v, which every halving divides
+    bv_dw = _dw_block_v(block_n, d, block_v, x.dtype, w.dtype)
     dw = pl.pallas_call(
-        functools.partial(_dw_kernel, num_n=num_n, **common),
-        grid=(num_v, num_n),
+        functools.partial(_dw_kernel, num_n=num_n, v=v, block_v=bv_dw),
+        grid=(wp.shape[1] // bv_dw, num_n),
         in_specs=[
             pl.BlockSpec((block_n, d), lambda j, i: (i, 0)),
-            pl.BlockSpec((d, block_v), lambda j, i: (0, j)),
+            pl.BlockSpec((d, bv_dw), lambda j, i: (0, j)),
             pl.BlockSpec((1, 1, block_n), lambda j, i: (i, 0, 0)),
             pl.BlockSpec((1, 1, block_n), lambda j, i: (i, 0, 0)),
             pl.BlockSpec((1, 1, block_n), lambda j, i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((d, block_v), lambda j, i: (0, j)),
+        out_specs=pl.BlockSpec((d, bv_dw), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct(wp.shape, w.dtype),
-        scratch_shapes=[pltpu.VMEM((d, block_v), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((d, bv_dw), jnp.float32)],
         compiler_params=_compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
     )(xp, wp, labp, lsep, dcep)

@@ -123,7 +123,7 @@ def token_chunked_lm_cross_entropy(x: jax.Array, w_head: jax.Array,
     full-vocab matmul ([chunk, D] x [D, V]) followed by a plain CE, with
     no online-logsumexp carry. The round-5 on-chip rows showed the
     vocab-chunked scan costs ~9 GPT MFU points over the monolithic loss
-    (BENCH_LM_SWEEP.json; PERF.md §0b): its per-step [N, chunk] max/
+    (BENCH_LM_SWEEP.json; PERF.md §5): its per-step [N, chunk] max/
     rescale/pick passes are VPU traffic over the whole activation set
     repeated every chunk, and its carries serialize against the matmul.
     Token chunking does the lse/pick arithmetic ONCE per token on an

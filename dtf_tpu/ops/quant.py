@@ -26,10 +26,6 @@ one quantization module every consumer shares:
   wins but warns once when it overrides a measured winner (the same
   ``note_override`` contract as block shapes and spec_k).
 
-fp8 is feature-gated through ``_jax_compat.fp8_e4m3_dtype()``: on a jax
-without the dtype, fp8 demotes to bf16 with one warning rather than
-crashing a launcher.
-
 The communicated-operand ring twins live in
 ``ops/collective_matmul.py`` (``ag_matmul_quant`` / ``matmul_rs_quant``
 — dequant-after-ppermute, ~2x fewer ring bytes); ``core/comms.tp_dense``
@@ -44,8 +40,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from dtf_tpu import _jax_compat
 
 #: the precision vocabulary tp_dense/TpDense accept. "" = bf16 with no
 #: tuner consultation (the pre-ISSUE-17 behavior, byte for byte);
@@ -67,10 +61,6 @@ def validate_precision(precision: str, *, what: str = "precision") -> str:
     return precision
 
 
-def fp8_supported() -> bool:
-    return _jax_compat.fp8_e4m3_dtype() is not None
-
-
 def quantize_channel(a: jax.Array, *, axis: int = -1,
                      dtype: str = "int8"):
     """Symmetric per-channel quantization over ``axis``.
@@ -87,14 +77,8 @@ def quantize_channel(a: jax.Array, *, axis: int = -1,
                      -127, 127).astype(jnp.int8)
         return q, scale
     if dtype == "fp8":
-        f8 = _jax_compat.fp8_e4m3_dtype()
-        if f8 is None:
-            raise ValueError(
-                "fp8 requested but this jax has no float8_e4m3fn — "
-                "resolve_precision demotes to bf16; an explicit fp8 "
-                "caller must gate on quant.fp8_supported()")
         scale = jnp.maximum(amax, _SCALE_EPS) / FP8_E4M3_MAX
-        q = (a.astype(jnp.float32) / scale).astype(f8)
+        q = (a.astype(jnp.float32) / scale).astype(jnp.float8_e4m3fn)
         return q, scale
     raise ValueError(f"quantize_channel dtype={dtype!r} must be "
                      "'int8' or 'fp8'")
@@ -171,19 +155,6 @@ def quantized_matmul(x: jax.Array, w: jax.Array, *,
     return _quantized_matmul(precision, x, w)
 
 
-@functools.lru_cache(maxsize=64)
-def _warn_fp8_demoted() -> None:
-    try:
-        from absl import logging as absl_logging
-
-        absl_logging.warning(
-            "fp8 matmul precision requested but this jax has no "
-            "float8_e4m3fn dtype — demoting to bf16 (feature gate: "
-            "dtf_tpu._jax_compat.fp8_e4m3_dtype)")
-    except Exception:  # pragma: no cover
-        pass
-
-
 def resolve_precision(precision: str, *, parallel: str, d_in: int,
                       d_out: int, dtype: str = "bfloat16",
                       n_devices: int = 1,
@@ -194,8 +165,7 @@ def resolve_precision(precision: str, *, parallel: str, d_in: int,
     path); ``"auto"`` returns the banked ``matmul_precision`` winner at
     the nearest (site, shape) — bf16 when nothing is banked; an
     explicit ``"int8"``/``"fp8"`` wins but ``note_override`` warns once
-    when it disagrees with a MEASURED winner. fp8 demotes to bf16 with
-    one warning where the jax has no e4m3 dtype."""
+    when it disagrees with a MEASURED winner."""
     validate_precision(precision)
     if precision in ("", "bf16"):
         return "bf16"
@@ -211,7 +181,4 @@ def resolve_precision(precision: str, *, parallel: str, d_in: int,
         tune_resolver.note_override(
             "matmul_precision", f"{parallel}:{d_in}x{d_out}", precision,
             plan.precision, source=plan.source, measured=plan.measured)
-    if resolved == "fp8" and not fp8_supported():
-        _warn_fp8_demoted()
-        return "bf16"
     return resolved

@@ -62,9 +62,8 @@ requests.
 Because all programs are compiled executables, steady state CANNOT
 recompile — a shape change would be a loud call-site error, not a silent
 retrace (``trace_counts`` exposes the per-program trace counters the fence
-test pins). State donation is deliberately off: on backfilled pre-0.5 jax a
-donated executable deserialized from the persistent compile cache drops
-aliased outputs (see core/train.py's gate and the conftest note).
+test pins). The engine does not donate its state: every step writes a new
+cache (unmeasured; a candidate once a decode cell exists — PERF.md §7).
 
 Sharded serving: pass ``mesh`` and TP-sharded params — the cache lands
 ``P('data','model')`` (:func:`dtf_tpu.models.gpt.cache_shardings`: slots

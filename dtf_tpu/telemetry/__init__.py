@@ -9,11 +9,12 @@ Enable with ``--telemetry`` on any train launcher; programmatic use:
     print(json.dumps(tel.finish()))      # the one RunReport JSON line
 """
 
-from dtf_tpu.telemetry.accounting import (GoodputTracker,          # noqa: F401
+from dtf_tpu.telemetry.accounting import (DEVICE_PEAKS,            # noqa: F401
+                                          GoodputTracker,
                                           RESNET50_TRAIN_FLOPS_PER_IMG,
-                                          V5E_PEAK_BF16_FLOPS,
                                           analytic_lm_flops_per_step,
                                           cost_analysis_flops,
+                                          device_peak_flops, device_peaks,
                                           param_count)
 from dtf_tpu.telemetry.events import EventLog, read_events         # noqa: F401
 from dtf_tpu.telemetry.fence import CompileFence                   # noqa: F401

@@ -2,7 +2,7 @@
 and what fraction of the wall clock actually trained?".
 
 MFU follows the two conventions the benches already bank (scripts/
-bench_lm.py, PERF.md §1):
+bench_lm.py, PERF.md §5):
 
 - **analytic**: 6 FLOPs per parameter per token (fwd+bwd weight FLOPs)
   plus the attention term ``12·L·d·s`` per token — the "Scalable Training
@@ -24,13 +24,42 @@ from __future__ import annotations
 
 from typing import Mapping, Optional
 
-#: TPU v5e peak bf16 matmul throughput per chip (the bench.py constant).
-V5E_PEAK_BF16_FLOPS = 197e12
+#: Published peaks of ONE chip, keyed by the ``device_kind`` JAX reports.
+#: THE table: every utilization this repo prints divides by a row of it.
+#: "TPU v5 lite" is the v5e — Google Cloud documentation, "TPU v5e":
+#: 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB of HBM at 819 GB/s.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9},
+}
 
-#: ResNet-50 v1.5 @224 fwd ≈ 4.09e9 MAC-derived FLOPs/image, training ≈ 3×
-#: fwd (the bench.py constant — keep the two in sync; bench.py cannot
-#: import this module because its parent process never imports jax deps).
+#: ResNet-50 v1.5 @224 fwd ≈ 4.09e9 MAC-derived FLOPs/image (2 FLOPs per
+#: MAC), training ≈ 3× fwd.
 RESNET50_TRAIN_FLOPS_PER_IMG = 3 * 4.09e9
+
+
+def device_peaks(device=None) -> Optional[dict]:
+    """The :data:`DEVICE_PEAKS` row of ``device`` (default: the first
+    device JAX reports). ``None`` on the CPU — a CPU run reports no
+    utilization rather than one against a chip it did not run on. An
+    accelerator missing from the table is an error, not a default."""
+    import jax
+
+    d = device if device is not None else jax.devices()[0]
+    if d.platform == "cpu":
+        return None
+    if d.device_kind not in DEVICE_PEAKS:
+        raise ValueError(
+            f"no published peaks for device_kind {d.device_kind!r} "
+            f"(platform {d.platform!r}); add a sourced row to "
+            f"telemetry.accounting.DEVICE_PEAKS")
+    return DEVICE_PEAKS[d.device_kind]
+
+
+def device_peak_flops(device=None) -> Optional[float]:
+    """bf16 peak FLOP/s of one chip, or None on the CPU."""
+    peaks = device_peaks(device)
+    return peaks["bf16_flops"] if peaks else None
 
 #: goodput buckets the trainer/hook instrumentation feeds; anything else
 #: lands in "other" so the report always sums to the measured overhead.

@@ -32,7 +32,7 @@ from typing import Mapping, Optional
 
 from dtf_tpu._hostio import atomic_replace
 from dtf_tpu.telemetry.accounting import (GoodputTracker,
-                                          V5E_PEAK_BF16_FLOPS)
+                                          device_peak_flops)
 from dtf_tpu.telemetry.fence import CompileFence
 from dtf_tpu.telemetry.flight import FlightRecorder, StallWatchdog
 from dtf_tpu.telemetry.spans import SpanRecorder, step_annotation
@@ -49,7 +49,7 @@ class Telemetry:
     def __init__(self, out_dir: Optional[str] = None, *,
                  keep_steps: int = 64, stall_factor: float = 10.0,
                  min_stall_s: float = 60.0, watchdog: bool = True,
-                 peak_flops: float = V5E_PEAK_BF16_FLOPS,
+                 peak_flops: Optional[float] = None,
                  n_devices: int = 1, clock=time.monotonic, wall=time.time):
         self.out_dir = out_dir
         self.spans = SpanRecorder()
@@ -70,8 +70,11 @@ class Telemetry:
             if watchdog else None
         #: per-CHIP peak × the mesh's device count is the MFU denominator:
         #: model_flops_per_step covers the whole global batch, so quoting
-        #: it against one chip's peak would overstate MFU by n_devices
-        self.peak_flops = peak_flops
+        #: it against one chip's peak would overstate MFU by n_devices.
+        #: Left unset it is the running device's published peak — None on
+        #: the CPU, where no mfu is reported at all.
+        self.peak_flops = (peak_flops if peak_flops is not None
+                           else device_peak_flops())
         self.n_devices = max(int(n_devices), 1)
         self.tokens_per_step: Optional[float] = None
         self.model_flops_per_step: Optional[float] = None
@@ -265,11 +268,10 @@ class Telemetry:
             if self.model_flops_per_step:
                 out["model_flops_per_step"] = self.model_flops_per_step
                 out["n_devices"] = self.n_devices
-                # 8 digits: tiny CPU-sim runs land at 1e-8..1e-6-scale MFU
-                # and must not round to a flat 0.0 in the committed artifact
-                out["mfu"] = round(
-                    sps * self.model_flops_per_step
-                    / (self.peak_flops * self.n_devices), 8)
+                if self.peak_flops:
+                    out["mfu"] = round(
+                        sps * self.model_flops_per_step
+                        / (self.peak_flops * self.n_devices), 8)
         if self.flight.last_scalars:
             out["last_scalars"] = dict(self.flight.last_scalars)
         if self.device_profile is not None:

@@ -67,6 +67,7 @@ def main(argv):
     import numpy as np
 
     from dtf_tpu.checkpoint import Checkpointer
+    from dtf_tpu.cli.launch import init_backend
     from dtf_tpu.core.mesh import MeshConfig, make_mesh
     from dtf_tpu.core.sharding import shard_tree
     from dtf_tpu.models import gpt
@@ -80,8 +81,7 @@ def main(argv):
         raise app.UsageError(
             "--top_k/--top_p have no effect at --temperature=0 (greedy); "
             "set a positive temperature to sample")
-    if FLAGS.backend == "cpu":
-        jax.config.update("jax_platforms", "cpu")
+    init_backend(FLAGS.backend)
     # Serving is a single-process, chief-only job: no cluster bootstrap.
     # Sharded decode is opt-in (explicit positive mesh axes) and runs on a
     # device SUBSET sized to the mesh — a serving batch is often tiny, and

@@ -212,6 +212,7 @@ def main(argv):
     import jax
 
     from dtf_tpu.checkpoint import Checkpointer, load_model_config
+    from dtf_tpu.cli.launch import device_report, init_backend
     from dtf_tpu.core.mesh import MeshConfig, make_mesh
     from dtf_tpu.core.sharding import shard_tree
     from dtf_tpu.metrics import MetricWriter
@@ -219,8 +220,7 @@ def main(argv):
     from dtf_tpu.serve import (DecodeEngine, PoissonLoadGen, Request,
                                Scheduler, replay)
 
-    if FLAGS.backend == "cpu":
-        jax.config.update("jax_platforms", "cpu")
+    init_backend(FLAGS.backend)
     sharded = FLAGS.mesh_model > 1 or FLAGS.mesh_data > 1
     mesh = None
     if sharded:
@@ -622,7 +622,8 @@ def main(argv):
     n_tokens = sum(len(p["tokens"]) for p in polls)
     cache_bytes = sum(e.cache_bytes() for e in engines)
     out = {"mode": "requests" if FLAGS.requests else "poisson",
-           "backend": jax.default_backend(), "step": step,
+           "backend": jax.default_backend(), **device_report(),
+           "step": step,
            # the published version serving STARTED on (0 = checkpoint
            # serving) and the one the fleet ended on after any rolling
            # swaps — stats() adds router_version/replica{i}_version

@@ -82,7 +82,7 @@ flags.DEFINE_integer("loss_chunk_vocab", 0, "compute the LM loss fused "
 flags.DEFINE_integer("loss_chunk_tokens", 0, "fused LM loss chunking "
                      "TOKENS instead of vocab columns: O(chunk*vocab) "
                      "live logits, one full-vocab matmul per block — "
-                     "the faster chunking axis on chip (PERF.md 0b). "
+                     "the faster chunking axis on chip (PERF.md §5). "
                      "Mutually exclusive with --loss_chunk_vocab; same "
                      "--mesh_model/--mesh_pipe restrictions")
 flags.DEFINE_boolean("loss_pallas", False, "Pallas fused head+CE kernel: "
@@ -346,7 +346,7 @@ def main(argv):
         # auto loss path: monolithic logits when they fit HBM (fastest),
         # the banked kernel-tune winner — token-chunked fused CE by
         # default — when they don't; explicit flags win but warn when
-        # they force a measured-slower path (PERF.md 0c, docs/TUNING.md)
+        # they force a measured-slower path (PERF.md §5, docs/TUNING.md)
         lpath = dflags.resolve_lm_loss(
             FLAGS, batch=FLAGS.batch_size, seq_len=FLAGS.seq_len,
             vocab_size=cfg.vocab_size, mesh_shape=dict(mesh.shape))
@@ -539,7 +539,7 @@ def main(argv):
         if (sp and FLAGS.attn_impl == "zigzag") else b,
         mesh, spec=spec)
     # every path evaluates — the pipelined ones via the un-pipelined
-    # sequential eval over the same stacked params (VERDICT r3 #7)
+    # sequential eval over the same stacked params
     eval_hook = lm_eval_hook(
         FLAGS, info, mesh, shardings, eval_fn, writer,
         place_batch, kind="gpt", mode="clm", vocab_size=cfg.vocab_size,

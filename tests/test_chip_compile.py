@@ -1,0 +1,148 @@
+"""The main path's Pallas kernels, compiled for the chip at real widths.
+
+Interpret mode (every other kernel test here) cannot see what the TPU's
+compiler refuses: a slice off the tiling, more scoped VMEM than a kernel
+may use. The compiler is installed without the chip and compiles for a
+DESCRIBED v5e, so these guard every later PR at no chip time — about two
+seconds each. A compile that passes is a compile, not a chip run:
+``chip_smoke.py`` runs the same kernels on the chip against their dense
+references.
+
+All in ONE file on purpose (on-chip-measurement guide, section 2): only
+one process may load the TPU's library, so the topology is described
+inside a module-scoped fixture of this file — never at import, in a
+``skipif`` or in ``parametrize`` arguments — and every compile runs in
+the test's own process. The persistent cache is off around them: a
+described-device executable is written to it but cannot be read back
+without a chip.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from dtf_tpu.ops import embed_gather, flash_attention as fa, fused_ce
+from dtf_tpu.tune import resolver
+
+VOCAB = 50304          # GPTConfig's vocab (TP-divisible GPT-2)
+TOKENS = 8 * 1024      # batch 8 x seq 1024
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no logs under /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def on_chip(topo):
+    """shape, dtype -> a ShapeDtypeStruct placed on one described chip."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+def _compiles_to_kernel(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def _flash(window, **shape):
+    """flash_attention with the blocks the CHIP would resolve: left at 0
+    they follow ``jax.default_backend()``, which is the CPU here."""
+    plan = resolver.flash_plan(dtype="bfloat16", causal=True, window=window,
+                               n_devices=1, backend="tpu", **shape)
+
+    def attn(q, k, v):
+        return fa.flash_attention(
+            q, k, v, causal=True, window=window, block_q=plan.block_q,
+            block_k=plan.block_k, block_q_bwd=plan.block_q_bwd,
+            block_k_bwd=plan.block_k_bwd)
+
+    return attn
+
+
+# (batch, heads, seq, head_dim): GPT-2 medium and small at the smoke's
+# and the long-context launcher's sequence lengths
+FLASH_SHAPES = {"medium_s1024": (8, 16, 1024, 64),
+                "small_s1024": (8, 12, 1024, 64),
+                "medium_s2048": (2, 16, 2048, 64)}
+
+
+@pytest.mark.parametrize("name,window", [
+    ("medium_s1024", 0), ("medium_s1024", 256), ("small_s1024", 0),
+    ("medium_s2048", 0)])
+def test_flash_forward_compiles(on_chip, name, window):
+    b, h, t, d = FLASH_SHAPES[name]
+    q = on_chip((b, h, t, d), jnp.bfloat16)
+    _compiles_to_kernel(_flash(window, seq=t, heads=h, head_dim=d), q, q, q)
+
+
+@pytest.mark.parametrize("name,window", [
+    ("medium_s1024", 0), ("medium_s1024", 256), ("medium_s2048", 256)])
+def test_flash_backward_compiles(on_chip, name, window):
+    b, h, t, d = FLASH_SHAPES[name]
+    q = on_chip((b, h, t, d), jnp.bfloat16)
+    attn = _flash(window, seq=t, heads=h, head_dim=d)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(attn(*a).astype(jnp.float32)),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    _compiles_to_kernel(grads, q, q, q)
+
+
+def _fused_ce_args(on_chip, d_model):
+    # the production dtype mix: bf16 hidden states, f32 master head
+    plan = resolver.fused_ce_plan(vocab=VOCAB, d_model=d_model,
+                                  dtype="bfloat16", n_devices=1,
+                                  backend="tpu")
+
+    def loss(x, w, labels):
+        return fused_ce.pallas_lm_cross_entropy(
+            x, w, labels, ignore_index=-100, block_n=plan.block_n,
+            block_v=plan.block_v)[0]
+
+    return loss, (on_chip((TOKENS, d_model), jnp.bfloat16),
+                  on_chip((d_model, VOCAB), jnp.float32),
+                  on_chip((TOKENS,), jnp.int32))
+
+
+@pytest.mark.parametrize("d_model", [768, 1024])
+def test_fused_ce_forward_compiles(on_chip, d_model):
+    loss, args = _fused_ce_args(on_chip, d_model)
+    _compiles_to_kernel(loss, *args)
+
+
+@pytest.mark.parametrize("d_model", [768, 1024])
+def test_fused_ce_backward_compiles(on_chip, d_model):
+    """The dW kernel at 512x1024 blocks needs 18 MiB (d 768) and 23 MiB
+    (d 1024) of the 16 MiB scoped VMEM with an f32 head — refused until
+    it chose its own vocab block (fused_ce._dw_block_v)."""
+    loss, args = _fused_ce_args(on_chip, d_model)
+    _compiles_to_kernel(
+        lambda x, w, labels: jax.grad(loss, argnums=(0, 1))(x, w, labels),
+        *args)
+
+
+def test_embedding_gather_compiles(on_chip):
+    # a Wide&Deep table: 1M rows x 64, a 4096-example batch of 26 features
+    table = on_chip((1_000_000, 64), jnp.float32)
+    ids = on_chip((4096, 26), jnp.int32)
+    _compiles_to_kernel(embed_gather.gather_rows, table, ids)

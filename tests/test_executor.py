@@ -41,12 +41,10 @@ def test_fenced_counts_per_trace_not_per_call():
     assert executor.fenced("q", body, None) is body
 
 
-def test_donation_argnums_routes_through_the_gate():
-    want = (0,) if tr.donation_enabled(True) else ()
-    assert executor.donation_argnums(True) == want
+def test_donation_argnums_follow_the_caller():
+    assert executor.donation_argnums(True) == (0,)
     assert executor.donation_argnums(False) == ()
-    assert executor.donation_argnums(True, (0, 1)) == (
-        (0, 1) if tr.donation_enabled(True) else ())
+    assert executor.donation_argnums(True, (0, 1)) == (0, 1)
 
 
 def test_program_bare_lower_uses_registered_abstracts():

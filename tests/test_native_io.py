@@ -98,3 +98,26 @@ def test_use_after_close_raises(idx_files):
     loader.close()
     with pytest.raises(RuntimeError, match="close"):
         loader.next_batch()
+
+
+def test_library_builds_unless_newer_than_source_and_makefile(
+        tmp_path, monkeypatch):
+    """The library is not committed and a copied checkout keeps no mtimes:
+    build when it is absent, or not strictly newer than BOTH the source
+    and the Makefile."""
+    from dtf_tpu.data import native
+
+    for name in ("dtfio.cpp", "Makefile"):
+        (tmp_path / name).write_text("x")
+        os.utime(tmp_path / name, (1000, 1000))
+    so = tmp_path / "libdtfio.so"
+    monkeypatch.setattr(native, "_NATIVE_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_SO_PATH", str(so))
+    assert native._stale()                       # absent
+    so.write_text("x")
+    os.utime(so, (1000, 1000))
+    assert native._stale()                       # a copy: all one mtime
+    os.utime(so, (2000, 2000))
+    assert not native._stale()
+    os.utime(tmp_path / "Makefile", (3000, 3000))
+    assert native._stale()                       # flags changed

@@ -253,7 +253,7 @@ def test_decoupled_decay_promotes_recipe_l2():
 
 
 def test_resolve_lm_loss_auto_picks_from_hbm_estimate():
-    """ISSUE 2 satellite: the LM loss path is an HBM decision (PERF.md 0c
+    """ISSUE 2 satellite: the LM loss path is an HBM decision (PERF.md §5
     — chunking costs ~9 GPT MFU points, it is a memory lever). Monolithic
     when the [B,T,V] logits fit per device, the banked kernel-tune
     winner (token-chunked by default) when they don't; explicit flags

@@ -35,8 +35,7 @@ from dtf_tpu.tune import cache, resolver, search
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-PRECISIONS_UNDER_TEST = ("int8",) + (("fp8",) if quant.fp8_supported()
-                                     else ())
+PRECISIONS_UNDER_TEST = ("int8", "fp8")
 
 
 @pytest.fixture
@@ -86,8 +85,6 @@ def test_zero_channel_roundtrips_bitwise(dtype):
 def test_quantize_dequantize_error_bound(dtype, bound):
     """Per-channel symmetric round-trip error: int8 resolves amax/127
     (worst-case half a step), e4m3's 3 mantissa bits ~6% relative."""
-    if dtype == "fp8" and not quant.fp8_supported():
-        pytest.skip("no float8_e4m3fn on this jax")
     rng = np.random.default_rng(0)
     a = jnp.asarray(rng.normal(size=(16, 64)).astype(np.float32))
     q, s = quant.quantize_channel(a, axis=-1, dtype=dtype)
@@ -193,10 +190,10 @@ def test_rs_ring_quant_parity(mesh_4x2):
 
 def test_ring_inventory_has_quant_pairs():
     """The soundness pass traces the quant rings' fwd AND bwd: the
-    inventory must name them (fp8 pair present iff the dtype exists)."""
+    inventory must name them."""
     names = [op.name for op in cm.ring_inventory()]
     assert "ag_matmul_int8" in names and "matmul_rs_int8" in names
-    assert ("ag_matmul_fp8" in names) == quant.fp8_supported()
+    assert "ag_matmul_fp8" in names and "matmul_rs_fp8" in names
 
 
 @pytest.mark.slow
@@ -307,27 +304,12 @@ def test_explicit_pin_warns_over_measured_winner(tune_env):
         assert out == "bf16"
         warn.assert_not_called()     # ''/'bf16' short-circuit: no consult
         got = quant.resolve_precision(
-            "fp8" if quant.fp8_supported() else "int8",
-            parallel="row", d_in=768, d_out=3072, backend="cpu")
+            "fp8", parallel="row", d_in=768, d_out=3072, backend="cpu")
         warn.assert_not_called()     # row site: fallback, not measured
-        assert got in ("fp8", "int8")
-        quant.resolve_precision("fp8" if quant.fp8_supported() else
-                                "bf16", parallel="column", d_in=768,
+        assert got == "fp8"
+        quant.resolve_precision("fp8", parallel="column", d_in=768,
                                 d_out=3072, backend="cpu")
-        if quant.fp8_supported():
-            warn.assert_called_once()    # explicit beats measured int8
-
-
-def test_fp8_demotes_to_bf16_when_unsupported(tune_env):
-    with mock.patch.object(quant._jax_compat, "fp8_e4m3_dtype",
-                           return_value=None):
-        quant._warn_fp8_demoted.cache_clear()
-        assert quant.resolve_precision(
-            "fp8", parallel="column", d_in=64, d_out=64,
-            backend="cpu") == "bf16"
-        with pytest.raises(ValueError, match="float8_e4m3fn"):
-            quant.quantize_channel(jnp.ones((2, 2)), dtype="fp8")
-    quant._warn_fp8_demoted.cache_clear()
+        warn.assert_called_once()    # explicit beats measured int8
 
 
 def test_select_precision_winner_enforces_ceiling():

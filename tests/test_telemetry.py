@@ -141,7 +141,8 @@ def test_run_report_phases_mfu_goodput(mesh8, tmp_path):
     """One RunReport with per-phase p50/p99, throughput + MFU from the
     declared per-step work, and goodput buckets that include the hook
     attribution (logging bucket from LoggingHook wall time)."""
-    tel = Telemetry(out_dir=str(tmp_path / "tel"), watchdog=False)
+    tel = Telemetry(out_dir=str(tmp_path / "tel"), watchdog=False,
+                    peak_flops=1e12)
     tel.set_throughput_model(tokens_per_step=64,
                              model_flops_per_step=1e9)
     state, step = build(mesh8, telemetry=tel)
@@ -213,6 +214,39 @@ def test_mfu_divides_by_device_count_and_throughput_name():
     assert r8["n_devices"] == 8
     assert r8["examples_per_sec"] == pytest.approx(64.0)
     assert "tokens_per_sec" not in r8
+
+
+def test_cpu_run_reports_no_mfu():
+    """The peak comes from the running device's row of DEVICE_PEAKS; the
+    CPU has none, so neither the RunReport nor LoggingHook names an mfu
+    (a utilization against a chip the run never touched)."""
+    t = [0.0]
+    tel = Telemetry(watchdog=False, clock=lambda: t[0])
+    assert tel.peak_flops is None
+    tel.set_throughput_model(tokens_per_step=64, model_flops_per_step=1e9)
+    tel.open_wall()
+    t[0] += 1.0
+    tel.note_step(1, {"step_s": 1.0})
+    tel.close_wall()
+    rep = tel.report()
+    assert rep["tokens_per_sec"] > 0 and "mfu" not in rep
+    hook = LoggingHook(MetricWriter(also_log=False), 1,
+                       model_flops_per_step=1e9)
+    assert hook.peak_flops is None
+
+
+def test_device_peaks_table_is_keyed_by_device_kind():
+    from dtf_tpu.telemetry import accounting
+
+    class Dev:
+        def __init__(self, platform, kind):
+            self.platform, self.device_kind = platform, kind
+
+    assert accounting.device_peaks(Dev("cpu", "cpu")) is None
+    v5e = accounting.device_peaks(Dev("tpu", "TPU v5 lite"))
+    assert v5e["bf16_flops"] == 197e12 and v5e["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(ValueError, match="TPU v99"):
+        accounting.device_peaks(Dev("tpu", "TPU v99"))
 
 
 def test_logging_hook_peak_derived_from_telemetry_mesh():

@@ -195,29 +195,20 @@ def test_lr_schedule_composes_with_grad_accum_and_zero1(mesh8):
     assert counts and all(int(c) == 8 for c in counts)
 
 
-def test_donation_gate_follows_backfilled_jax(monkeypatch, mesh8):
-    """ISSUE 9 satellite: the `_compat.BACKFILLED` donation gate —
-    previously only documented in a comment and the conftest — is a
-    tested contract: train steps donate NOTHING on backfilled jax (a
-    donated executable deserialized from the persistent compile cache
-    drops aliased outputs there — the BN-stats-freeze class) and DO
-    donate their state otherwise.  Asserted on the lowering's own
-    args_info, the surface the analyzer's memory pass introspects."""
-    from dtf_tpu import _jax_compat as _compat
-
+def test_train_steps_donate_their_state(mesh8):
+    """Train steps donate their state unless the caller says otherwise.
+    Asserted on the lowering's own args_info, the surface the analyzer's
+    memory pass introspects."""
     tx = optax.adam(0.1)
     rng = jax.random.PRNGKey(0)
     state, shardings = tr.abstract_train_state(linear_init, tx, rng, mesh8)
     batch = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype),
         make_batch(16))
-    for backfilled, expect_donated in ((True, False), (False, True)):
-        monkeypatch.setattr(_compat, "BACKFILLED", backfilled)
-        assert tr.donation_enabled(True) is expect_donated
-        step = tr.make_train_step(linear_loss, tx, mesh8, shardings)
+    for donate in (True, False):
+        step = tr.make_train_step(linear_loss, tx, mesh8, shardings,
+                                  donate=donate)
         donated = [getattr(a, "donated", False)
                    for a in jax.tree.leaves(step.lower(state,
                                                        batch).args_info)]
-        assert any(donated) is expect_donated, (backfilled, donated)
-    # donate=False wins regardless of the jax version
-    assert tr.donation_enabled(False) is False
+        assert any(donated) is donate, (donate, donated)
