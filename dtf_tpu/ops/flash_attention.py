@@ -345,6 +345,7 @@ def _fwd(q, k, v, mask_bias, *, sm_scale, causal, window, block_q, block_k,
              pltpu.VMEM((block_q, d), jnp.float32)]),
         compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="dtf_flash_fwd",
     )(*inputs)
     return out[:, :t_q], lse.reshape(bh, num_q * block_q)[:, :t_q]
 
@@ -489,6 +490,7 @@ def _bwd(q, k, v, mask_bias, out, lse, do, *, sm_scale, causal, window,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="dtf_flash_dq",
     )(qp, kp, vp, dop, lsep, deltap, *mask_in)
 
     q_map = _q_sticky_map(causal=causal, window=window, block_q=block_q,
@@ -523,6 +525,7 @@ def _bwd(q, k, v, mask_bias, out, lse, do, *, sm_scale, causal, window,
         ],
         compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="dtf_flash_dkv",
     )(qp, kp, vp, dop, lsep, deltap, *mask_in)
     return dq[:, :t_q], dk[:, :t_k], dv[:, :t_k]
 

@@ -80,7 +80,6 @@ ever dominates (docs/SERVING.md).
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import logging
 import math
@@ -93,6 +92,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dtf_tpu.core import executor
 from dtf_tpu.models import gpt
+from dtf_tpu.telemetry.spans import NO_SPAN, trace_annotation
 
 log = logging.getLogger("dtf_tpu")
 
@@ -576,13 +576,17 @@ class DecodeEngine:
                                   "draft_prefill_chunks": 0,
                                   "draft_fallbacks": 0,
                                   "spec_proposed": 0, "spec_accepted": 0})
-        #: when True, each compiled-program dispatch is wrapped in a
-        #: jax.profiler.TraceAnnotation carrying the request trace id(s) the
+        #: when True, each prefill-chunk and decode call is wrapped in a
+        #: jax.profiler.TraceAnnotation (``dtf.serve.prefill_chunk`` /
+        #: ``dtf.serve.decode``) carrying the request trace id(s) the
         #: scheduler threaded down — a ProfilerHook window over a serving
-        #: run then shows WHICH requests each prefill/decode dispatch
-        #: served, joinable to the per-request chrome trace. Off by
-        #: default: a TraceMe outside any profiling session is cheap but
-        #: not free, and the id strings allocate per decode step.
+        #: run then shows WHICH requests each call served, joinable to
+        #: the per-request chrome trace — and, nested in it, its two
+        #: phases: ``dtf.engine.<call>.dispatch`` (operands built, the
+        #: compiled program called, futures back) and ``.readback`` (the
+        #: host waits for the outputs). Off by default: a TraceMe outside
+        #: any profiling session is cheap but not free, and the id
+        #: strings allocate per decode step.
         self.annotate_traces = False
         if mesh is None:
             # a restored checkpoint carries the TRAINING mesh's shardings;
@@ -745,12 +749,16 @@ class DecodeEngine:
         return math.ceil(prompt_len / self.prefill_chunk)
 
     def _annotation(self, name: str, **ids):
-        """A jax.profiler.TraceAnnotation stamping request trace ids into
-        the XPlane timeline (``annotate_traces``); a null context
-        otherwise. Host-side marker only — never reads a device value."""
+        """A jax.profiler.TraceAnnotation under ``annotate_traces``: one
+        phase of an engine call (``dtf.engine.*``: dispatch until the
+        program returns futures, readback of its outputs) or the whole
+        call (``dtf.serve.*``, stamping the request trace ids) in the
+        XPlane timeline; otherwise the one shared null context — nothing
+        is constructed. Host-side marker only — never reads a device
+        value."""
         if not self.annotate_traces:
-            return contextlib.nullcontext()
-        return jax.profiler.TraceAnnotation(name, **ids)
+            return NO_SPAN
+        return trace_annotation(name, **ids)
 
     def prefill_chunk_into(self, slot: int, prompt: Sequence[int],
                            chunk_i: int, *, start: int = 0,
@@ -785,53 +793,59 @@ class DecodeEngine:
         n = self.n_chunks(len(tail))
         if not 0 <= chunk_i < n:
             raise ValueError(f"chunk {chunk_i} out of range [0, {n})")
-        seg = tail[chunk_i * c:(chunk_i + 1) * c]
-        buf = np.zeros((c,), np.int32)
-        buf[:len(seg)] = seg
         last = chunk_i == n - 1
         with self._annotation("dtf.serve.prefill_chunk", slot=slot,
                               chunk=chunk_i,
                               trace_id=-1 if trace_id is None else trace_id):
-            self._state, out = self._prefill_c(
-                self._params, self._state, np.int32(slot), np.int32(start),
-                buf, np.int32(len(seg)), np.bool_(chunk_i == 0),
-                np.bool_(last), np.float32(temperature), np.int32(top_k),
-                np.float32(top_p),
-                np.int32(-1 if eos_id is None else eos_id),
-                np.int32(pad_id),
-                np.asarray(jax.random.PRNGKey(seed), np.uint32))
-        self.counters["prefill_chunks"] += 1
-        if self.spec_k:
-            # the DRAFT cache must ingest the same prompt (pages never
-            # shortcut it — the draft pool does not exist, and the draft
-            # is cheap enough that full-prompt draft prefill still wins):
-            # one draft chunk rides along per target chunk, and the tail
-            # (page-hit admissions cover fewer live target chunks than
-            # the draft's full count) completes with the LAST target
-            # chunk, so both models flip active in the same host call.
-            if chunk_i == 0:
-                self._draft_chunks[slot] = 0
-                # a page load just before this admission shortcuts the
-                # draft too (self-spec; load_prefix staged the count)
-                self._draft_start[slot] = self._draft_pending[slot]
-                self._draft_pending[slot] = 0
-            dstart = int(self._draft_start[slot])
-            n_d = self.n_chunks(len(prompt) - dstart)
-            if self._draft_chunks[slot] < n_d:
-                self._draft_prefill_chunk(slot, prompt,
-                                          int(self._draft_chunks[slot]),
-                                          dstart)
-            if last:
-                while self._draft_chunks[slot] < n_d:
-                    self._draft_prefill_chunk(
-                        slot, prompt, int(self._draft_chunks[slot]),
-                        dstart)
-        if not last:
-            return None
-        if self.spec_k:
-            self._spec_index[slot] = len(prompt)
-            self._spec_tok[slot] = int(out["token"])
-        return int(out["token"]), bool(out["done"])
+            with self._annotation("dtf.engine.prefill.dispatch"):
+                seg = tail[chunk_i * c:(chunk_i + 1) * c]
+                buf = np.zeros((c,), np.int32)
+                buf[:len(seg)] = seg
+                self._state, out = self._prefill_c(
+                    self._params, self._state, np.int32(slot),
+                    np.int32(start), buf, np.int32(len(seg)),
+                    np.bool_(chunk_i == 0), np.bool_(last),
+                    np.float32(temperature), np.int32(top_k),
+                    np.float32(top_p),
+                    np.int32(-1 if eos_id is None else eos_id),
+                    np.int32(pad_id),
+                    np.asarray(jax.random.PRNGKey(seed), np.uint32))
+            self.counters["prefill_chunks"] += 1
+            if self.spec_k:
+                # the DRAFT cache must ingest the same prompt (pages never
+                # shortcut it — the draft pool does not exist, and the
+                # draft is cheap enough that full-prompt draft prefill
+                # still wins): one draft chunk rides along per target
+                # chunk, and the tail (page-hit admissions cover fewer
+                # live target chunks than the draft's full count)
+                # completes with the LAST target chunk, so both models
+                # flip active in the same host call.
+                if chunk_i == 0:
+                    self._draft_chunks[slot] = 0
+                    # a page load just before this admission shortcuts
+                    # the draft too (self-spec; load_prefix staged the
+                    # count)
+                    self._draft_start[slot] = self._draft_pending[slot]
+                    self._draft_pending[slot] = 0
+                dstart = int(self._draft_start[slot])
+                n_d = self.n_chunks(len(prompt) - dstart)
+                if self._draft_chunks[slot] < n_d:
+                    self._draft_prefill_chunk(slot, prompt,
+                                              int(self._draft_chunks[slot]),
+                                              dstart)
+                if last:
+                    while self._draft_chunks[slot] < n_d:
+                        self._draft_prefill_chunk(
+                            slot, prompt, int(self._draft_chunks[slot]),
+                            dstart)
+            if not last:
+                return None
+            with self._annotation("dtf.engine.prefill.readback"):
+                tok, done = int(out["token"]), bool(out["done"])
+            if self.spec_k:
+                self._spec_index[slot] = len(prompt)
+                self._spec_tok[slot] = tok
+            return tok, done
 
     def _draft_prefill_chunk(self, slot: int, prompt: Sequence[int],
                              chunk_i: int, start: int = 0) -> None:
@@ -842,17 +856,19 @@ class DecodeEngine:
         token is discarded: the request's sampling stream belongs to the
         verifier alone."""
         c = self.prefill_chunk
-        tail = list(int(t) for t in prompt)[start:]
-        n_d = self.n_chunks(len(tail))
-        seg = tail[chunk_i * c:(chunk_i + 1) * c]
-        buf = np.zeros((c,), np.int32)
-        buf[:len(seg)] = seg
-        self._draft_state, _ = self._draft_prefill_c(
-            self._draft_params, self._draft_state, np.int32(slot),
-            np.int32(start), buf, np.int32(len(seg)),
-            np.bool_(chunk_i == 0), np.bool_(chunk_i == n_d - 1),
-            np.float32(0.0), np.int32(0), np.float32(1.0), np.int32(-1),
-            np.int32(0), np.asarray(jax.random.PRNGKey(0), np.uint32))
+        with self._annotation("dtf.engine.prefill.dispatch"):
+            tail = list(int(t) for t in prompt)[start:]
+            n_d = self.n_chunks(len(tail))
+            seg = tail[chunk_i * c:(chunk_i + 1) * c]
+            buf = np.zeros((c,), np.int32)
+            buf[:len(seg)] = seg
+            self._draft_state, _ = self._draft_prefill_c(
+                self._draft_params, self._draft_state, np.int32(slot),
+                np.int32(start), buf, np.int32(len(seg)),
+                np.bool_(chunk_i == 0), np.bool_(chunk_i == n_d - 1),
+                np.float32(0.0), np.int32(0), np.float32(1.0),
+                np.int32(-1), np.int32(0),
+                np.asarray(jax.random.PRNGKey(0), np.uint32))
         self.counters["draft_prefill_chunks"] += 1
         self._draft_chunks[slot] += 1
 
@@ -886,15 +902,17 @@ class DecodeEngine:
         [s]]`` per slot (still one sync per TICK, now worth up to k+1
         tokens). ``trace_ids`` (scheduler-threaded) names the requests
         this step serves in the XPlane annotation."""
-        if self.spec_k:
-            return self._decode_spec(trace_ids)
         with self._annotation(
                 "dtf.serve.decode",
                 trace_ids="" if trace_ids is None
                 else ",".join(map(str, trace_ids))):
-            self._state, out = self._decode_c(self._params, self._state)
-        self.counters["decode_steps"] += 1
-        return np.asarray(out["token"]), np.asarray(out["done"])
+            if self.spec_k:
+                return self._decode_spec()
+            with self._annotation("dtf.engine.decode.dispatch"):
+                self._state, out = self._decode_c(self._params, self._state)
+            self.counters["decode_steps"] += 1
+            with self._annotation("dtf.engine.decode.readback"):
+                return np.asarray(out["token"]), np.asarray(out["done"])
 
     def draft_propose(self):
         """One draft_all dispatch: k greedy proposals per slot off the
@@ -908,27 +926,26 @@ class DecodeEngine:
         self.counters["draft_steps"] += 1
         return props
 
-    def _decode_spec(self, trace_ids):
-        try:
-            props = self.draft_propose()
-        except Exception as e:  # noqa: BLE001 — a draft failure must not
-            # fail requests: the verify step is CORRECT for arbitrary
-            # proposals (worst case it emits 1 token — plain decode), so
-            # null proposals are the fallback, not an error.
-            log.warning("draft_all failed (%r); falling back to plain "
-                        "decode this tick", e)
-            self.counters["draft_fallbacks"] += 1
-            props = np.zeros((self.n_slots, self.spec_k), np.int32)
-        with self._annotation(
-                "dtf.serve.decode",
-                trace_ids="" if trace_ids is None
-                else ",".join(map(str, trace_ids))):
+    def _decode_spec(self):
+        with self._annotation("dtf.engine.decode.dispatch"):
+            try:
+                props = self.draft_propose()
+            except Exception as e:  # noqa: BLE001 — a draft failure must
+                # not fail requests: the verify step is CORRECT for
+                # arbitrary proposals (worst case it emits 1 token — plain
+                # decode), so null proposals are the fallback, not an
+                # error.
+                log.warning("draft_all failed (%r); falling back to plain "
+                            "decode this tick", e)
+                self.counters["draft_fallbacks"] += 1
+                props = np.zeros((self.n_slots, self.spec_k), np.int32)
             self._state, out = self._decode_c(self._params, self._state,
                                               props)
         self.counters["decode_steps"] += 1
-        toks = np.asarray(out["tokens"])
-        dones = np.asarray(out["done"])
-        n_emit = np.asarray(out["n_emit"]).astype(np.int32)
+        with self._annotation("dtf.engine.decode.readback"):
+            toks = np.asarray(out["tokens"])
+            dones = np.asarray(out["done"])
+            n_emit = np.asarray(out["n_emit"]).astype(np.int32)
         # host mirrors advance from values this readback carries anyway
         live = n_emit > 0
         self._spec_index = self._spec_index + n_emit

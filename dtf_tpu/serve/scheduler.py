@@ -20,7 +20,13 @@ data_wait/dispatch decomposition, serving edition) plus ``router_wait``
 (queue time between submit and a slot accepting the request — the
 admission latency the Router SLO panel watches), and ``stats()`` gains
 their p50/p99. All of it is host clock arithmetic: zero added device
-readbacks (counter-instrumented test, PR 5 idiom).
+readbacks (counter-instrumented test, PR 5 idiom). The same gate also
+writes each tick into the profiler's timeline as one
+``jax.profiler.TraceAnnotation`` span, ``dtf.serve.tick`` (the engine adds
+its own inside it under ``annotate_traces``; docs/OBSERVABILITY.md section
+7 has the table), so that a trace reader can say how much of a tick's idle
+device time lies outside every engine call. Without telemetry a tick
+constructs no annotation.
 
 With an engine built with ``prefix_pages > 0`` admission consults the
 prefix page cache: the pinned page chain lands in ONE batched gather on
@@ -61,6 +67,7 @@ import time
 from typing import Optional, Sequence
 
 from dtf_tpu.metrics import quantile as _quantile
+from dtf_tpu.telemetry.spans import trace_annotation
 
 log = logging.getLogger("dtf_tpu")
 
@@ -332,7 +339,17 @@ class Scheduler:
 
     def tick(self) -> None:
         """One scheduling round: deadline sweep, bounded prefill, then one
-        decode step."""
+        decode step. With telemetry the round is a ``dtf.serve.tick`` span
+        on the profiler's clock (``jax.profiler.TraceAnnotation``, reached
+        through telemetry/spans.py so this module stays jax-free at
+        import); without, the one attribute test is all it costs."""
+        if self.telemetry is None:
+            self._round()
+            return
+        with trace_annotation("dtf.serve.tick"):
+            self._round()
+
+    def _round(self) -> None:
         self._tick += 1
         if self._any_deadlines:
             self._sweep_deadlines()

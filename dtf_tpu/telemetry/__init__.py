@@ -21,7 +21,8 @@ from dtf_tpu.telemetry.fence import CompileFence                   # noqa: F401
 from dtf_tpu.telemetry.flight import (FlightRecorder,              # noqa: F401
                                       StallWatchdog)
 from dtf_tpu.telemetry.run import Telemetry, merge_artifact        # noqa: F401
-from dtf_tpu.telemetry.spans import SpanRecorder, step_annotation  # noqa: F401
+from dtf_tpu.telemetry.spans import (SpanRecorder,             # noqa: F401
+                                     step_annotation, trace_annotation)
 from dtf_tpu.telemetry.trace import TraceCollector                 # noqa: F401
 
 # NOTE: dtf_tpu.telemetry.xplane / .profile are imported lazily by their

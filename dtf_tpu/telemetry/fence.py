@@ -88,13 +88,17 @@ class CompileFence:
 
     def count_traces(self, name: str, fn):
         """Wrap a to-be-jitted python function so each TRACE increments
-        ``trace_counts[name]`` (the DecodeEngine ``counted`` idiom)."""
+        ``trace_counts[name]`` (the DecodeEngine ``counted`` idiom). The
+        wrapper is called ``name``: ``jax.jit`` names the program after
+        the function it is given, so traces and profiles show
+        ``jit_train_step``, not ``jit_wrapped``."""
         self.trace_counts.setdefault(name, 0)
 
         def wrapped(*args, **kwargs):
             self.trace_counts[name] += 1
             return fn(*args, **kwargs)
 
+        wrapped.__name__ = wrapped.__qualname__ = name
         return wrapped
 
     # ---------------------------------------------------- event ingestion

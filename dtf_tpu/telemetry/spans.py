@@ -16,17 +16,23 @@ tests/test_telemetry.py's counter-instrumented fit).
 The device half is :func:`step_annotation`:
 ``jax.profiler.StepTraceAnnotation`` around each loop iteration stamps the
 step number into the XPlane timeline, so a ProfilerHook trace window lines
-up 1:1 with the host spans recorded for the same steps.
+up 1:1 with the host spans recorded for the same steps. Its serving twin is
+:func:`trace_annotation`: the ``dtf.serve.*`` / ``dtf.engine.*`` phases of
+a scheduler tick, written into the same timeline.
 """
 
 from __future__ import annotations
 
 import collections
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Iterator, Mapping
 
 from dtf_tpu.metrics import quantile
+
+#: what a gated-off phase enters instead of a :func:`trace_annotation`: one
+#: reusable null context, so the untraced hot path constructs nothing
+NO_SPAN = nullcontext()
 
 #: per-phase sample retention: enough for tight quantiles over a long run
 #: without per-step memory growth (a ring, like the flight recorder).
@@ -103,3 +109,18 @@ def step_annotation(step: int, name: str = "train"):
     import jax
 
     return jax.profiler.StepTraceAnnotation(name, step_num=step)
+
+
+def trace_annotation(name: str, **ids):
+    """``jax.profiler.TraceAnnotation`` under one of the program's ``dtf.*``
+    names (docs/OBSERVABILITY.md section 7 lists them): a host phase on the
+    profiler's own clock, beside the device events it caused, so a trace
+    reader can say under which phase the device sat idle. Imported lazily
+    for the same reason as :func:`step_annotation`: the serve scheduler
+    reaches it from here and stays jax-free at import. Callers gate it
+    (``Scheduler(telemetry=)``, ``DecodeEngine.annotate_traces``); this
+    function always constructs one.
+    """
+    import jax
+
+    return jax.profiler.TraceAnnotation(name, **ids)

@@ -18,6 +18,7 @@ without a chip.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -58,9 +59,10 @@ def on_chip(topo):
                                                      sharding=one_chip)
 
 
-def _compiles_to_kernel(fn, *args):
+def _compiles_to_kernel(fn, *args) -> str:
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 def _flash(window, **shape):
@@ -146,3 +148,37 @@ def test_embedding_gather_compiles(on_chip):
     table = on_chip((1_000_000, 64), jnp.float32)
     ids = on_chip((4096, 26), jnp.int32)
     _compiles_to_kernel(embed_gather.gather_rows, table, ids)
+
+
+def _named_kernel_cases(on_chip):
+    """case -> (function, arguments, the ``dtf_*`` names its kernels carry)."""
+    b, h, t, d = FLASH_SHAPES["medium_s1024"]
+    q = on_chip((b, h, t, d), jnp.bfloat16)
+    attn = _flash(0, seq=t, heads=h, head_dim=d)
+    return {
+        "flash_fwd": (attn, (q, q, q), ["dtf_flash_fwd"]),
+        "flash_bwd": (
+            lambda q, k, v: jax.grad(
+                lambda *a: jnp.sum(attn(*a).astype(jnp.float32)),
+                argnums=(0, 1, 2))(q, k, v),
+            (q, q, q), ["dtf_flash_fwd", "dtf_flash_dq", "dtf_flash_dkv"]),
+    }
+
+
+@pytest.mark.parametrize("case", ["flash_fwd", "flash_bwd"])
+def test_kernels_carry_their_names(on_chip, case):
+    """Each flash-attention ``pl.pallas_call`` (the kernels the benchmark's
+    cells run) carries its ``dtf_*`` name in the name of its instruction
+    in the compiled program, which is what the profiler's ``XLA Ops``
+    events start with and how the benchmark's ``kernel_roofline`` reader
+    (and a person reading a trace) finds it:
+    ``%dtf_flash_fwd.1`` called plainly or under a module's scope,
+    ``%jvp_dtf_flash_fwd_.1`` / ``%transpose_jvp_dtf_flash_dq__.1`` called
+    bare under ``jax.grad`` as here. Without ``name=`` it is the enclosing
+    scope alone (``%attention.1``, ``%jvp__.1``), the same for every
+    kernel of a module."""
+    fn, args, names = _named_kernel_cases(on_chip)[case]
+    text = _compiles_to_kernel(fn, *args)
+    for name in names:
+        assert re.search(rf"^\s*%\w*{name}\w*(\.\d+)? = .*tpu_custom_call",
+                         text, re.M), name

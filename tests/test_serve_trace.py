@@ -319,3 +319,134 @@ def test_standalone_scheduler_registers_own_provider():
     sched.submit(Request(prompt=[1], max_new=5))
     post = tel.dump_postmortem("sigterm")
     assert post["context"]["serve_scheduler"]["queue_depth"] == 1
+
+
+# --------------------------------------------------------------------------
+# the dtf.* spans of a tick, on the profiler's clock (ISSUE 24)
+# --------------------------------------------------------------------------
+
+#: every span of docs/OBSERVABILITY.md section 7's table, and its parent
+DTF_SPANS = {
+    "dtf.serve.tick": None,
+    "dtf.serve.prefill_chunk": "dtf.serve.tick",
+    "dtf.serve.decode": "dtf.serve.tick",
+    "dtf.engine.prefill.dispatch": "dtf.serve.prefill_chunk",
+    "dtf.engine.prefill.readback": "dtf.serve.prefill_chunk",
+    "dtf.engine.decode.dispatch": "dtf.serve.decode",
+    "dtf.engine.decode.readback": "dtf.serve.decode",
+}
+
+
+@pytest.fixture(scope="module")
+def tiny_engine():
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from dtf_tpu.models import gpt
+    from dtf_tpu.serve import DecodeEngine
+
+    cfg = gpt.GPTConfig.tiny(dtype=jnp.float32)
+    model = gpt.GPT(dataclasses.replace(cfg, decode_len=MAX_LEN))
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 1), jnp.int32))["params"]
+    return DecodeEngine(cfg, params, n_slots=2, max_len=MAX_LEN,
+                        prefill_chunk=4)
+
+
+def _serve_some(sched, n_ticks=None):
+    for i in range(5):
+        # 6 tokens = two chunks of 4: a chunk that reads nothing back, and
+        # a last chunk that does
+        sched.submit(Request(prompt=[1 + i, 2, 3, 4, 5, 6], max_new=3))
+    if n_ticks is None:
+        sched.run_until_idle()
+    else:
+        for _ in range(n_ticks):
+            sched.tick()
+
+
+def test_traced_tick_writes_every_dtf_span_nested_under_its_cause(
+        tiny_engine):
+    """A tiny engine under the profiler on the CPU (as the benchmark's
+    ``--rehearse 1 --trace 1`` runs are), switched on by the two handles
+    the benchmark uses: every span of the table is in the trace, read by
+    the benchmark's own loader, and each lies inside a span of its
+    parent's name — so every engine span lies inside a tick."""
+    from benchmarks.lib import xtrace
+
+    sched = Scheduler(tiny_engine, telemetry=Telemetry(watchdog=False))
+    tiny_engine.annotate_traces = True
+    try:
+        trace_dir = xtrace.start()
+        _serve_some(sched)
+        trace = xtrace.stop(trace_dir)
+    finally:
+        tiny_engine.annotate_traces = False
+    by_name = {}
+    for name, start, dur in trace.host:
+        by_name.setdefault(name, []).append((start, start + dur))
+    assert set(DTF_SPANS) <= set(by_name), set(DTF_SPANS) - set(by_name)
+    # nothing else claims the vocabulary
+    assert {n for n in by_name if n.startswith("dtf.")} == set(DTF_SPANS)
+    for name, parent in DTF_SPANS.items():
+        if parent is None:
+            continue
+        for s, e in by_name[name]:
+            assert any(ps <= s and e <= pe for ps, pe in by_name[parent]), (
+                name, parent)
+    ticks = len(by_name["dtf.serve.tick"])
+    # at most one decode a tick, and one of each of its phases a decode
+    assert len(by_name["dtf.serve.decode"]) <= ticks
+    assert (len(by_name["dtf.engine.decode.dispatch"])
+            == len(by_name["dtf.engine.decode.readback"])
+            == len(by_name["dtf.serve.decode"]))
+    # five requests of two chunks: every chunk dispatches, the last of
+    # each request reads its first token back
+    assert len(by_name["dtf.serve.prefill_chunk"]) == 10
+    assert len(by_name["dtf.engine.prefill.dispatch"]) == 10
+    assert len(by_name["dtf.engine.prefill.readback"]) == 5
+    # the span-recorder keys the accepted metrics read are as they were
+    rolled = sched.telemetry.spans.rollup()
+    assert rolled["serve_prefill_chunk"]["count"] == 10
+    assert rolled["serve_decode"]["count"] == len(by_name["dtf.serve.decode"])
+    assert not any(k.startswith("dtf.") for k in rolled)
+
+
+@pytest.mark.parametrize("telemetry,annotate,expect_some", [
+    (False, False, False),     # the measured run: nothing is constructed
+    (True, False, True),       # the scheduler's tick span only
+    (False, True, True),       # engine spans only
+])
+def test_untraced_ticks_construct_no_annotation(tiny_engine, monkeypatch,
+                                                telemetry, annotate,
+                                                expect_some):
+    """With ``telemetry=None`` and ``annotate_traces=False`` — how the
+    benchmark measures and how a user's default serves — 20 ticks
+    construct no ``jax.profiler.TraceAnnotation`` at all; each gate
+    switches on its own layer's spans and not the other's."""
+    import jax
+
+    made = []
+
+    class Counted(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            made.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counted)
+    sched = Scheduler(tiny_engine, telemetry=Telemetry(watchdog=False)
+                      if telemetry else None)
+    monkeypatch.setattr(tiny_engine, "annotate_traces", annotate)
+    _serve_some(sched, n_ticks=20)
+    assert sched.stats()["serve_completed"] == 5
+    if not expect_some:
+        assert made == []
+        return
+    if telemetry:
+        assert made == ["dtf.serve.tick"] * 20
+    else:
+        # dtf.serve.prefill_chunk / .decode are the engine's whole-call spans
+        assert {name.split(".")[1] for name in made} == {"serve", "engine"}
+        assert "dtf.serve.tick" not in made

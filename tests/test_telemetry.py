@@ -22,6 +22,7 @@ import optax
 import pytest
 
 from dtf_tpu.core import train as tr
+from dtf_tpu.core.comms import shard_batch
 from dtf_tpu.hooks import Hook, LoggingHook, ProfilerHook, StopAtStepHook
 from dtf_tpu.loop import Trainer
 from dtf_tpu.metrics import MetricWriter, quantile
@@ -77,6 +78,49 @@ def test_trainer_trace_counts_pinned_steady_state(mesh8):
 def test_trainer_without_telemetry_has_empty_trace_counts(mesh8):
     state, step = build(mesh8)
     assert Trainer(step, mesh8).trace_counts == {}
+
+
+@pytest.mark.parametrize("name", ["train_step", "eval_step"])
+def test_count_traces_names_the_wrapper_after_the_program(name):
+    """``jax.jit`` names a program after the function it is given, and the
+    name is what every trace, xprof view and ``XLA Modules`` event shows: a
+    fenced step must not be called ``wrapped``."""
+    tel = Telemetry(watchdog=False)
+    counted = tel.count_traces(name, lambda x: x + 1)
+    assert counted.__name__ == name and counted.__qualname__ == name
+    assert jax.jit(counted).lower(1.0).as_text().startswith(
+        f"module @jit_{name} ")
+    assert tel.trace_counts == {name: 1}
+
+
+def test_fenced_train_step_lowers_as_jit_train_step(mesh8):
+    """The program the trainer runs under telemetry is ``jit_train_step``
+    (the benchmark's ``step_device_ms_p50`` and a person reading a trace
+    both find it by that name), and naming it did not add a trace."""
+    tel = Telemetry(watchdog=False)
+    state, step = build(mesh8, telemetry=tel)
+    lowered = step.lower(state, shard_batch(make_batch(), mesh8))
+    assert lowered.as_text().startswith("module @jit_train_step ")
+    assert "HloModule jit_train_step" in lowered.compile().as_text()
+    assert tel.trace_counts == {"train_step": 1}
+
+
+def test_serve_programs_lower_as_jit_decode_fn_and_jit_prefill_fn():
+    """The names the ledger's serve traces carry and the benchmark's
+    ``decode_device_ms_p50`` / ``prefill_chunk_device_ms_p50`` match:
+    pinned here, so that renaming the engine's step bodies fails a test
+    and not a metric."""
+    import jax.numpy as jnp
+
+    from dtf_tpu.models import gpt
+    from dtf_tpu.serve.engine import program_table
+
+    programs, _ = program_table(gpt.GPTConfig.tiny(dtype=jnp.float32),
+                                n_slots=2, max_len=16, prefill_chunk=4)
+    assert programs["decode"].lower().as_text().startswith(
+        "module @jit_decode_fn ")
+    assert programs["prefill"].lower().as_text().startswith(
+        "module @jit_prefill_fn ")
 
 
 # --------------------------------------------------------------------------
