@@ -308,24 +308,39 @@ def _cache_put_dyn(cfg, cvar, svar, slot, a) -> None:
             svar.value, s, slot, axis=2)
 
 
-def _cache_put_span(cfg, cvar, svar, positions, a, active, cache_len) -> None:
-    """Per-row multi-position cache write (the slot VERIFY step): batch row
-    b writes ``a[b, :, j, :]`` at its own absolute position
-    ``positions[b, j]`` — slot = position, the full-cache layout this mode
-    requires. Positions at or past the cache end, and every position of an
-    inactive row, are pointed at the out-of-range sentinel and DROPPED
-    (never wrapped): a wrapped write would clobber live early positions
-    with speculative K/V that a rejected tail could not roll back."""
-    b = positions.shape[0]
-    rows = jnp.arange(b)[:, None]                              # [B, 1]
-    drop = positions >= cache_len
-    if active is not None:
-        drop = drop | ~active[:, None]
-    slots = jnp.where(drop, cache_len, positions)              # OOB = drop
+def _cache_put_rows(cfg, cvar, svar, positions, a, active=None) -> None:
+    """Per-row cache write (the ``slot_decode`` steps): batch row b writes
+    ``a[b, :, j, :]`` at its own cache slot ``positions[b, j]`` — the
+    vectorized counterpart of :func:`_cache_put_dyn` for per-slot cache
+    indices. ``a`` is [B,H,t,D], ``positions`` [B,t]: t = 1 is the decode
+    step (the caller passes the rolled slot ``idx % cache_len``), t > 1
+    the speculative VERIFY step (slot = absolute position, the full-cache
+    layout that mode requires).
 
-    def put(var, upd):                                         # upd [B,H,t,D]
-        var.value = var.value.at[rows, :, slots, :].set(
-            upd.transpose(0, 2, 1, 3), mode="drop")
+    Spelled as position-mask selects over the leaf, not a scatter: the TPU
+    compiler keeps a cache leaf with POSITION as the minor (lane)
+    dimension and scatters only with position major, so
+    ``.at[rows, :, slots, :].set`` cost two relayouts of the whole cache
+    per step (PERF.md §6, PR 25). The t selects fuse into one elementwise
+    pass on the leaf as it lies — in place when the caller donates the
+    cache — and store the same values at the same positions. A position
+    at or past the cache end matches no slot and is DROPPED, never
+    wrapped (a wrapped verify write would clobber live early positions
+    with speculative K/V that a rejected tail could not roll back).
+    ``active`` [B] bool folds into the mask: an inactive row (a slot
+    mid-prefill) matches nothing, so it rides the fixed-shape step
+    untouched."""
+    lane = jnp.arange(cvar.value.shape[2])
+
+    def put(var, upd):                                 # upd [B,H,t,D|1]
+        out = var.value
+        for j in range(upd.shape[2]):
+            hit = lane == positions[:, j, None]                    # [B, L]
+            if active is not None:
+                hit = hit & active[:, None]
+            out = jnp.where(hit[:, None, :, None], upd[:, :, j:j + 1, :],
+                            out)
+        var.value = out
 
     if svar is None:
         put(cvar, a.astype(cfg.dtype))
@@ -333,30 +348,6 @@ def _cache_put_span(cfg, cvar, svar, positions, a, active, cache_len) -> None:
         q, s = _kv_quant(a)
         put(cvar, q)
         put(svar, s)
-
-
-def _cache_put_rows(cfg, cvar, svar, slots, a, active=None) -> None:
-    """Per-row single-slot cache write (the ``slot_decode`` step): batch row
-    b writes its own slot ``slots[b]`` — the vectorized counterpart of
-    :func:`_cache_put_dyn` for per-slot cache indices. ``a`` is [B,H,1,D];
-    the two advanced indices (rows, slots) land the [B,H,D] update.
-    ``active`` [B] bool masks the write per row (inactive rows scatter
-    their CURRENT slot contents back — a gather+scatter no-op — so a slot
-    mid-prefill rides the fixed-shape decode step untouched)."""
-    rows = jnp.arange(a.shape[0])
-
-    def put(var, upd):
-        if active is not None:
-            cur = var.value[rows, :, slots, :]
-            upd = jnp.where(active[:, None, None], upd, cur)
-        var.value = var.value.at[rows, :, slots, :].set(upd)
-
-    if svar is None:
-        put(cvar, a[:, :, 0, :].astype(cfg.dtype))
-    else:
-        q, s = _kv_quant(a)
-        put(cvar, q[:, :, 0, :])
-        put(svar, s[:, :, 0, :])
 
 
 class CausalSelfAttention(nn.Module):
@@ -486,7 +477,7 @@ class CausalSelfAttention(nn.Module):
             # reductions); the TESTED contract is token-stream identity,
             # exactly like chunked vs one-shot prefill's decode
             # continuation. Writes past the cache end DROP (never wrap —
-            # _cache_put_span): their queries' tokens sit past the slot
+            # _cache_put_rows): their queries' tokens sit past the slot
             # budget and are never delivered. The caller rolls cache_index
             # back to the accepted boundary afterwards (cache_rollback);
             # rejected-tail K/V needs no clearing — validity is derived
@@ -499,10 +490,8 @@ class CausalSelfAttention(nn.Module):
             q = rope(q, qpos, cfg.rope_theta)
             k = rope(k, qpos, cfg.rope_theta)
             if is_initialized:
-                _cache_put_span(cfg, ck, sk, qpos, k,
-                                active=decode_active, cache_len=cache_len)
-                _cache_put_span(cfg, cv, sv, qpos, v,
-                                active=decode_active, cache_len=cache_len)
+                _cache_put_rows(cfg, ck, sk, qpos, k, active=decode_active)
+                _cache_put_rows(cfg, cv, sv, qpos, v, active=decode_active)
                 ci.value = (idx + t if decode_active is None
                             else idx + t * decode_active.astype(jnp.int32))
             slots = jnp.arange(cache_len)
@@ -631,9 +620,9 @@ class CausalSelfAttention(nn.Module):
                     # inactive slot (mid-prefill in the serving engine)
                     # neither writes its cache nor advances its index, so
                     # the fixed-shape all-slots step cannot corrupt it.
-                    _cache_put_rows(cfg, ck, sk, slot, k,
+                    _cache_put_rows(cfg, ck, sk, slot[:, None], k,
                                     active=decode_active)
-                    _cache_put_rows(cfg, cv, sv, slot, v,
+                    _cache_put_rows(cfg, cv, sv, slot[:, None], v,
                                     active=decode_active)
                     ci.value = (idx + 1 if decode_active is None
                                 else idx + decode_active.astype(jnp.int32))

@@ -52,7 +52,8 @@ docs/RESILIENCE.md "Serving" + §9 walk the failure semantics.
 
 from dtf_tpu.serve.client import (Heartbeat, PoissonLoadGen, ServeClient,
                                   replay)
-from dtf_tpu.serve.engine import DecodeEngine, decode_step_view
+from dtf_tpu.serve.engine import (DecodeEngine, EngineStateLost,
+                                  decode_step_view)
 from dtf_tpu.serve.health import (HealthConfig, HealthTracker,
                                   install_serve_fault)
 from dtf_tpu.serve.pages import PageStore, PrefixIndex
@@ -60,8 +61,8 @@ from dtf_tpu.serve.router import Router, SwapConfig
 from dtf_tpu.serve.scheduler import (FAILED_STATUSES, Request,
                                      RequestFailed, Scheduler)
 
-__all__ = ["DecodeEngine", "FAILED_STATUSES", "Heartbeat", "HealthConfig",
-           "HealthTracker", "PageStore", "PoissonLoadGen", "PrefixIndex",
-           "Request", "RequestFailed", "Router", "Scheduler", "ServeClient",
-           "SwapConfig", "decode_step_view", "install_serve_fault",
-           "replay"]
+__all__ = ["DecodeEngine", "EngineStateLost", "FAILED_STATUSES", "Heartbeat",
+           "HealthConfig", "HealthTracker", "PageStore", "PoissonLoadGen",
+           "PrefixIndex", "Request", "RequestFailed", "Router", "Scheduler",
+           "ServeClient", "SwapConfig", "decode_step_view",
+           "install_serve_fault", "replay"]

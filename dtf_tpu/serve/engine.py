@@ -62,8 +62,29 @@ requests.
 Because all programs are compiled executables, steady state CANNOT
 recompile — a shape change would be a loud call-site error, not a silent
 retrace (``trace_counts`` exposes the per-program trace counters the fence
-test pins). The engine does not donate its state: every step writes a new
-cache (unmeasured; a candidate once a decode cell exists — PERF.md §7).
+test pins).
+
+**The state is donated** (PR 25): ``prefill``, ``decode``/``verify``, the
+draft twins and ``page_load`` take the engine state as a donated argument
+and return its successor, so the KV cache is updated IN PLACE — no program
+copies or relayouts a whole cache leaf (the slot-decode write is a
+position-mask select on the leaf as it lies, ``gpt._cache_put_rows``; the
+described-v5e compile fence in tests/test_chip_compile.py pins both).
+``page_save`` is left alone: it returns the pool, which several engines
+may mount. What donation means for the host:
+
+- a call CONSUMES ``self._state`` and rebinds it from the result in the
+  same statement; nothing else keeps a state array across a call (take a
+  host COPY, ``np.array``, if you need one — the old arrays are deleted);
+- everything that can REFUSE a request raises BEFORE the dispatch —
+  ``prefill_chunk_into``'s validation, the AOT executable's shape and
+  sharding checks, the chaos injectors that wrap ``decode`` /
+  ``prefill_chunk_into`` / ``draft_propose`` — so the scheduler's "fail the
+  request, keep the replica" path still holds a live state;
+- a failure INSIDE a dispatched program loses the state it was given. The
+  next call says so with :class:`EngineStateLost` instead of JAX's "Array
+  has been deleted". Such a replica cannot serve again: it is the Router's
+  to retire, which is where decode-path exceptions already go.
 
 Sharded serving: pass ``mesh`` and TP-sharded params — the cache lands
 ``P('data','model')`` (:func:`dtf_tpu.models.gpt.cache_shardings`: slots
@@ -97,6 +118,12 @@ from dtf_tpu.telemetry.spans import NO_SPAN, trace_annotation
 log = logging.getLogger("dtf_tpu")
 
 PyTree = Any
+
+
+class EngineStateLost(RuntimeError):
+    """The engine's donated state did not come back: a program failed
+    after it was dispatched, and the cache it was given went with it."""
+
 
 #: engine state keys that are flat per-slot arrays (leading dim n_slots),
 #: next to the "cache" collection. One registry so the state builder, the
@@ -745,6 +772,20 @@ class DecodeEngine:
     def _pages(self, pool):
         self._page_store.pool = pool
 
+    @staticmethod
+    def _live(state: PyTree) -> PyTree:
+        """``state``, if the last program that was given it returned: the
+        programs donate their state, so a call that raised after its
+        dispatch left ``self._state`` naming deleted arrays (module
+        docstring). One host-side flag test, no device work."""
+        if state["tok"].is_deleted():
+            raise EngineStateLost(
+                "the engine state was donated to a program that failed "
+                "after dispatch, so its KV cache is gone: this engine "
+                "cannot serve again — retire the replica (the Router "
+                "quarantines it) and build a new engine")
+        return state
+
     def n_chunks(self, prompt_len: int) -> int:
         return math.ceil(prompt_len / self.prefill_chunk)
 
@@ -802,7 +843,7 @@ class DecodeEngine:
                 buf = np.zeros((c,), np.int32)
                 buf[:len(seg)] = seg
                 self._state, out = self._prefill_c(
-                    self._params, self._state, np.int32(slot),
+                    self._params, self._live(self._state), np.int32(slot),
                     np.int32(start), buf, np.int32(len(seg)),
                     np.bool_(chunk_i == 0), np.bool_(last),
                     np.float32(temperature), np.int32(top_k),
@@ -863,7 +904,8 @@ class DecodeEngine:
             buf = np.zeros((c,), np.int32)
             buf[:len(seg)] = seg
             self._draft_state, _ = self._draft_prefill_c(
-                self._draft_params, self._draft_state, np.int32(slot),
+                self._draft_params, self._live(self._draft_state),
+                np.int32(slot),
                 np.int32(start), buf, np.int32(len(seg)),
                 np.bool_(chunk_i == 0), np.bool_(chunk_i == n_d - 1),
                 np.float32(0.0), np.int32(0), np.float32(1.0),
@@ -909,7 +951,8 @@ class DecodeEngine:
             if self.spec_k:
                 return self._decode_spec()
             with self._annotation("dtf.engine.decode.dispatch"):
-                self._state, out = self._decode_c(self._params, self._state)
+                self._state, out = self._decode_c(
+                    self._params, self._live(self._state))
             self.counters["decode_steps"] += 1
             with self._annotation("dtf.engine.decode.readback"):
                 return np.asarray(out["token"]), np.asarray(out["done"])
@@ -921,8 +964,8 @@ class DecodeEngine:
         :meth:`decode` so chaos injectors can wrap it — a poisoned draft
         must fall back to plain decode, not error the request."""
         self._draft_state, props = self._draft_c(
-            self._draft_params, self._draft_state, self._spec_tok,
-            self._spec_index)
+            self._draft_params, self._live(self._draft_state),
+            self._spec_tok, self._spec_index)
         self.counters["draft_steps"] += 1
         return props
 
@@ -930,6 +973,8 @@ class DecodeEngine:
         with self._annotation("dtf.engine.decode.dispatch"):
             try:
                 props = self.draft_propose()
+            except EngineStateLost:
+                raise       # no draft cache to fall back from: retire
             except Exception as e:  # noqa: BLE001 — a draft failure must
                 # not fail requests: the verify step is CORRECT for
                 # arbitrary proposals (worst case it emits 1 token — plain
@@ -939,8 +984,8 @@ class DecodeEngine:
                             "decode this tick", e)
                 self.counters["draft_fallbacks"] += 1
                 props = np.zeros((self.n_slots, self.spec_k), np.int32)
-            self._state, out = self._decode_c(self._params, self._state,
-                                              props)
+            self._state, out = self._decode_c(
+                self._params, self._live(self._state), props)
         self.counters["decode_steps"] += 1
         with self._annotation("dtf.engine.decode.readback"):
             toks = np.asarray(out["tokens"])
@@ -1100,8 +1145,8 @@ class DecodeEngine:
         most of it back as host dispatch overhead)."""
         ids = [e.page_id for e in handle.entries]
         self._state = self._page_load_c(
-            self._state, self._pages, np.int32(slot), self._ids_buf(ids),
-            np.int32(len(ids)))
+            self._live(self._state), self._pages, np.int32(slot),
+            self._ids_buf(ids), np.int32(len(ids)))
         self.counters["pages_loaded"] += len(ids)
         if self.spec_k and self._draft_self:
             # self-speculation: the draft cache is struct-identical, so
@@ -1109,7 +1154,7 @@ class DecodeEngine:
             # draft's prefill then covers only the uncached tail, like
             # the target's (no draft page programs exist or are needed)
             self._draft_state = self._page_load_c(
-                self._draft_state, self._pages, np.int32(slot),
+                self._live(self._draft_state), self._pages, np.int32(slot),
                 self._ids_buf(ids), np.int32(len(ids)))
             self._draft_pending[slot] = handle.n_tokens
             self.counters["draft_pages_loaded"] += len(ids)
@@ -1143,8 +1188,8 @@ class DecodeEngine:
             return
         buf = self._ids_buf([0] * have + ids)
         self._pages = self._page_save_c(
-            self._state, self._pages, np.int32(slot), buf, np.int32(have),
-            np.int32(have + len(ids)))
+            self._live(self._state), self._pages, np.int32(slot), buf,
+            np.int32(have), np.int32(have + len(ids)))
         self.counters["pages_saved"] += len(ids)
 
     def release_prefix(self, handle) -> None:
@@ -1163,7 +1208,7 @@ class DecodeEngine:
         if self._prefix is None:
             return
         buf = self._ids_buf([])
-        self._state = self._page_load_c(self._state, self._pages,
+        self._state = self._page_load_c(self._live(self._state), self._pages,
                                         np.int32(0), buf, np.int32(0))
         self._pages = self._page_save_c(self._state, self._pages,
                                         np.int32(0), buf, np.int32(0),
@@ -1239,7 +1284,9 @@ def program_table(cfg: gpt.GPTConfig, *, n_slots: int, max_len: int,
     :class:`dtf_tpu.core.executor.Program`s with their operand abstracts
     registered; ``models`` the matching flax modules. ``counts`` is the
     shared trace fence dict (``DecodeEngine.trace_counts``). ``probe()``
-    needs no entry: it replays the compiled decode program.
+    needs no entry: it replays the compiled decode program. Every program
+    DONATES its state operand (argument 1) — the in-place cache update;
+    the module docstring has what that asks of the caller.
     """
     base = dataclasses.replace(cfg, decode_len=max_len, slot_decode=False,
                                chunked_prefill=False)
@@ -1277,23 +1324,24 @@ def program_table(cfg: gpt.GPTConfig, *, n_slots: int, max_len: int,
         verify_kw["out_shardings"] = (state_sh,
                                       {"tokens": rep, "done": rep,
                                        "n_emit": rep})
+    donate_state = {"donate": True, "donate_args": (1,)}
     programs = {}
     if spec_k:
         props_abs = jax.ShapeDtypeStruct((n_slots, spec_k), jnp.int32,
                                          sharding=rep)
         executor.program(
             "decode", _build_verify_fn(models["decode"], spec_k),
-            counts=counts, jit_kw=verify_kw,
+            counts=counts, jit_kw=verify_kw, **donate_state,
             abstract_args=(abs_params, abs_state, props_abs),
             table=programs)
     else:
         executor.program(
             "decode", _build_decode_fn(models["decode"]),
-            counts=counts, jit_kw=jit_kw,
+            counts=counts, jit_kw=jit_kw, **donate_state,
             abstract_args=(abs_params, abs_state), table=programs)
     executor.program(
         "prefill", _build_prefill_fn(models["prefill"]),
-        counts=counts, jit_kw=jit_kw,
+        counts=counts, jit_kw=jit_kw, **donate_state,
         abstract_args=(abs_params, abs_state) + prefill_tail,
         table=programs)
     if spec_k:
@@ -1318,12 +1366,12 @@ def program_table(cfg: gpt.GPTConfig, *, n_slots: int, max_len: int,
         vec_abs = jax.ShapeDtypeStruct((n_slots,), jnp.int32, sharding=rep)
         executor.program(
             "draft_prefill", _build_prefill_fn(models["draft_prefill"]),
-            counts=counts, jit_kw=dp_kw,
+            counts=counts, jit_kw=dp_kw, **donate_state,
             abstract_args=(abs_dparams, abs_dstate) + prefill_tail,
             table=programs)
         executor.program(
             "draft", _build_draft_fn(models["draft"], spec_k),
-            counts=counts, jit_kw=da_kw,
+            counts=counts, jit_kw=da_kw, **donate_state,
             abstract_args=(abs_dparams, abs_dstate, vec_abs, vec_abs),
             table=programs)
     return programs, models
@@ -1355,6 +1403,7 @@ def page_program_table(abs_state: PyTree, pool_abs: PyTree, *,
         table=programs)
     executor.program(
         "load", _build_page_load_fn(), counts=counts, jit_kw=load_kw,
+        donate=True,    # the state (argument 0); the pool is only read
         abstract_args=(abs_state, pool_abs, s_i32, ids_abs, s_i32),
         table=programs)
     return programs
@@ -1376,6 +1425,11 @@ def decode_step_view(cfg: gpt.GPTConfig, *, n_slots: int, max_len: int,
     # costs extra replication all-gathers the served per-tick graph never
     # runs (the engine feeds each output straight back in) — pinning here
     # would charge the comms budget for transfers that don't happen.
+    # For the same reason the view does not donate: without the pin GSPMD
+    # shards some outputs (rng, done) its own way, and a donated leaf
+    # whose output has another layout aliases nothing. Donation soundness
+    # of the PINNED table programs is checked on this mesh by
+    # tests/test_memory_analysis.py instead.
     view = executor.program("decode_view", prog.body,
                             abstract_args=(abs_params, abs_state))
     return view, abs_params, abs_state

@@ -6,7 +6,9 @@ may use. The compiler is installed without the chip and compiles for a
 DESCRIBED v5e, so these guard every later PR at no chip time — about two
 seconds each. A compile that passes is a compile, not a chip run:
 ``chip_smoke.py`` runs the same kernels on the chip against their dense
-references.
+references. The serve engine's two programs are fenced here too: what the
+compiler does to a whole KV-cache leaf (a copy, a relayout, a scatter) is
+only visible in the TPU's optimised HLO.
 
 All in ONE file on purpose (on-chip-measurement guide, section 2): only
 one process may load the TPU's library, so the topology is described
@@ -25,7 +27,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from dtf_tpu.models import gpt
 from dtf_tpu.ops import embed_gather, flash_attention as fa, fused_ce
+from dtf_tpu.serve import engine as serve_engine
 from dtf_tpu.tune import resolver
 
 VOCAB = 50304          # GPTConfig's vocab (TP-divisible GPT-2)
@@ -182,3 +186,57 @@ def test_kernels_carry_their_names(on_chip, case):
     for name in names:
         assert re.search(rf"^\s*%\w*{name}\w*(\.\d+)? = .*tpu_custom_call",
                          text, re.M), name
+
+
+# ---- the serve engine's programs: the KV cache is updated in place ---------
+
+SERVE = dict(n_slots=32, max_len=1024, prefill_chunk=128)   # the serve cell
+
+
+def _serve_program(on_chip, name, heads):
+    """``program_table``'s ``name`` at the serve cell's widths and slots, cut
+    to 2 layers and a 1024-token vocabulary (neither touches how a cache leaf
+    is written; the full vocabulary's sort alone compiles for 20 s), with
+    every operand on one described chip. Returns ``(compiled, state)``."""
+    cfg = gpt.GPTConfig(d_model=1024, layers=2, heads=heads, d_ff=4096,
+                        vocab_size=1024)
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda s: on_chip(s.shape, s.dtype), tree)
+    state = place(serve_engine.engine_state_struct(
+        cfg, n_slots=SERVE["n_slots"], max_len=SERVE["max_len"]))
+    model = gpt.GPT(cfg)
+    params = place(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32)))["params"])
+    programs, _ = serve_engine.program_table(
+        cfg, **SERVE, abs_trees={"params": params, "state": state})
+    prog = programs[name]
+    return prog.lower(*place(prog.abstract_args)).compile(), state
+
+
+@pytest.mark.parametrize("d_head", [64, 128])
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_serve_programs_update_the_cache_in_place(on_chip, name, d_head):
+    """PR 25's fence. Before it ``jit_decode_fn`` relayouted every cache leaf
+    twice a step around a scatter (the compiler keeps a leaf with position
+    as the minor dimension and scatters only with position major) and
+    ``jit_prefill_fn`` copied every leaf into its un-donated output: 44% of
+    the serve cell's device time. Now no instruction produces a whole leaf
+    by ``copy``, ``transpose`` or ``scatter``, the donated state is aliased
+    to the output whole, and the temporaries stay under one set of leaves.
+    (``copy-start``/``copy-done`` pairs are not counted: the compiler's
+    prefetch of a leaf into another memory space, the same bytes the
+    in-place pass reads and writes.) d_head 128 is fenced beside 64 so
+    neither layout pays for the other."""
+    heads = 1024 // d_head
+    compiled, state = _serve_program(on_chip, name, heads)
+    leaf = (f"[{SERVE['n_slots']},{heads},{SERVE['max_len']},{d_head}]")
+    whole_leaf = re.findall(
+        rf"^\s*(?:ROOT )?%\S+ = bf16{re.escape(leaf)}\S* "
+        rf"(copy|transpose|scatter)\(", compiled.as_text(), re.M)
+    assert not whole_leaf, whole_leaf
+
+    nbytes = lambda tree: sum(  # noqa: E731
+        s.size * s.dtype.itemsize for s in jax.tree.leaves(tree))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes(state)
+    assert mem.temp_size_in_bytes < nbytes(state["cache"])

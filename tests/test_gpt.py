@@ -905,3 +905,79 @@ def test_beam_composes_with_int8_rolling_cache():
     out2 = gpt.generate_beam(model, variables["params"], prompt, 10,
                              num_beams=3)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
+
+
+# (dtype, t, positions [B,t], active [B] or None): position 0, the last
+# position, a rolling-window slot that wrapped (idx % L), inactive rows, the
+# int8 cache with its scales, and the verify span with writes past the end
+_L = 8
+_PUT_ROWS_CASES = {
+    "bf16_first_last_inactive": (
+        "bf16", [[0], [_L - 1], [3], [5]], [True, True, False, True]),
+    "no_active_mask": ("bf16", [[2], [2], [0], [_L - 1]], None),
+    "rolling_window_wrapped": (
+        "bf16", [[(_L + 3) % _L], [(2 * _L - 1) % _L], [(5 * _L) % _L], [5]],
+        [True, True, True, False]),
+    "int8_with_scales": (
+        "int8", [[0], [_L - 1], [4], [4]], [True, False, True, True]),
+    "verify_span_drops_past_end": (
+        "bf16", [[0, 1, 2], [_L - 2, _L - 1, _L], [_L, _L + 1, _L + 2],
+                 [3, 4, 5]], [True, True, True, False]),
+    "verify_span_int8": (
+        "int8", [[5, 6, 7], [0, 1, 2], [_L - 1, _L, _L + 1], [2, 3, 4]],
+        None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PUT_ROWS_CASES))
+def test_cache_put_rows_matches_the_scatter(case):
+    """The slot-decode / verify cache write is a position-mask select
+    (layout-native on the TPU); it must store what the scatter it replaced
+    stored — ``cache[b, :, positions[b, j], :] = a[b, :, j, :]`` for active
+    rows, positions past the cache end dropped — bit for bit, and leave
+    every other element alone."""
+    import types
+
+    kind, positions, active = _PUT_ROWS_CASES[case]
+    positions = np.asarray(positions, np.int32)
+    b, t = positions.shape
+    h, d = 2, 4
+    rng = np.random.default_rng(0)
+    quant = kind == "int8"
+    cfg = gpt.GPTConfig.tiny(kv_cache_dtype="int8" if quant else "")
+    a = jnp.asarray(rng.normal(size=(b, h, t, d)), jnp.float32)
+    if quant:
+        cache0 = rng.integers(-127, 128, (b, h, _L, d)).astype(np.int8)
+        scale0 = rng.random((b, h, _L, 1)).astype(np.float32)
+        vals, scales = (np.asarray(x) for x in jax.jit(gpt._kv_quant)(a))
+    else:
+        cache0 = np.asarray(jnp.asarray(rng.normal(size=(b, h, _L, d)),
+                                        cfg.dtype))
+        scale0 = scales = None
+        vals = np.asarray(a.astype(cfg.dtype))
+
+    want, want_s = cache0.copy(), None if scale0 is None else scale0.copy()
+    for row in range(b):
+        if active is not None and not active[row]:
+            continue
+        for j in range(t):
+            pos = positions[row, j]
+            if pos < _L:                      # mode="drop" of the scatter
+                want[row, :, pos, :] = vals[row, :, j, :]
+                if quant:
+                    want_s[row, :, pos, :] = scales[row, :, j, :]
+
+    def put(cache, scale):
+        cvar = types.SimpleNamespace(value=cache)
+        svar = types.SimpleNamespace(value=scale) if quant else None
+        gpt._cache_put_rows(
+            cfg, cvar, svar, jnp.asarray(positions), a,
+            active=None if active is None else jnp.asarray(active))
+        return cvar.value, svar.value if quant else None
+
+    got, got_s = jax.jit(put)(jnp.asarray(cache0),
+                              jnp.asarray(scale0) if quant else None)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert got.dtype == cache0.dtype
+    if quant:
+        np.testing.assert_array_equal(np.asarray(got_s), want_s)

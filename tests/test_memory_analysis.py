@@ -130,6 +130,30 @@ def test_aliased_param_numbers_parses_header():
     assert mem.aliased_param_numbers("HloModule jit_f\nbody") == set()
 
 
+@pytest.mark.parametrize("name", ["decode", "prefill", "load"])
+def test_serve_programs_donate_their_state_soundly(name):
+    """The serve engine's programs donate their state (the in-place KV
+    cache, PR 25). On the ``gpt_serve`` mesh, with the engine's output
+    pins, every donated leaf must be aliased: a dropped one would be
+    deleted at dispatch with nowhere to go. (The analysis VIEWS of these
+    programs compile unpinned and so undonated — engine.decode_step_view
+    — which is why the real table is checked here.)"""
+    from dtf_tpu.models import gpt
+    from dtf_tpu.serve import engine, pages
+
+    mesh = cfgs.BY_NAME["gpt_serve"].mesh()
+    programs, _ = engine.program_table(gpt.GPTConfig.tiny(), n_slots=8,
+                                       max_len=64, mesh=mesh)
+    if name == "load":
+        state = programs["decode"].abstract_args[1]
+        pool = pages.pool_abstract(state["cache"], 4, 16, mesh)
+        programs = engine.page_program_table(
+            state, pool, n_pages=4, max_len=64, kv_page_size=16, mesh=mesh)
+    low = programs[name].lower()
+    assert any(mem.donated_flags(low))
+    assert not mem.donation_soundness(name, low, low.compile())
+
+
 # ------------------------------------------------ state accounting model
 
 def test_resident_model_matches_compiled_arguments_exactly():

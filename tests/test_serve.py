@@ -10,8 +10,8 @@ import numpy as np
 import pytest
 
 from dtf_tpu.models import gpt
-from dtf_tpu.serve import (DecodeEngine, PoissonLoadGen, Request, Scheduler,
-                           ServeClient)
+from dtf_tpu.serve import (DecodeEngine, EngineStateLost, PoissonLoadGen,
+                           Request, Scheduler, ServeClient)
 
 CFG = gpt.GPTConfig.tiny(dtype=jnp.float32)
 MAX_LEN = 48
@@ -290,6 +290,97 @@ def test_engine_and_config_validation(params):
     with pytest.raises(ValueError, match="slot_decode"):
         gpt.GPTConfig.tiny(slot_decode=True, decode_len=8,
                            chunked_prefill=True)
+
+
+def _cache_leaves(eng):
+    return jax.tree.leaves(eng._state["cache"])
+
+
+@pytest.mark.parametrize("call", ["decode", "prefill_chunk_into"])
+def test_state_is_donated_and_rebound(params, call):
+    """The in-place contract (engine docstring): a call consumes the state
+    it was given — every previous cache leaf is deleted — and the engine
+    holds the live successor; values held across a call are host copies."""
+    eng = DecodeEngine(CFG, params, n_slots=2, max_len=16, prefill_chunk=4)
+    eng.prefill(0, [1, 2, 3])
+    before = _cache_leaves(eng)
+    key = lambda e: e._state["cache"]["layer_0"]["attention"]["cached_key"]
+    # a host COPY outlives the call (np.asarray on the CPU backend is a
+    # zero-copy view that pins the buffer, and a pinned buffer is not
+    # donated)
+    kept = np.array(key(eng))
+    if call == "decode":
+        eng.decode()
+    else:
+        eng.prefill_chunk_into(1, [4, 5, 6, 7, 8], 0)
+    assert all(x.is_deleted() for x in before)
+    assert not any(x.is_deleted() for x in _cache_leaves(eng))
+    # slot 0's prompt positions came through the in-place update
+    np.testing.assert_array_equal(kept[0, :, :3],
+                                  np.asarray(key(eng))[0, :, :3])
+
+
+def test_refused_request_leaves_the_state_usable(params):
+    """What can refuse a request raises BEFORE the dispatch — validation
+    and the AOT executable's own operand checks — so the state survives
+    and the next request is served (the scheduler's fail-the-request,
+    keep-the-replica path)."""
+    eng = DecodeEngine(CFG, params, n_slots=2, max_len=16, prefill_chunk=4)
+    with pytest.raises(ValueError, match="prompt length"):
+        eng.prefill(0, list(range(16)))            # bad prompt length
+    assert not any(x.is_deleted() for x in _cache_leaves(eng))
+    # the same through the scheduler: a poisoned admission (refused before
+    # its dispatch, as the chaos injector does) fails that request alone
+    sched = Scheduler(eng, None)
+    real = eng.prefill_chunk_into
+
+    def poisoned(slot, prompt, chunk_i, **kw):
+        if list(prompt) == [9, 9, 9]:
+            raise RuntimeError("poison")
+        return real(slot, prompt, chunk_i, **kw)
+
+    eng.prefill_chunk_into = poisoned
+    bad = sched.submit(Request(prompt=[9, 9, 9], max_new=2))
+    good = sched.submit(Request(prompt=[1, 2, 3], max_new=4))
+    sched.run_until_idle()
+    assert sched.poll(bad)["status"] == "error"
+    assert sched.poll(good)["status"] == "done"
+    assert sched.poll(good)["tokens"] == _offline(
+        params, dict(prompt=[1, 2, 3], max_new=4))
+    # an operand the compiled program rejects (wrong chunk width) is
+    # rejected before the state is consumed
+    with pytest.raises((TypeError, ValueError)):
+        eng._prefill_c(eng._params, eng._state, np.int32(0), np.int32(0),
+                       np.zeros((3,), np.int32), np.int32(3), np.bool_(True),
+                       np.bool_(True), np.float32(0), np.int32(0),
+                       np.float32(1), np.int32(-1), np.int32(0),
+                       np.zeros((2,), np.uint32))
+    assert not any(x.is_deleted() for x in _cache_leaves(eng))
+    eng.decode()
+
+
+def test_failure_after_dispatch_raises_engine_state_lost(params):
+    """A program that fails AFTER it was given the state loses it: the
+    next call names that (EngineStateLost) instead of JAX's "Array has
+    been deleted", on every dispatching entry point."""
+    eng = DecodeEngine(CFG, params, n_slots=2, max_len=16, prefill_chunk=4)
+    eng.prefill(0, [1, 2, 3])
+    real = eng._decode_c
+
+    def fails_after_dispatch(*args):
+        real(*args)                       # consumes the donated state
+        raise RuntimeError("device fault")
+
+    eng._decode_c = fails_after_dispatch
+    with pytest.raises(RuntimeError, match="device fault"):
+        eng.decode()
+    eng._decode_c = real
+    with pytest.raises(EngineStateLost, match="retire the replica"):
+        eng.decode()
+    with pytest.raises(EngineStateLost):
+        eng.prefill(1, [4, 5])
+    with pytest.raises(EngineStateLost):
+        eng.probe()
 
 
 def test_filter_logits_dynamic_matches_static():
