@@ -20,6 +20,16 @@ Pre-LN blocks, RoPE positions (global positions, so they are correct under
 sequence sharding), optional grouped-query attention (``kv_heads`` — the
 KV cache shrinks by heads/kv_heads, the decode-memory lever), untied LM
 head, bf16 compute / f32 params.
+
+The same block also spells today's hybrid sparse decoders (PR 26), each
+departure one config field: ``norm="rmsnorm"``, ``ffn="swiglu"``,
+``qk_norm``, bias-free projections, a tied head, bfloat16 storage
+(``param_dtype``), a per-layer operator (``layer_kinds``: attention or the
+gated short convolution :class:`ShortConv`, whose recurrent state lives in
+the ``cache`` collection beside K/V) and dropless routed experts
+(``experts`` from layer ``dense_layers`` on —
+:class:`dtf_tpu.parallel.moe.DroplessMoE`). Forward and serving only:
+ROADMAP.md says what training them still lacks.
 """
 
 from __future__ import annotations
@@ -130,8 +140,56 @@ class GPTConfig:
     #: the first consumer (serve_gpt --draft_precision): the bf16
     #: verifier keeps emitted tokens byte-identical regardless.
     matmul_precision: str = ""
+    #: "layernorm" (bias and mean, flax's epsilon) or "rmsnorm" (weight
+    #: only, ``norm_eps``); float32 either way.
+    norm: str = "layernorm"
+    norm_eps: float = 1e-5
+    #: the dense FFN: "gelu" = ``W_out gelu(W_in x)`` with biases, "swiglu"
+    #: = ``W2 (silu(W1 x) * W3 x)`` (``mlp_gate`` / ``mlp_up`` / ``mlp_out``).
+    ffn: str = "gelu"
+    #: RMSNorm over each head's width on q and on k (learned weight),
+    #: before the rotary embedding.
+    qk_norm: bool = False
+    #: False = every projection bias-free.
+    use_bias: bool = True
+    #: the head is the token embedding, transposed (no ``lm_head`` leaf).
+    tie_head: bool = False
+    #: per-layer operator, ``"attn"`` or ``"conv"`` (the gated short
+    #: convolution); () = attention in every layer.
+    layer_kinds: tuple = ()
+    #: taps of the short convolution, and columns of its decode state.
+    conv_kernel: int = 3
+    #: dropless routed experts (parallel/moe.py DroplessMoE) as the FFN of
+    #: every layer from ``dense_layers`` on; layers before it keep the
+    #: dense FFN of width ``d_ff``. None = no such layer.
+    experts: Optional[moe_lib.ExpertsConfig] = None
+    dense_layers: int = 0
+    #: storage dtype of the matrices (embedding, projections, experts);
+    #: norm weights, the router and its bias stay float32.
+    param_dtype: jnp.dtype = jnp.float32
 
     def __post_init__(self):
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"norm={self.norm!r} must be layernorm or "
+                             "rmsnorm")
+        if self.ffn not in ("gelu", "swiglu"):
+            raise ValueError(f"ffn={self.ffn!r} must be gelu or swiglu")
+        if self.layer_kinds and (
+                len(self.layer_kinds) != self.layers
+                or set(self.layer_kinds) - {"attn", "conv"}):
+            raise ValueError(
+                f"layer_kinds={self.layer_kinds} must name 'attn' or "
+                f"'conv' for each of the {self.layers} layers")
+        if self.conv_kernel < 2:
+            raise ValueError(f"conv_kernel={self.conv_kernel} must be >= 2")
+        if self.experts is not None and self.moe_every:
+            raise ValueError(
+                "experts (dropless) and moe_every (Switch) are two expert "
+                "layers: pick one")
+        if not 0 <= self.dense_layers <= self.layers:
+            raise ValueError(
+                f"dense_layers={self.dense_layers} must be in [0, layers="
+                f"{self.layers}]")
         if self.kv_heads is not None and (
                 self.kv_heads < 1 or self.heads % self.kv_heads):
             raise ValueError(
@@ -175,6 +233,19 @@ class GPTConfig:
     @property
     def kv_heads_resolved(self) -> int:
         return self.heads if self.kv_heads is None else self.kv_heads
+
+    def layer_kind(self, layer: int) -> str:
+        return self.layer_kinds[layer] if self.layer_kinds else "attn"
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        """True when some layer keeps a running state in the cache (a conv
+        layer): the serving features that index the cache by POSITION
+        (prefix pages, speculative rollback) do not apply to it."""
+        return "conv" in self.layer_kinds
+
+    def layer_has_experts(self, layer: int) -> bool:
+        return self.experts is not None and layer >= self.dense_layers
 
     @staticmethod
     def by_name(name: str) -> "GPTConfig":
@@ -350,6 +421,14 @@ def _cache_put_rows(cfg, cvar, svar, positions, a, active=None) -> None:
         put(svar, s)
 
 
+def _norm(cfg: GPTConfig, name: str):
+    """The block's normalisation, float32: LayerNorm or RMSNorm."""
+    if cfg.norm == "rmsnorm":
+        return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
+                          param_dtype=jnp.float32, name=name)
+    return nn.LayerNorm(dtype=jnp.float32, name=name)
+
+
 class CausalSelfAttention(nn.Module):
     cfg: GPTConfig
     mesh: Optional[Mesh]
@@ -441,10 +520,13 @@ class CausalSelfAttention(nn.Module):
                    and not self.manual_seq)
         dense = lambda name, nh: comms.TpDense(  # noqa: E731
             nh * d_head, self.mesh, "column", overlap=overlap,
-            dtype=cfg.dtype, precision=cfg.matmul_precision, name=name)
+            use_bias=cfg.use_bias, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, precision=cfg.matmul_precision,
+            name=name)
         out_dense = lambda: comms.TpDense(  # noqa: E731
             cfg.d_model, self.mesh, "row", overlap=overlap,
-            dtype=cfg.dtype, precision=cfg.matmul_precision,
+            use_bias=cfg.use_bias, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, precision=cfg.matmul_precision,
             name="attn_out")
 
         def split(v, nh):
@@ -453,6 +535,13 @@ class CausalSelfAttention(nn.Module):
         q = split(dense("query", cfg.heads)(x), cfg.heads)
         k = split(dense("key", kv_heads)(x), kv_heads)
         v = split(dense("value", kv_heads)(x), kv_heads)
+        if cfg.qk_norm:
+            # over each head's width, before rope, in every branch below
+            head_norm = lambda name: nn.RMSNorm(  # noqa: E731
+                epsilon=cfg.norm_eps, dtype=jnp.float32,
+                param_dtype=jnp.float32, name=name)
+            q = head_norm("q_norm")(q).astype(cfg.dtype)
+            k = head_norm("k_norm")(k).astype(cfg.dtype)
 
         def expand_kv(a):
             # GQA: query head h reads shared K/V head h // group. jnp.repeat
@@ -761,12 +850,85 @@ class CausalSelfAttention(nn.Module):
         return nn.Dropout(cfg.dropout)(out, deterministic=deterministic)
 
 
+class ShortConv(nn.Module):
+    """Gated short convolution, the hybrid decoders' cheap operator:
+    ``[B, C, X] = split3(W_in u)``; ``z = B * X``; ``c_t = sum_j w[j] *
+    z_{t-(L-1)+j}`` (depthwise, causal, ``L = conv_kernel`` taps, zeros
+    before the sequence's start); ``out = W_out (C * c)``. No bias anywhere.
+
+    Decoding (``decode_len > 0``) keeps the last ``L`` columns of ``z`` per
+    row as ``conv_state`` [B, L, d] in the ``cache`` collection (width minor:
+    the TPU's lanes; [B, d, L] would pad 3 columns to 128). It is a RUNNING
+    state, not a position-indexed one, so K/V's habits do not carry over
+    (docs/SERVING.md): a stale state is read, so whoever re-uses a row must
+    zero it; a multi-token apply CONTINUES the state it finds (chunked
+    prefill, and one-shot prefill from the zeros ``init`` leaves); with
+    ``prefill_len`` the new state is the last ``L`` VALID columns, so the
+    pad of a ragged last chunk never enters it; ``decode_active`` rows alone
+    advance in the slot step."""
+
+    cfg: GPTConfig
+
+    @nn.compact
+    def __call__(self, x, prefill_len=None, decode_active=None):
+        cfg = self.cfg
+        b, t, d = x.shape
+        taps = cfg.conv_kernel
+        dense = lambda name, n: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            name=name)
+        gate_b, gate_c, xs = jnp.split(dense("in_proj", 3 * d)(x), 3, axis=-1)
+        z = gate_b * xs                                        # [B, t, d]
+        w = self.param("conv_w", nn.initializers.variance_scaling(
+            1.0, "fan_in", "normal", in_axis=0, out_axis=1), (taps, d),
+            jnp.float32)
+
+        def conv(cols):
+            """[B, taps - 1 + n, d] -> [B, n, d]: output i reads columns
+            i .. i + taps - 1."""
+            n = cols.shape[1] - (taps - 1)
+            return sum(w[j] * cols[:, j:j + n].astype(jnp.float32)
+                       for j in range(taps))
+
+        if cfg.decode_len > 0:
+            ready = self.has_variable("cache", "conv_state")
+            state = self.variable("cache", "conv_state", jnp.zeros,
+                                  (b, taps, d), cfg.dtype)
+            if t == 1:
+                new = jnp.concatenate([state.value[:, 1:], z], axis=1)
+                c = conv(new)
+                if ready and decode_active is not None:
+                    new = jnp.where(decode_active[:, None, None], new,
+                                    state.value)
+            else:
+                if cfg.slot_decode:
+                    raise ValueError(
+                        "the slot VERIFY step has no conv state to roll a "
+                        "rejected tail back to: speculative decoding does "
+                        "not serve a model with conv layers")
+                cols = jnp.concatenate([state.value, z], axis=1)
+                c = conv(cols[:, 1:])
+                new = jax.lax.dynamic_slice_in_dim(
+                    cols, t if prefill_len is None else prefill_len, taps,
+                    axis=1)
+            if ready:
+                state.value = new
+        else:
+            c = conv(jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0))))
+        y = gate_c.astype(jnp.float32) * c
+        return dense("out_proj", d)(y.astype(cfg.dtype))
+
+
 class Block(nn.Module):
     cfg: GPTConfig
     mesh: Optional[Mesh]
     use_moe: bool
     window: int  # no default — see CausalSelfAttention.window
     manual_seq: bool = False  # see CausalSelfAttention.manual_seq
+    #: the layer's operator: "attn" or "conv" (GPTConfig.layer_kinds)
+    op: str = "attn"
+    #: the FFN is the dropless routed-expert layer (GPTConfig.experts)
+    experts: bool = False
 
     @nn.compact
     def __call__(self, x, deterministic: bool, prefill_len=None,
@@ -774,31 +936,51 @@ class Block(nn.Module):
         cfg = self.cfg
         overlap = (cfg.tp_overlap and self.mesh is not None
                    and not self.manual_seq)
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln1")(x)
-        x = x + CausalSelfAttention(cfg, self.mesh, self.window,
-                                    manual_seq=self.manual_seq,
-                                    name="attention")(h, deterministic,
-                                                      prefill_len,
-                                                      decode_active)
+        h = _norm(cfg, "ln1")(x)
+        if self.op == "conv":
+            x = x + ShortConv(cfg, name="conv")(h, prefill_len,
+                                                decode_active)
+        else:
+            x = x + CausalSelfAttention(cfg, self.mesh, self.window,
+                                        manual_seq=self.manual_seq,
+                                        name="attention")(h, deterministic,
+                                                          prefill_len,
+                                                          decode_active)
         if overlap:
             x = comms.tp_token_sharded(x, self.mesh)
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln2")(x)
-        if self.use_moe:
+        h = _norm(cfg, "ln2")(x)
+        tp_dense = lambda name, n, parallel: comms.TpDense(  # noqa: E731
+            n, self.mesh, parallel, overlap=overlap, use_bias=cfg.use_bias,
+            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            precision=cfg.matmul_precision, name=name)
+        if self.experts:
+            # tokens that are not there (a slot that is not decoding, the
+            # pad of a ragged prefill chunk) choose no expert
+            live = None
+            if decode_active is not None:
+                live = jnp.broadcast_to(decode_active[:, None], x.shape[:2])
+            elif prefill_len is not None:
+                live = jnp.broadcast_to(
+                    jnp.arange(x.shape[1])[None, :] < prefill_len,
+                    x.shape[:2])
+            y = moe_lib.DroplessMoE(cfg.d_model, cfg.experts,
+                                    dtype=cfg.dtype,
+                                    param_dtype=cfg.param_dtype,
+                                    name="experts")(h, live)
+        elif self.use_moe:
             y = moe_lib.SwitchFFN(cfg.d_model, cfg.d_ff, cfg.moe,
                                   dtype=cfg.dtype, name="moe")(h)
+        elif cfg.ffn == "swiglu":
+            y = (nn.silu(tp_dense("mlp_gate", cfg.d_ff, "column")(h))
+                 * tp_dense("mlp_up", cfg.d_ff, "column")(h))
+            y = tp_dense("mlp_out", cfg.d_model, "row")(y)
         else:
             # the Megatron pair (collective matmuls under overlap; gelu
             # runs on the feature-sharded activations in between, and the
             # residual stream stays token-sharded over ('seq','model'))
-            y = comms.TpDense(cfg.d_ff, self.mesh, "column",
-                              overlap=overlap, dtype=cfg.dtype,
-                              precision=cfg.matmul_precision,
-                              name="mlp_in")(h)
+            y = tp_dense("mlp_in", cfg.d_ff, "column")(h)
             y = nn.gelu(y, approximate=True)
-            y = comms.TpDense(cfg.d_model, self.mesh, "row",
-                              overlap=overlap, dtype=cfg.dtype,
-                              precision=cfg.matmul_precision,
-                              name="mlp_out")(y)
+            y = tp_dense("mlp_out", cfg.d_model, "row")(y)
         y = nn.Dropout(cfg.dropout)(y, deterministic=deterministic)
         if overlap:
             # keep the residual stream in the Megatron-SP token-sharded
@@ -821,8 +1003,9 @@ class GPT(nn.Module):
                  decode_active=None):
         cfg = self.cfg
         overlap = cfg.tp_overlap and self.mesh is not None
-        x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
-                     param_dtype=jnp.float32, name="token_embed")(input_ids)
+        embed = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
+                         param_dtype=cfg.param_dtype, name="token_embed")
+        x = embed(input_ids)
         if overlap:
             # pin the embed OUTPUT to the baseline batch layout first (the
             # vocab-sharded masked-lookup + psum spelling, no table
@@ -838,9 +1021,11 @@ class GPT(nn.Module):
         for i in range(cfg.layers):
             use_moe = cfg.moe_every > 0 and (i + 1) % cfg.moe_every == 0
             x = block(cfg, self.mesh, use_moe, cfg.layer_window(i),
+                      op=cfg.layer_kind(i),
+                      experts=cfg.layer_has_experts(i),
                       name=f"layer_{i}")(x, deterministic, prefill_len,
                                          decode_active)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_f")(x)
+        x = _norm(cfg, "ln_f")(x)
         if return_hidden:
             # the chunked-loss path applies lm_head itself; the Dense
             # below must still exist at init time, which it does — init
@@ -851,6 +1036,11 @@ class GPT(nn.Module):
             # ACTIVATIONS come back over the TP axis for the vocab-parallel
             # head matmul — never the [D, V] head kernel.
             x = comms.tp_activation_gathered(x, self.mesh)
+        if cfg.tie_head:
+            # the table at its storage dtype, accumulated in float32
+            return jnp.einsum("btd,vd->btv", x.astype(cfg.dtype),
+                              embed.embedding.astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)
         logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
                           param_dtype=jnp.float32, name="lm_head")(x)
         return logits
@@ -1122,6 +1312,15 @@ def _prefill(model: GPT, params, cache0, prompt, prefill_chunk: int):
 _BATCH_LED_CACHE_KEYS = frozenset(
     {"cached_key", "cached_value", "key_scale", "value_scale"})
 _NON_BATCH_CACHE_KEYS = frozenset({"cache_index"})
+#: RECURRENT leaves ([rows, ...], ShortConv's ``conv_state``): led by the
+#: batch like the above — beams reorder them, the serve engine slices them
+#: per slot — but they hold a running summary and no positions. A stale one
+#: IS read (admission zeroes it), and there is nothing in it to page or to
+#: roll back to.
+_RECURRENT_CACHE_KEYS = frozenset({"conv_state"})
+#: every leaf whose leading dim is the batch: what beams reorder and the
+#: serve engine slices per slot
+_ROW_LED_CACHE_KEYS = _BATCH_LED_CACHE_KEYS | _RECURRENT_CACHE_KEYS
 
 
 def _path_key(k) -> str:
@@ -1151,6 +1350,11 @@ def _paged_leaf_check(name: str) -> bool:
     the completeness contract of ``_BATCH_LED_CACHE_KEYS``."""
     if name in _NON_BATCH_CACHE_KEYS:
         return False
+    if name in _RECURRENT_CACHE_KEYS:
+        raise ValueError(
+            f"cache leaf {name!r} is a recurrent state: it has no positions "
+            "to page (the prefix page cache does not serve a model with "
+            "conv layers)")
     if name not in _BATCH_LED_CACHE_KEYS:
         raise ValueError(
             f"unknown cache leaf {name!r}: add it to "
@@ -1323,7 +1527,7 @@ def generate_beam(model: GPT, params, prompt: jax.Array, n_new: int, *,
     def _map_batch_led(fn, cache, lead):
         def per_leaf(path, leaf):
             name = getattr(path[-1], "key", str(path[-1]))
-            if name in _BATCH_LED_CACHE_KEYS:
+            if name in _ROW_LED_CACHE_KEYS:
                 assert getattr(leaf, "ndim", 0) >= 1 and \
                     leaf.shape[0] == lead, (
                         f"cache leaf {name!r} expected leading dim "

@@ -610,7 +610,12 @@ def flash_attention_sharded(q, k, v, mesh, *, causal: bool = False,
             "flash attention keeps the sequence whole per shard; on a mesh "
             f"with seq={mesh.shape['seq']} use attn_impl='ring'/'zigzag' "
             "(full causal) or the halo path (windowed) instead")
-    spec = P("data", "model", None, None)
+    # fewer sequences than data shards (a two-sequence evaluation on a
+    # data-parallel mesh of four) cannot be sharded at all: such a batch
+    # stays whole on every data shard. Any other batch the axis does not
+    # divide is a mis-sized training batch, and the shard_map refuses it.
+    batch_axis = None if q.shape[0] < mesh.shape.get("data", 1) else "data"
+    spec = P(batch_axis, "model", None, None)
     if kv_mask is None:
         fn = functools.partial(flash_attention, causal=causal, window=window,
                                block_h=block_h, interpret=interpret)
@@ -623,7 +628,7 @@ def flash_attention_sharded(q, k, v, mesh, *, causal: bool = False,
                                interpret=interpret)
 
     return jax.shard_map(
-        fn, mesh=mesh, in_specs=(spec, spec, spec, P("data", None)),
+        fn, mesh=mesh, in_specs=(spec, spec, spec, P(batch_axis, None)),
         out_specs=spec, check_vma=False)(q, k, v, kv_mask)
 
 

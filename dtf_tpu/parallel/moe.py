@@ -27,16 +27,28 @@ G·s·cap bytes, i.e. divided by n — and the cumsum (the token→slot race for
 capacity) runs *within* each group, which is exactly GShard's semantics.
 The group axis rides the ``data`` mesh axis; E rides ``expert``; the two
 dispatch einsums still lower to the same pair of all-to-alls.
+
+Beside it, :class:`DroplessMoE` (PR 26) is the serving-side expert layer of
+today's sparse models: sigmoid scores with a per-expert choice bias, top-k
+of many, SwiGLU experts, NO capacity and no dropped token. It computes only
+the chosen (token, expert) pairs, grouped by expert
+(:func:`group_layout` + a grouped matrix product), and is told which
+contiguous range of experts it holds (``experts_held``), so a chip's share
+of a layer is data. It has no loss, no bias update and no all-to-all yet
+(ROADMAP.md): forward only.
 """
 
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 from jax.sharding import PartitionSpec as P
+
+from dtf_tpu.ops import moe_gmm
 
 
 @dataclasses.dataclass(frozen=True)
@@ -201,3 +213,206 @@ def moe_aux_loss(mutables: dict, cfg: MoeConfig) -> jax.Array:
     if not leaves:
         return jnp.zeros((), jnp.float32)
     return cfg.aux_loss_weight * sum(jnp.mean(l) for l in leaves) / len(leaves)
+
+
+# ---------------------------------------------------------------------------
+# Dropless top-k experts (sigmoid router, SwiGLU experts) — forward only
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertsConfig:
+    """A dropless routed-expert layer as today's sparse decoders publish it
+    (``num_experts`` / ``num_experts_per_tok`` / ``moe_intermediate_size`` /
+    ``norm_topk_prob`` / ``use_expert_bias`` / ``routed_scaling_factor``)."""
+
+    num_experts: int = 64
+    top_k: int = 4
+    d_ff: int = 1536
+    norm_topk_prob: bool = True
+    use_expert_bias: bool = True
+    routed_scaling_factor: float = 1.0
+    #: the contiguous range ``(lo, hi)`` of experts THIS layer holds (None =
+    #: all). The router keeps its full width and its top-k; the layer
+    #: computes its own experts' part of the result and leaves the rest
+    #: out — a chip's share of an expert-parallel deployment, without the
+    #: exchange. The shares of disjoint ranges add up to the whole layer.
+    experts_held: Optional[tuple[int, int]] = None
+
+    def __post_init__(self):
+        if not 1 <= self.top_k <= self.num_experts:
+            raise ValueError(
+                f"top_k={self.top_k} must be in [1, num_experts="
+                f"{self.num_experts}]")
+        lo, hi = self.held
+        if not 0 <= lo < hi <= self.num_experts:
+            raise ValueError(
+                f"experts_held={self.experts_held} must be a non-empty range "
+                f"inside [0, {self.num_experts})")
+
+    @property
+    def held(self) -> tuple[int, int]:
+        return (0, self.num_experts) if self.experts_held is None \
+            else tuple(self.experts_held)
+
+
+def route_topk(scores: jax.Array, bias: Optional[jax.Array], cfg: ExpertsConfig
+               ) -> tuple[jax.Array, jax.Array]:
+    """``scores`` [G, E] float32 (after the sigmoid) -> (experts [G, k]
+    int32, weights [G, k] float32). The bias moves the CHOICE only; the
+    weights are the chosen experts' own scores, normalised over the chosen
+    (``s_i / (sum + 1e-6)``) and scaled."""
+    choice = scores if bias is None else scores + bias[None, :]
+    _, experts = jax.lax.top_k(choice, cfg.top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if cfg.norm_topk_prob:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
+    return experts.astype(jnp.int32), weights * cfg.routed_scaling_factor
+
+
+def group_layout(group: jax.Array, counts: jax.Array, tm: int) -> dict:
+    """Where each (token, expert) pair's row lies when rows are laid out
+    group by group, every group padded to whole ``tm``-row tiles.
+
+    ``group`` [P] int32: the pair's group in ``[0, n_groups)``, or
+    ``n_groups`` for a pair that is left out (an expert held elsewhere, a
+    masked token); ``counts`` [n_groups] int32: the pairs of each group.
+    Static row count ``M = P + n_groups * (tm - 1)`` rounded
+    up to tiles — the worst case; tiles past ``n_used`` hold nothing.
+    Gathers and two small sorts only, no scatter. Returns ``src`` [M] (the pair whose row this is), ``valid`` [M],
+    ``row_of_pair`` [P] (0 for a pair left out), ``kept`` [P],
+    ``tile_group`` [M // tm] and ``n_used`` [1]."""
+    p, n_groups = group.shape[0], counts.shape[0]
+    padded = (counts + tm - 1) // tm * tm
+    pad_end = jnp.cumsum(padded)
+    pad_start = pad_end - padded
+    sorted_start = jnp.cumsum(counts) - counts
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    rank_sorted = jnp.argsort(order).astype(jnp.int32)   # pair -> sorted pos
+
+    m = -(-(p + n_groups * (tm - 1)) // tm) * tm
+    rows = jnp.arange(m, dtype=jnp.int32)
+    g_row = jnp.searchsorted(pad_end, rows, side="right").astype(jnp.int32)
+    g_in = jnp.minimum(g_row, n_groups - 1)
+    rank = rows - pad_start[g_in]
+    valid = (g_row < n_groups) & (rank < counts[g_in])
+    src = order[jnp.clip(sorted_start[g_in] + rank, 0, p - 1)]
+
+    kept = group < n_groups
+    g_pair = jnp.minimum(group, n_groups - 1)
+    row_of_pair = jnp.where(
+        kept, pad_start[g_pair] + rank_sorted - sorted_start[g_pair], 0)
+
+    n_tiles = m // tm
+    n_used = pad_end[-1] // tm
+    tile_group = g_row[::tm]
+    # empty tiles keep the last used group's weights: no traffic for them
+    last = tile_group[jnp.maximum(n_used - 1, 0)]
+    tile_group = jnp.minimum(
+        jnp.where(jnp.arange(n_tiles) < n_used, tile_group, last),
+        n_groups - 1)
+    return {"src": src, "valid": valid,
+            "row_of_pair": row_of_pair, "kept": kept,
+            "tile_group": tile_group.astype(jnp.int32),
+            "n_used": n_used.astype(jnp.int32)[None]}
+
+
+def tile_rows(pairs: int, n_groups: int) -> int:
+    """Rows of a tile for the Pallas product: about twice the mean group,
+    so that most groups fill one tile, between 16 (bfloat16's sublane
+    packing) and 128 (the MXU's edge)."""
+    tm = moe_gmm.MIN_TILE_ROWS
+    while tm < 128 and tm < 2 * pairs // max(n_groups, 1):
+        tm *= 2
+    return tm
+
+
+class DroplessMoE(nn.Module):
+    """Routed experts without capacity. Input [B, T, d] -> output [B, T, d].
+
+    ``s = sigmoid(W_g x)`` in float32 (a top-k choice flips on rounding);
+    the ``k`` largest of ``s + b`` are chosen; the output is
+    ``sum_chosen weight_i * W2_i (silu(W1_i x) * W3_i x)`` over the chosen
+    experts this layer holds. Only the chosen pairs are computed: rows are
+    grouped by expert (:func:`group_layout`) and run through three grouped
+    products. ``token_mask`` [B, T] bool leaves whole tokens out (a serving
+    slot that is not decoding, the pad columns of a ragged prefill chunk):
+    their rows are zero and they touch no expert's weights.
+
+    Sows, into the ``moe_stats`` collection when it is mutable, what the
+    routing did to the unmasked tokens over ALL experts: ``touched`` (experts
+    with at least one token) and ``max_load`` (tokens on the fullest one).
+
+    The grouped product is the ``dtf_moe_gmm`` Pallas kernel on a TPU and
+    ``jax.lax.ragged_dot`` on any other backend (the rule flash attention
+    follows).
+    """
+
+    d_model: int
+    cfg: ExpertsConfig = ExpertsConfig()
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, token_mask=None):
+        cfg = self.cfg
+        b, t, d = x.shape
+        g, e, k = b * t, cfg.num_experts, cfg.top_k
+        lo, hi = cfg.held
+        n_held = hi - lo
+        tokens = x.reshape(g, d)
+
+        w_g = self.param("router", nn.initializers.lecun_normal(), (d, e),
+                         jnp.float32)
+        bias = (self.param("expert_bias", nn.initializers.zeros, (e,),
+                           jnp.float32) if cfg.use_expert_bias else None)
+        init = nn.initializers.variance_scaling(
+            1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
+        w1 = self.param("w1", init, (n_held, d, cfg.d_ff), self.param_dtype)
+        w3 = self.param("w3", init, (n_held, d, cfg.d_ff), self.param_dtype)
+        w2 = self.param("w2", init, (n_held, cfg.d_ff, d), self.param_dtype)
+
+        scores = jax.nn.sigmoid(jnp.dot(
+            tokens.astype(jnp.float32), w_g,
+            precision=jax.lax.Precision.HIGHEST))
+        experts, weights = route_topk(scores, bias, cfg)       # [G, k]
+
+        live = jnp.ones((g,), bool) if token_mask is None \
+            else token_mask.reshape(g)
+        pair_expert = jnp.where(live[:, None], experts, e).reshape(g * k)
+        load = jnp.sum(pair_expert[:, None] == jnp.arange(e)[None, :],
+                       axis=0, dtype=jnp.int32)                # [E]
+        self.sow("moe_stats", "touched", jnp.sum(load > 0, dtype=jnp.int32))
+        self.sow("moe_stats", "max_load", jnp.max(load))
+
+        held = (pair_expert >= lo) & (pair_expert < hi)
+        group = jnp.where(held, pair_expert - lo, n_held).astype(jnp.int32)
+        on_tpu = jax.default_backend() == "tpu"
+        tm = tile_rows(g * k, n_held) if on_tpu else 1
+        counts = load[lo:hi]
+        lay = group_layout(group, counts, tm)
+        if on_tpu:
+            def product(a, w):
+                return moe_gmm.grouped_matmul(
+                    a, w, lay["tile_group"], lay["n_used"], tm=tm)
+        else:
+            def product(a, w):
+                return jax.lax.ragged_dot(
+                    a, w, counts,
+                    preferred_element_type=jnp.float32).astype(a.dtype)
+
+        rows = jnp.where(lay["valid"][:, None],
+                         tokens[lay["src"] // k].astype(self.dtype), 0)
+        w1, w3, w2 = (w.astype(self.dtype) for w in (w1, w3, w2))
+        gate = product(rows, w1).astype(jnp.float32)
+        up = product(rows, w3).astype(jnp.float32)
+        out_rows = product((jax.nn.silu(gate) * up).astype(self.dtype), w2)
+
+        # a pair left out reads row 0, which may hold anything: select, do
+        # not multiply by zero
+        out_pairs = jnp.where(
+            lay["kept"][:, None],
+            out_rows[lay["row_of_pair"]].astype(jnp.float32), 0.0)
+        out = (out_pairs * weights.reshape(g * k, 1)).reshape(
+            g, k, d).sum(axis=1)
+        return out.astype(x.dtype).reshape(b, t, d)

@@ -155,13 +155,13 @@ def _slice_slot_cache(cache: PyTree, slot) -> PyTree:
     of silently riding the slot un-sliced."""
     def leaf(path, x):
         name = _leaf_name(path)
-        if name in gpt._BATCH_LED_CACHE_KEYS:
+        if name in gpt._ROW_LED_CACHE_KEYS:
             return jax.lax.dynamic_slice_in_dim(x, slot, 1, axis=0)
         if name == "cache_index":
             return jax.lax.dynamic_slice_in_dim(x, slot, 1, axis=0)[0]
         raise ValueError(
             f"unknown cache leaf {name!r}: teach serve/engine.py how to "
-            "slice it per slot (see gpt._BATCH_LED_CACHE_KEYS)")
+            "slice it per slot (see gpt._ROW_LED_CACHE_KEYS)")
 
     return jax.tree_util.tree_map_with_path(leaf, cache)
 
@@ -170,7 +170,7 @@ def _write_slot_cache(cache: PyTree, row: PyTree, slot) -> PyTree:
     """Write a batch-1 plain cache back into slot ``slot``."""
     def leaf(path, x, r):
         name = _leaf_name(path)
-        if name in gpt._BATCH_LED_CACHE_KEYS:
+        if name in gpt._ROW_LED_CACHE_KEYS:
             return jax.lax.dynamic_update_slice_in_dim(x, r, slot, axis=0)
         if name == "cache_index":
             return jax.lax.dynamic_update_slice_in_dim(
@@ -192,14 +192,33 @@ def _pick(sub, logits_v, temp, top_k, top_p):
     return jnp.where(temp > 0.0, sampled, greedy).astype(jnp.int32)
 
 
+def _moe_out_names(cfg: gpt.GPTConfig) -> tuple:
+    """What the decode program of a model with routed experts returns
+    beside ``token`` and ``done``, in the same transfer: the (token,
+    expert) pairs an expert layer computed, per expert layer the experts
+    that got a token and the fullest expert's tokens, and the valid cache
+    positions of the active slots (what a step must read)."""
+    if cfg.experts is None:
+        return ()
+    return ("moe_picks", "moe_touched", "moe_max_load", "cache_positions")
+
+
+def _layers_in_order(tree: dict) -> list:
+    return [tree[name] for name in
+            sorted(tree, key=lambda name: int(name.split("_")[-1]))]
+
+
 def _build_decode_fn(model: gpt.GPT):
     """decode_all: one masked token step across all slots."""
+    with_moe = bool(_moe_out_names(model.cfg))
+    collections_out = ["cache"] + (["moe_stats"] if with_moe else [])
+
     def decode_fn(params, state):
         active = state["active"]
         logits, mut = model.apply(
             {"params": params, "cache": state["cache"]},
-            state["tok"][:, None], deterministic=True, mutable=["cache"],
-            decode_active=active)
+            state["tok"][:, None], deterministic=True,
+            mutable=collections_out, decode_active=active)
         lg = logits[:, 0]                                    # [S, V] f32
 
         def one(key, lv, temp, tk, tp):
@@ -222,7 +241,17 @@ def _build_decode_fn(model: gpt.GPT):
             "tok": jnp.where(active, nxt, state["tok"]),
             "done": jnp.where(active, done, state["done"]),
         }
-        return new_state, {"token": nxt, "done": done}
+        out = {"token": nxt, "done": done}
+        if with_moe:
+            layers = _layers_in_order(mut["moe_stats"])
+            for key in ("touched", "max_load"):
+                out[f"moe_{key}"] = jnp.stack(
+                    [layer["experts"][key][0] for layer in layers])
+            out["moe_picks"] = (jnp.sum(active, dtype=jnp.int32)
+                                * model.cfg.experts.top_k)
+            out["cache_positions"] = jnp.sum(jnp.where(
+                active, gpt.cache_index_of(state["cache"]), 0))
+        return new_state, out
 
     return decode_fn
 
@@ -349,14 +378,20 @@ def _build_prefill_fn(model: gpt.GPT):
         # a fresh request starts at index `start` (0 without prefix pages;
         # stale slot contents past it need no clearing — validity is
         # derived from the index, gpt.py docstring)
-        row = jax.tree_util.tree_map_with_path(
-            lambda p, x: jnp.where(reset, jnp.asarray(start, x.dtype), x)
-            if _leaf_name(p) == "cache_index" else x, row)
+        # ... but a recurrent state (conv layers) IS read whatever the
+        # index says: a fresh request starts from zeros
+        def admit(p, x):
+            if _leaf_name(p) == "cache_index":
+                return jnp.where(reset, jnp.asarray(start, x.dtype), x)
+            if _leaf_name(p) in gpt._RECURRENT_CACHE_KEYS:
+                return jnp.where(reset, jnp.zeros_like(x), x)
+            return x
+
+        row = jax.tree_util.tree_map_with_path(admit, row)
         logits, mut = model.apply(
             {"params": params, "cache": row}, chunk[None, :],
             deterministic=True, mutable=["cache"], prefill_len=n_valid)
         cache = _write_slot_cache(cache, mut["cache"], slot)
-
         # sampling-params rows are (re)stamped on every chunk of the
         # request — idempotent, and the slot is fully reinitialized by its
         # first chunk no matter who occupied it before.
@@ -511,6 +546,22 @@ class DecodeEngine:
                     f"the prefix page cache needs the plain slot=position "
                     f"cache layout; attn_window={cfg.attn_window} rolls "
                     "the buffer so page windows alias arbitrary positions")
+        if cfg.has_recurrent_state:
+            # a conv layer's state is a running summary, not positions: a
+            # page of it cannot be loaded behind a prefix, a rejected
+            # speculative tail cannot be rolled out of it, and the int8
+            # cache's scales are per position (docs/SERVING.md)
+            for asked, what in (
+                    (prefix_pages, "the prefix page cache (prefix_pages)"),
+                    (draft_cfg is not None or spec_k,
+                     "speculative decoding (draft_cfg / spec_k)"),
+                    (cfg.kv_cache_dtype == "int8",
+                     "the int8 KV cache (kv_cache_dtype='int8')")):
+                if asked:
+                    raise ValueError(
+                        f"{what} does not serve a model with conv layers: "
+                        "their recurrent state has no positions to page, "
+                        "roll back or rescale")
         base = dataclasses.replace(cfg, decode_len=max_len,
                                    slot_decode=False, chunked_prefill=False)
         # the chunk may not be wider than ANY layer's cache: the rolling-
@@ -587,6 +638,18 @@ class DecodeEngine:
                          "pages_loaded": 0, "pages_saved": 0,
                          "prefix_hit_tokens": 0, "prefix_miss_tokens": 0,
                          "probe_decodes": 0, "param_swaps": 0}
+        #: what the last engine call's routing did (models with routed
+        #: experts; None for every other model and once read): numbers by
+        #: name, host values from a readback the call makes anyway or from
+        #: its own operands. The scheduler drains them into its
+        #: SpanRecorder as ``serve_moe_<name>`` (docs/OBSERVABILITY.md
+        #: section 7) and ``counters`` keeps their sums.
+        self.moe_samples: Optional[dict] = None
+        if base.experts is not None:
+            self.counters.update({"moe_decode_picks": 0,
+                                  "moe_prefill_picks": 0,
+                                  "moe_experts_touched": 0,
+                                  "moe_max_expert_load": 0})
         #: the param VERSION this engine serves (ISSUE 14 hot-swap):
         #: monotone, bumped by :meth:`swap_params`, stamped into every
         #: completed record by the scheduler and used as the prefix-page
@@ -682,7 +745,6 @@ class DecodeEngine:
         self._prefill_model = models["prefill"]
         self._decode_c = self.programs["decode"].aot()
         self._prefill_c = self.programs["prefill"].aot()
-
         if self.spec_k:
             self._draft_decode_model = models["draft"]
             self._draft_prefill_model = models["draft_prefill"]
@@ -852,6 +914,11 @@ class DecodeEngine:
                     np.int32(pad_id),
                     np.asarray(jax.random.PRNGKey(seed), np.uint32))
             self.counters["prefill_chunks"] += 1
+            if self.cfg.experts is not None:
+                # pad columns choose no expert: the chunk's valid tokens
+                picks = len(seg) * self.cfg.experts.top_k
+                self.counters["moe_prefill_picks"] += picks
+                self.moe_samples = {"prefill_picks": picks}
             if self.spec_k:
                 # the DRAFT cache must ingest the same prompt (pages never
                 # shortcut it — the draft pool does not exist, and the
@@ -955,7 +1022,31 @@ class DecodeEngine:
                     self._params, self._live(self._state))
             self.counters["decode_steps"] += 1
             with self._annotation("dtf.engine.decode.readback"):
+                if "moe_touched" in out:
+                    self._note_moe(out)
                 return np.asarray(out["token"]), np.asarray(out["done"])
+
+    def _note_moe(self, out) -> None:
+        """A routed-expert model's decode step, from the readback decode
+        makes anyway: ``picks`` are the (token, expert) pairs one expert
+        layer computed; the rest are means over the expert layers.
+        ``counters`` sums over layers (divide by ``decode_steps`` x layers
+        for means)."""
+        touched = np.asarray(out["moe_touched"])
+        max_load = np.asarray(out["moe_max_load"])
+        picks = int(out["moe_picks"])
+        self.counters["moe_decode_picks"] += picks * len(touched)
+        self.counters["moe_experts_touched"] += int(touched.sum())
+        self.counters["moe_max_expert_load"] += int(max_load.sum())
+        if not picks:
+            return
+        self.moe_samples = {
+            "picks": picks,
+            "experts_touched": float(touched.mean()),
+            "max_expert_load": float(max_load.mean()),
+            "max_load_over_mean": float(max_load.mean())
+            * self.cfg.experts.num_experts / picks,
+            "cache_positions": int(out["cache_positions"])}
 
     def draft_propose(self):
         """One draft_all dispatch: k greedy proposals per slot off the
@@ -1335,9 +1426,13 @@ def program_table(cfg: gpt.GPTConfig, *, n_slots: int, max_len: int,
             abstract_args=(abs_params, abs_state, props_abs),
             table=programs)
     else:
+        decode_kw = dict(jit_kw)
+        if mesh is not None:
+            decode_kw["out_shardings"] = (state_sh, {
+                name: rep for name in ("token", "done") + _moe_out_names(cfg)})
         executor.program(
             "decode", _build_decode_fn(models["decode"]),
-            counts=counts, jit_kw=jit_kw, **donate_state,
+            counts=counts, jit_kw=decode_kw, **donate_state,
             abstract_args=(abs_params, abs_state), table=programs)
     executor.program(
         "prefill", _build_prefill_fn(models["prefill"]),

@@ -503,6 +503,19 @@ class Scheduler:
 
     # ------------------------------------------------------------- internals
 
+    def _note_moe(self) -> None:
+        """What a routed-expert model's engine call left in
+        ``DecodeEngine.moe_samples`` (a decode step's picks, experts
+        touched, fullest expert, cache positions; a prefill chunk's picks),
+        into the SpanRecorder as ``serve_moe_<name>``. The channel is named
+        for seconds, but a sample is a number: ``mean_s`` and ``total_s`` of
+        these are plain means and sums (docs/OBSERVABILITY.md section 7)."""
+        samples = getattr(self.engine, "moe_samples", None)
+        if samples:
+            self.engine.moe_samples = None
+            for name, value in samples.items():
+                self.telemetry.spans.add(f"serve_moe_{name}", value)
+
     def _tracer(self):
         """The run's per-request TraceCollector, if one is attached to the
         telemetry object (host-clock chrome events; None = no recording)."""
@@ -517,17 +530,16 @@ class Scheduler:
         if self.telemetry is None:
             return fn(*args, **kwargs)
         tracer = self._tracer()
-        if tracer is None:
-            with self.telemetry.spans.span(name):
-                return fn(*args, **kwargs)
-        t0 = tracer.now_us()
+        t0 = None if tracer is None else tracer.now_us()
         try:
             with self.telemetry.spans.span(name):
                 return fn(*args, **kwargs)
         finally:
-            tracer.complete(name, cat="engine",
-                            tid="engine" if tid is None else tid,
-                            t0_us=t0, t1_us=tracer.now_us(), args=targs)
+            self._note_moe()
+            if tracer is not None:
+                tracer.complete(name, cat="engine",
+                                tid="engine" if tid is None else tid,
+                                t0_us=t0, t1_us=tracer.now_us(), args=targs)
 
     def _budget_spent(self, rec: _Rec) -> bool:
         return (len(rec.tokens) >= rec.req.max_new

@@ -240,3 +240,68 @@ def test_serve_programs_update_the_cache_in_place(on_chip, name, d_head):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= nbytes(state)
     assert mem.temp_size_in_bytes < nbytes(state["cache"])
+
+
+# ---- the hybrid sparse decoder: conv state beside K/V, grouped experts -----
+
+LFM2_SERVE = dict(n_slots=32, max_len=4096, prefill_chunk=256)  # its cell
+
+
+def _lfm2_program(on_chip, monkeypatch, name):
+    """The serve cell's ``name`` program at its widths and slots, cut to one
+    conv layer (dense FFN) and one attention layer (64 routed experts) and a
+    1024-token vocabulary. The program asks ``jax.default_backend()`` whether to run the Pallas
+    grouped product; the answer is the CPU's here, so the test gives the
+    chip's."""
+    from dtf_tpu.parallel import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = gpt.GPTConfig(
+        vocab_size=1024, d_model=2048, layers=2, heads=32, kv_heads=8,
+        d_ff=11776, norm="rmsnorm", ffn="swiglu", qk_norm=True,
+        use_bias=False, tie_head=True, layer_kinds=("conv", "attn"),
+        rope_theta=1e6, dense_layers=1, param_dtype=jnp.bfloat16,
+        experts=moe.ExpertsConfig(num_experts=64, top_k=4, d_ff=1536))
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda s: on_chip(s.shape, s.dtype), tree)
+    state = place(serve_engine.engine_state_struct(
+        cfg, n_slots=LFM2_SERVE["n_slots"], max_len=LFM2_SERVE["max_len"]))
+    model = gpt.GPT(cfg)
+    params = place(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"])
+    programs, _ = serve_engine.program_table(
+        cfg, **LFM2_SERVE, abs_trees={"params": params, "state": state})
+    prog = programs[name]
+    return prog.lower(*place(prog.abstract_args)).compile(), state
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_lfm2_serve_programs_update_both_kinds_of_state_in_place(
+        on_chip, monkeypatch, name):
+    """PR 25's fence on the model of PR 26: neither the K/V leaves nor the
+    conv-state leaf is produced whole by a ``copy``, ``transpose`` or
+    ``scatter``, the donated state is aliased to the output whole, the temporaries leave no room for a copy of a leaf, and
+    the grouped product is the named Pallas kernel, three calls an expert
+    layer. Prefill's temporaries are its dense scores of one chunk against
+    a 4096-position cache, [8, 4, 256, 4352] float32 twice (285 MB, more
+    than this cut's one attention layer of cache): a K or V leaf more (134
+    MB) would pass the limit."""
+    compiled, state = _lfm2_program(on_chip, monkeypatch, name)
+    text = compiled.as_text()
+    n = LFM2_SERVE["n_slots"]
+    for leaf in (f"[{n},8,{LFM2_SERVE['max_len']},64]", f"[{n},3,2048]"):
+        whole_leaf = re.findall(
+            rf"^\s*(?:ROOT )?%\S+ = bf16{re.escape(leaf)}\S* "
+            rf"(copy|transpose|scatter)\(", text, re.M)
+        assert not whole_leaf, (leaf, whole_leaf)
+    nbytes = lambda tree: sum(  # noqa: E731
+        s.size * s.dtype.itemsize for s in jax.tree.leaves(tree))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes(state)
+    chunk, cached = LFM2_SERVE["prefill_chunk"], LFM2_SERVE["max_len"]
+    scores = 32 * chunk * (cached + chunk) * 4
+    limit = nbytes(state["cache"]) if name == "decode" else 2.5 * scores
+    assert mem.temp_size_in_bytes < limit
+    kernels = re.findall(
+        r"^\s*%\w*dtf_moe_gmm\w*(?:\.\d+)? = .*tpu_custom_call", text, re.M)
+    assert len(kernels) == 3, kernels

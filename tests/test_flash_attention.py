@@ -252,6 +252,34 @@ def test_flash_sharded_rejects_seq_mesh():
         flash_attention_sharded(q, q, q, mesh, causal=True)
 
 
+@pytest.mark.parametrize("batch", [2, 8, 6])
+def test_flash_sharded_keeps_whole_only_a_batch_smaller_than_the_data_axis(
+        batch):
+    """Two sequences evaluated on a data-parallel mesh of four (the
+    benchmark's reference check in a four-chip cell) cannot be sharded:
+    every data shard computes the whole batch. Eight are sharded as ever;
+    six, a mis-sized training batch, are still refused."""
+    from dtf_tpu.core.mesh import MeshConfig, make_mesh
+    from dtf_tpu.ops.flash_attention import flash_attention_sharded
+
+    mesh = make_mesh(MeshConfig(data=4), devices=jax.devices()[:4])
+    q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (batch, 2, 16, 8))
+               for i in range(3))
+    if batch == 6:
+        with pytest.raises(ValueError, match="divisible|evenly"):
+            flash_attention_sharded(q, k, v, mesh, causal=True,
+                                    interpret=True)
+        return
+    got = flash_attention_sharded(q, k, v, mesh, causal=True,
+                                  interpret=True)
+    np.testing.assert_allclose(got, dense_attention(q, k, v, causal=True),
+                               atol=2e-5)
+    mask = jnp.ones((batch, 16), bool).at[:, 12:].set(False)
+    got = flash_attention_sharded(q, k, v, mesh, kv_mask=mask,
+                                  interpret=True)
+    assert got.shape == q.shape and bool(jnp.isfinite(got).all())
+
+
 @pytest.mark.parametrize("block_h", [2, 4])
 @pytest.mark.parametrize("causal,window", [(False, 0), (True, 0), (True, 24)])
 def test_hfold_forward_matches_dense(block_h, causal, window):
