@@ -28,9 +28,12 @@ section below.
   bit-compatible with offline decode per request.
 - ``decode_all()`` — one masked token step across ALL slots
   (``slot_decode`` model), with per-slot temperature/top-k/top-p/eos
-  applied through :func:`dtf_tpu.models.gpt.filter_logits_dynamic` under a
-  per-slot rng stream (vmapped split-then-pick, the batch-1 ``generate``
-  stream per slot).
+  under a per-slot rng stream (split-then-pick, the batch-1 ``generate``
+  stream per slot). The pick (:func:`_pick_rows`, shared with the other
+  two programs that pick) does only the work the step's live slots ask
+  for: :func:`dtf_tpu.models.gpt.filter_logits_dynamic` and its
+  vocabulary sorts run only in a step where some live slot filters, the
+  noise only where one samples; an all-greedy step is an arg-max.
 
 With ``prefix_pages > 0`` the engine additionally keeps a device **page
 pool** and two more AOT programs, ``page_save``/``page_load`` (fixed-shape
@@ -180,16 +183,67 @@ def _write_slot_cache(cache: PyTree, row: PyTree, slot) -> PyTree:
     return jax.tree_util.tree_map_with_path(leaf, cache, row)
 
 
-def _pick(sub, logits_v, temp, top_k, top_p):
+def _pick(sub, logits_v, temp, top_k=None, top_p=None):
     """One slot's token pick — ``generate``'s ``pick`` at batch-1 shapes
     ([1,V] through the filter, [0] out), so the sampled stream is
-    bit-identical to an offline batch-1 ``generate`` with the same rng."""
+    bit-identical to an offline batch-1 ``generate`` with the same rng.
+    Without ``top_k`` / ``top_p`` the filter is left out, as ``generate``
+    leaves it out when both are off: the same token as with both gates
+    off, which hand the filter's input back, less its sorts."""
     safe_t = jnp.where(temp > 0.0, temp, 1.0)
-    filt = gpt.filter_logits_dynamic(logits_v[None, :] / safe_t,
-                                     top_k=top_k, top_p=top_p)
+    filt = logits_v[None, :] / safe_t
+    if top_k is not None:
+        filt = gpt.filter_logits_dynamic(filt, top_k=top_k, top_p=top_p)
     sampled = jax.random.categorical(sub, filt, -1)[0]
     greedy = jnp.argmax(logits_v[None, :], -1)[0]
     return jnp.where(temp > 0.0, sampled, greedy).astype(jnp.int32)
+
+
+#: what a call of :func:`_pick_rows` had to do, by the index it returns; the
+#: engine counts decode steps under ``sampler_steps_<name>``
+_SAMPLER_PATHS = ("greedy", "unfiltered", "filtered")
+
+
+def _pick_rows(subs, logits, temp, top_k, top_p, live):
+    """The token pick of every row of one program call: ``subs`` [N, 2],
+    ``logits`` [N, V], ``temp`` / ``top_k`` / ``top_p`` / ``live`` [N] ->
+    ``(tokens [N] int32, path)``. ``live`` marks the rows whose pick the
+    program keeps.
+
+    The work is chosen ONCE, for all rows, from what the live rows ask
+    for, and only the chosen branch runs (a ``lax.cond`` inside a vmapped
+    :func:`_pick` would become a select that runs both sides — call this
+    outside any ``vmap``):
+
+    0. no live row samples: the arg-max of the raw logits. No sort, no
+       softmax, no noise.
+    1. some live row samples, none filters: :func:`_pick` without its
+       filter on every row — tempered categorical noise, arg-max for the
+       greedy rows.
+    2. some live row filters (``top_k`` > 0 or ``top_p`` < 1 at
+       ``temp`` > 0): :func:`_pick` on every row — the vocabulary sorts of
+       ``filter_logits_dynamic`` included.
+
+    A live row's token is bit-identical on whichever path the call takes:
+    2 is ``_pick`` itself, 1 is ``_pick`` less a filter that hands its
+    input back when both gates are off, 0 is what ``_pick`` selects for
+    ``temp`` == 0. A row that is not live gets a token of the path its
+    neighbours chose, which its caller throws away."""
+    samples = live & (temp > 0.0)
+    filters = samples & ((top_k > 0) | (top_p < 1.0))
+    path = (jnp.any(samples).astype(jnp.int32)
+            + jnp.any(filters).astype(jnp.int32))
+
+    def greedy():
+        return jnp.argmax(logits, -1).astype(jnp.int32)
+
+    def unfiltered():
+        return jax.vmap(_pick)(subs, logits, temp)
+
+    def filtered():
+        return jax.vmap(_pick)(subs, logits, temp, top_k, top_p)
+
+    return jax.lax.switch(path, (greedy, unfiltered, filtered)), path
 
 
 def _moe_out_names(cfg: gpt.GPTConfig) -> tuple:
@@ -221,12 +275,15 @@ def _build_decode_fn(model: gpt.GPT):
             mutable=collections_out, decode_active=active)
         lg = logits[:, 0]                                    # [S, V] f32
 
-        def one(key, lv, temp, tk, tp):
-            s2 = jax.random.split(key)
-            return s2[0], _pick(s2[1], lv, temp, tk, tp)
-
-        rng, nxt = jax.vmap(one)(state["rng"], lg, state["temp"],
-                                 state["top_k"], state["top_p"])
+        # one split per row whatever the pick does with its half: a
+        # request's sampling stream does not depend on its neighbours
+        keys = jax.vmap(jax.random.split)(state["rng"])      # [S, 2, 2]
+        rng = keys[:, 0]
+        # a done row emits pad and an inactive row nothing: neither asks
+        # the sampler for anything
+        nxt, path = _pick_rows(keys[:, 1], lg, state["temp"],
+                               state["top_k"], state["top_p"],
+                               active & ~state["done"])
         # offline eos semantics per slot: a done row keeps stepping but
         # emits pad; done flips AFTER the eos token itself is kept.
         nxt = jnp.where(state["done"], state["pad"], nxt)
@@ -241,7 +298,7 @@ def _build_decode_fn(model: gpt.GPT):
             "tok": jnp.where(active, nxt, state["tok"]),
             "done": jnp.where(active, done, state["done"]),
         }
-        out = {"token": nxt, "done": done}
+        out = {"token": nxt, "done": done, "sampler_path": path}
         if with_moe:
             layers = _layers_in_order(mut["moe_stats"])
             for key in ("touched", "max_load"):
@@ -322,25 +379,36 @@ def _build_verify_fn(model: gpt.GPT, k: int):
             {"params": params, "cache": state["cache"]}, inputs,
             deterministic=True, mutable=["cache"], decode_active=active)
 
-        def one(key, lv, temp, tk, tp, eos, pad, done0):
-            # the row's rng/eos chain, unrolled k+1 deep: entry j is what
-            # the j-th sequential decode step would have sampled/split
-            toks, dones, keys = [], [], [key]
-            done, cur = done0, key
-            for j in range(k + 1):
+        def chain(key):
+            # the row's rng chain, unrolled k+1 deep: entry j is what the
+            # j-th sequential decode step would have split; it does not
+            # depend on what was picked
+            subs, keys, cur = [], [key], key
+            for _ in range(k + 1):
                 s2 = jax.random.split(cur)
-                v = _pick(s2[1], lv[j], temp, tk, tp)
-                tkn = jnp.where(done, pad, v)
-                done = done | ((eos >= 0) & (tkn == eos))
-                toks.append(tkn)
-                dones.append(done)
+                subs.append(s2[1])
                 keys.append(s2[0])
                 cur = s2[0]
-            return jnp.stack(toks), jnp.stack(dones), jnp.stack(keys)
+            return jnp.stack(subs), jnp.stack(keys)
 
-        toks, dones, keys = jax.vmap(one)(
-            state["rng"], logits, state["temp"], state["top_k"],
-            state["top_p"], state["eos"], state["pad"], state["done"])
+        subs, keys = jax.vmap(chain)(state["rng"])   # [S,k+1,2], [S,k+2,2]
+
+        def per_pos(x):
+            return jnp.repeat(x, k + 1)
+
+        picks, path = _pick_rows(
+            subs.reshape(-1, 2), logits.reshape(-1, logits.shape[-1]),
+            per_pos(state["temp"]), per_pos(state["top_k"]),
+            per_pos(state["top_p"]), per_pos(active & ~state["done"]))
+        picks = picks.reshape(-1, k + 1)
+        # the eos chain: what the j-th sequential step would have emitted
+        toks, dones, done = [], [], state["done"]
+        for j in range(k + 1):
+            tkn = jnp.where(done, state["pad"], picks[:, j])
+            done = done | ((state["eos"] >= 0) & (tkn == state["eos"]))
+            toks.append(tkn)
+            dones.append(done)
+        toks, dones = jnp.stack(toks, axis=1), jnp.stack(dones, axis=1)
         match = jnp.cumprod((toks[:, :k] == proposals).astype(jnp.int32),
                             axis=1)
         n_emit = jnp.where(active, 1 + match.sum(axis=1),
@@ -358,7 +426,8 @@ def _build_verify_fn(model: gpt.GPT, k: int):
             "tok": jnp.where(active, new_tok, state["tok"]),
             "done": jnp.where(active, new_done, state["done"]),
         }
-        return new_state, {"tokens": toks, "done": dones, "n_emit": n_emit}
+        return new_state, {"tokens": toks, "done": dones, "n_emit": n_emit,
+                           "sampler_path": path}
 
     return verify_fn
 
@@ -399,7 +468,10 @@ def _build_prefill_fn(model: gpt.GPT):
                                             axis=0, keepdims=False)  # [V]
         key_row = jnp.where(reset, key, state["rng"][slot])
         s2 = jax.random.split(key_row)
-        tok_new = _pick(s2[1], last, temp, top_k, top_p)
+        # the pick is kept on the request's last chunk only
+        picks, _ = _pick_rows(s2[1][None], last[None], temp[None],
+                              top_k[None], top_p[None], is_last[None])
+        tok_new = picks[0]
         done_new = is_last & (eos >= 0) & (tok_new == eos)
         new_state = {
             **state,
@@ -645,6 +717,16 @@ class DecodeEngine:
         #: SpanRecorder as ``serve_moe_<name>`` (docs/OBSERVABILITY.md
         #: section 7) and ``counters`` keeps their sums.
         self.moe_samples: Optional[dict] = None
+        #: the path the last decode step's sampler took (an index into
+        #: ``_SAMPLER_PATHS``), still on the device: :meth:`take_samples`
+        #: reads and counts it, so a step nobody observes transfers nothing.
+        #: Once somebody has asked, a step starts the scalar's copy to the
+        #: host beside its tokens', and the asker does not wait for a
+        #: transfer of its own (~0.5 ms a tick on a v5e's host).
+        self._sampler_path = None
+        self._sampler_watched = False
+        self.counters.update(
+            {f"sampler_steps_{name}": 0 for name in _SAMPLER_PATHS})
         if base.experts is not None:
             self.counters.update({"moe_decode_picks": 0,
                                   "moe_prefill_picks": 0,
@@ -1020,11 +1102,19 @@ class DecodeEngine:
             with self._annotation("dtf.engine.decode.dispatch"):
                 self._state, out = self._decode_c(
                     self._params, self._live(self._state))
-            self.counters["decode_steps"] += 1
+            self._note_step(out)
             with self._annotation("dtf.engine.decode.readback"):
                 if "moe_touched" in out:
                     self._note_moe(out)
                 return np.asarray(out["token"]), np.asarray(out["done"])
+
+    def _note_step(self, out) -> None:
+        """A dispatched decode (or verify) step: counted, and its sampler
+        path kept for :meth:`take_samples`."""
+        self.counters["decode_steps"] += 1
+        self._sampler_path = out["sampler_path"]
+        if self._sampler_watched:
+            self._sampler_path.copy_to_host_async()
 
     def _note_moe(self, out) -> None:
         """A routed-expert model's decode step, from the readback decode
@@ -1047,6 +1137,26 @@ class DecodeEngine:
             "max_load_over_mean": float(max_load.mean())
             * self.cfg.experts.num_experts / picks,
             "cache_positions": int(out["cache_positions"])}
+
+    def take_samples(self) -> dict:
+        """What the last engine call left for a telemetry object, by span
+        name less its ``serve_`` (docs/OBSERVABILITY.md section 7), taken
+        once: ``moe_samples``, and after a decode step ``sampler_greedy``
+        (1.0 where no live slot sampled, else 0.0). The sampler's path is
+        read from the device HERE, into ``counters["sampler_steps_*"]``
+        too: the scheduler asks only where a telemetry object is attached,
+        so a served step pays no transfer for it (and from the second
+        asking on, the copy started with the step: ``_note_step``)."""
+        samples = {f"moe_{name}": value
+                   for name, value in (self.moe_samples or {}).items()}
+        self.moe_samples = None
+        path, self._sampler_path = self._sampler_path, None
+        if path is not None:
+            self._sampler_watched = True
+            path = int(path)
+            self.counters[f"sampler_steps_{_SAMPLER_PATHS[path]}"] += 1
+            samples["sampler_greedy"] = float(path == 0)
+        return samples
 
     def draft_propose(self):
         """One draft_all dispatch: k greedy proposals per slot off the
@@ -1077,7 +1187,7 @@ class DecodeEngine:
                 props = np.zeros((self.n_slots, self.spec_k), np.int32)
             self._state, out = self._decode_c(
                 self._params, self._live(self._state), props)
-        self.counters["decode_steps"] += 1
+        self._note_step(out)
         with self._annotation("dtf.engine.decode.readback"):
             toks = np.asarray(out["tokens"])
             dones = np.asarray(out["done"])
@@ -1414,7 +1524,7 @@ def program_table(cfg: gpt.GPTConfig, *, n_slots: int, max_len: int,
         jit_kw["out_shardings"] = (state_sh, {"token": rep, "done": rep})
         verify_kw["out_shardings"] = (state_sh,
                                       {"tokens": rep, "done": rep,
-                                       "n_emit": rep})
+                                       "n_emit": rep, "sampler_path": rep})
     donate_state = {"donate": True, "donate_args": (1,)}
     programs = {}
     if spec_k:
@@ -1429,7 +1539,8 @@ def program_table(cfg: gpt.GPTConfig, *, n_slots: int, max_len: int,
         decode_kw = dict(jit_kw)
         if mesh is not None:
             decode_kw["out_shardings"] = (state_sh, {
-                name: rep for name in ("token", "done") + _moe_out_names(cfg)})
+                name: rep for name in ("token", "done", "sampler_path")
+                + _moe_out_names(cfg)})
         executor.program(
             "decode", _build_decode_fn(models["decode"]),
             counts=counts, jit_kw=decode_kw, **donate_state,
@@ -1672,7 +1783,8 @@ def spec_step_view(cfg: gpt.GPTConfig, draft_cfg: gpt.GPTConfig, *,
         jit_kw["out_shardings"] = {
             "state": jax.tree.map(lambda s: s.sharding, abs_state),
             "draft_state": jax.tree.map(lambda s: s.sharding, abs_dstate),
-            "out": {"tokens": rep, "done": rep, "n_emit": rep}}
+            "out": {"tokens": rep, "done": rep, "n_emit": rep,
+                    "sampler_path": rep}}
     return (executor.program("spec_view", step, jit_kw=jit_kw,
                              abstract_args=(bundle, ops)),
             bundle, ops)

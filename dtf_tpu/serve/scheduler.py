@@ -503,18 +503,18 @@ class Scheduler:
 
     # ------------------------------------------------------------- internals
 
-    def _note_moe(self) -> None:
-        """What a routed-expert model's engine call left in
-        ``DecodeEngine.moe_samples`` (a decode step's picks, experts
-        touched, fullest expert, cache positions; a prefill chunk's picks),
-        into the SpanRecorder as ``serve_moe_<name>``. The channel is named
-        for seconds, but a sample is a number: ``mean_s`` and ``total_s`` of
-        these are plain means and sums (docs/OBSERVABILITY.md section 7)."""
-        samples = getattr(self.engine, "moe_samples", None)
-        if samples:
-            self.engine.moe_samples = None
-            for name, value in samples.items():
-                self.telemetry.spans.add(f"serve_moe_{name}", value)
+    def _note_samples(self) -> None:
+        """What the engine call left for a telemetry object
+        (``DecodeEngine.take_samples``: a routed-expert model's picks,
+        experts touched, fullest expert and cache positions; whether a
+        decode step's sampler stayed greedy), into the SpanRecorder as
+        ``serve_<name>``. The channel is named for seconds, but a sample is
+        a number: ``mean_s`` and ``total_s`` of these are plain means and
+        sums (docs/OBSERVABILITY.md section 7)."""
+        take = getattr(self.engine, "take_samples", None)
+        if take is not None:
+            for name, value in take().items():
+                self.telemetry.spans.add(f"serve_{name}", value)
 
     def _tracer(self):
         """The run's per-request TraceCollector, if one is attached to the
@@ -535,7 +535,7 @@ class Scheduler:
             with self.telemetry.spans.span(name):
                 return fn(*args, **kwargs)
         finally:
-            self._note_moe()
+            self._note_samples()
             if tracer is not None:
                 tracer.complete(name, cat="engine",
                                 tid="engine" if tid is None else tid,

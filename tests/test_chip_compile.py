@@ -63,6 +63,20 @@ def on_chip(topo):
                                                      sharding=one_chip)
 
 
+@pytest.fixture(scope="module")
+def compiled_once():
+    """key, builder -> what the builder returned the first time: a serve
+    program compiles for up to 20 s, and more than one test reads it."""
+    cache = {}
+
+    def get(key, build):
+        if key not in cache:
+            cache[key] = build()
+        return cache[key]
+
+    return get
+
+
 def _compiles_to_kernel(fn, *args) -> str:
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
@@ -215,7 +229,8 @@ def _serve_program(on_chip, name, heads):
 
 @pytest.mark.parametrize("d_head", [64, 128])
 @pytest.mark.parametrize("name", ["decode", "prefill"])
-def test_serve_programs_update_the_cache_in_place(on_chip, name, d_head):
+def test_serve_programs_update_the_cache_in_place(on_chip, compiled_once,
+                                                  name, d_head):
     """PR 25's fence. Before it ``jit_decode_fn`` relayouted every cache leaf
     twice a step around a scatter (the compiler keeps a leaf with position
     as the minor dimension and scatters only with position major) and
@@ -228,7 +243,8 @@ def test_serve_programs_update_the_cache_in_place(on_chip, name, d_head):
     in-place pass reads and writes.) d_head 128 is fenced beside 64 so
     neither layout pays for the other."""
     heads = 1024 // d_head
-    compiled, state = _serve_program(on_chip, name, heads)
+    compiled, state = compiled_once(
+        ("gpt", name, heads), lambda: _serve_program(on_chip, name, heads))
     leaf = (f"[{SERVE['n_slots']},{heads},{SERVE['max_len']},{d_head}]")
     whole_leaf = re.findall(
         rf"^\s*(?:ROOT )?%\S+ = bf16{re.escape(leaf)}\S* "
@@ -277,7 +293,7 @@ def _lfm2_program(on_chip, monkeypatch, name):
 
 @pytest.mark.parametrize("name", ["decode", "prefill"])
 def test_lfm2_serve_programs_update_both_kinds_of_state_in_place(
-        on_chip, monkeypatch, name):
+        on_chip, monkeypatch, compiled_once, name):
     """PR 25's fence on the model of PR 26: neither the K/V leaves nor the
     conv-state leaf is produced whole by a ``copy``, ``transpose`` or
     ``scatter``, the donated state is aliased to the output whole, the temporaries leave no room for a copy of a leaf, and
@@ -286,7 +302,8 @@ def test_lfm2_serve_programs_update_both_kinds_of_state_in_place(
     a 4096-position cache, [8, 4, 256, 4352] float32 twice (285 MB, more
     than this cut's one attention layer of cache): a K or V leaf more (134
     MB) would pass the limit."""
-    compiled, state = _lfm2_program(on_chip, monkeypatch, name)
+    compiled, state = compiled_once(
+        ("lfm2", name), lambda: _lfm2_program(on_chip, monkeypatch, name))
     text = compiled.as_text()
     n = LFM2_SERVE["n_slots"]
     for leaf in (f"[{n},8,{LFM2_SERVE['max_len']},64]", f"[{n},3,2048]"):
@@ -305,3 +322,64 @@ def test_lfm2_serve_programs_update_both_kinds_of_state_in_place(
     kernels = re.findall(
         r"^\s*%\w*dtf_moe_gmm\w*(?:\.\d+)? = .*tpu_custom_call", text, re.M)
     assert len(kernels) == 3, kernels
+
+
+# ---- the sampler: a step pays for the vocabulary sorts only if it asks -----
+
+def _computations(text: str) -> dict:
+    """Optimised HLO text -> {computation: its instruction lines}, the
+    entry computation under ``"ENTRY"``."""
+    found = re.findall(r"^(ENTRY )?%(\S+) \(.*?\{\n(.*?)^\}", text,
+                       re.M | re.S)
+    return {"ENTRY" if entry else name: body.splitlines()
+            for entry, name, body in found}
+
+
+def _reached(comps: dict, start, through_conditionals: bool) -> set:
+    """The computations ``start`` calls, directly or not."""
+    seen, todo = set(), list(start)
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            if through_conditionals or " conditional(" not in line:
+                todo += [callee for callee in re.findall(r"%([\w.\-]+)", line)
+                         if callee in comps]
+    return seen
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+@pytest.mark.parametrize("family", ["gpt", "lfm2"])
+def test_serve_programs_sort_only_inside_a_conditional(
+        on_chip, monkeypatch, compiled_once, family, name):
+    """PR 27's fence. The token pick chooses its work once per call, outside
+    any ``vmap`` (``serve_engine._pick_rows``), so the compiler keeps a
+    ``conditional`` and the vocabulary sorts of ``filter_logits_dynamic``
+    stay inside one of its branches: a greedy step runs none (they were 42%
+    and 34% of the two serve cells' device time). Moved back under a
+    ``vmap`` the choice becomes a select, both sides run, and the sorts
+    reappear in what the entry computation executes on every call."""
+    if family == "gpt":
+        compiled, _ = compiled_once(
+            ("gpt", name, 16), lambda: _serve_program(on_chip, name, 16))
+    else:
+        compiled, _ = compiled_once(
+            ("lfm2", name), lambda: _lfm2_program(on_chip, monkeypatch, name))
+    comps = _computations(compiled.as_text())
+    # a model may sort for itself (an expert layer routes and groups its
+    # tokens by sorting, under the model's scope ``GPT/``): the sampler's
+    # sorts are the others
+    sorts = lambda names: [  # noqa: E731
+        line.split(" = ")[0].strip() for c in names for line in comps[c]
+        if re.search(r" sort\(", line)
+        and not re.search(r'op_name="[^"]*/GPT/', line)]
+    always = _reached(comps, ["ENTRY"], through_conditionals=False)
+    assert not sorts(always), sorts(always)
+    branches = [
+        callee for c in always for line in comps[c] if " conditional(" in line
+        for callee in re.findall(r"%([\w.\-]+)", line) if callee in comps]
+    assert branches
+    # gpt.filter_logits_dynamic: one sort and two argsorts
+    assert sorts(_reached(comps, branches, through_conditionals=True))

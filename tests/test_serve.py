@@ -396,3 +396,135 @@ def test_filter_logits_dynamic_matches_static():
                                         top_p=jnp.float32(tp))
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
                                       err_msg=f"top_k={tk} top_p={tp}")
+
+
+# ---- the sampler does only the work its rows ask for -----------------------
+
+_VOCAB = 64
+
+#: per case: (temp, top_k, top_p, live) rows, and the path the call takes
+_PICK_CASES = {
+    "all_greedy": ([0.0] * 4, [0] * 4, [1.0] * 4, [True] * 4, 0),
+    # a greedy row's top_k / top_p ask for nothing: its sample is dropped
+    "greedy_rows_with_gates_set": (
+        [0.0] * 4, [3, 0, 0, 5], [1.0, 0.5, 1.0, 0.9], [True] * 4, 0),
+    "one_row_samples": ([0.0, 0.8, 0.0, 0.0], [0] * 4, [1.0] * 4,
+                        [True] * 4, 1),
+    "one_row_top_k": ([0.0, 0.8, 0.0, 1.3], [0, 5, 0, 0], [1.0] * 4,
+                      [True] * 4, 2),
+    "one_row_top_p": ([0.7, 0.0, 0.0, 0.0], [0] * 4, [0.6, 1.0, 1.0, 1.0],
+                      [True] * 4, 2),
+    "top_k_and_top_p": ([0.7, 0.0, 1.1, 0.0], [0, 0, 7, 0],
+                        [0.6, 1.0, 0.8, 1.0], [True] * 4, 2),
+    "sampling_row_inactive": ([0.0, 0.9, 0.0, 0.0], [0, 4, 0, 0],
+                              [1.0, 0.7, 1.0, 1.0],
+                              [True, False, True, True], 0),
+    "filtering_row_inactive": ([0.5, 0.9, 0.0, 0.0], [0, 4, 0, 0],
+                               [1.0] * 4, [True, False, True, True], 1),
+    # the prefill program's shapes: one row, live on the last chunk
+    "one_row_greedy": ([0.0], [0], [1.0], [True], 0),
+    "one_row_sampled": ([0.9], [0], [1.0], [True], 1),
+    "one_row_filtered": ([0.9], [3], [0.8], [True], 2),
+    "one_row_not_last_chunk": ([0.9], [3], [0.8], [False], 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PICK_CASES))
+def test_pick_rows_matches_the_vmapped_pick(case):
+    """``_pick_rows`` against the formulation it replaced, kept here as the
+    plain reference: one split and one ``_pick`` per row under ``vmap``,
+    every row paying for the filter's sorts and the noise. Every live
+    row's token and every row's rng are bit-identical, and the path index
+    says how little the call had to do."""
+    from dtf_tpu.serve import engine as serve_engine
+
+    temp, top_k, top_p, live, want_path = _PICK_CASES[case]
+    n = len(temp)
+    rng = np.random.default_rng(len(case))
+    logits = jnp.asarray(rng.normal(size=(n, _VOCAB)).astype(np.float32))
+    keys = jnp.asarray(rng.integers(0, 2 ** 32, (n, 2), dtype=np.uint32))
+    temp, top_p = (jnp.asarray(x, jnp.float32) for x in (temp, top_p))
+    top_k, live = jnp.asarray(top_k, jnp.int32), jnp.asarray(live)
+
+    def reference(keys, logits, temp, top_k, top_p):
+        def one(key, lv, t, tk, tp):
+            s2 = jax.random.split(key)
+            return s2[0], serve_engine._pick(s2[1], lv, t, tk, tp)
+
+        return jax.vmap(one)(keys, logits, temp, top_k, top_p)
+
+    def changed(keys, logits, temp, top_k, top_p, live):
+        s2 = jax.vmap(jax.random.split)(keys)
+        toks, path = serve_engine._pick_rows(s2[:, 1], logits, temp, top_k,
+                                             top_p, live)
+        return s2[:, 0], toks, path
+
+    want_rng, want_toks = jax.jit(reference)(keys, logits, temp, top_k, top_p)
+    got_rng, got_toks, path = jax.jit(changed)(keys, logits, temp, top_k,
+                                               top_p, live)
+    assert int(path) == want_path
+    np.testing.assert_array_equal(np.asarray(got_rng), np.asarray(want_rng))
+    keep = np.asarray(live)
+    np.testing.assert_array_equal(np.asarray(got_toks)[keep],
+                                  np.asarray(want_toks)[keep])
+    assert got_toks.dtype == jnp.int32
+    if want_path and n > 1:
+        # the case can tell the paths apart: a sampling row left its arg-max
+        sampled = keep & (np.asarray(temp) > 0)
+        assert (np.asarray(want_toks)[sampled]
+                != np.asarray(jnp.argmax(logits, -1))[sampled]).any()
+
+
+def test_sampler_path_counters(params):
+    """The decode program reports the path it took; the engine counts it
+    where a telemetry object asks (``take_samples``), one count a decode
+    step, and an all-greedy run never leaves path 0. A sampled request
+    moves the steps it shares to path 1, a filtered one to path 2, and the
+    steps after both ended — their slots refilled by greedy requests —
+    are back on 0. Without a telemetry object nothing is read or counted."""
+    from dtf_tpu.telemetry import Telemetry
+
+    def served(requests, telemetry):
+        eng = DecodeEngine(CFG, params, n_slots=2, max_len=MAX_LEN,
+                           prefill_chunk=4)
+        sched = Scheduler(eng, telemetry=telemetry)
+        for req in requests:
+            sched.submit(Request(**req))
+        sched.run_until_idle()
+        assert eng.trace_counts == {"prefill": 1, "decode": 1}
+        steps = {name: eng.counters[f"sampler_steps_{name}"]
+                 for name in ("greedy", "unfiltered", "filtered")}
+        return eng, steps
+
+    greedy = [dict(prompt=[1, 2, 3], max_new=6),
+              dict(prompt=[4, 5], max_new=4, top_k=3, top_p=0.5),
+              dict(prompt=[6], max_new=5)]
+    tel = Telemetry(watchdog=False)
+    eng, steps = served(greedy, tel)
+    assert steps == {"greedy": eng.counters["decode_steps"],
+                     "unfiltered": 0, "filtered": 0}
+    rollup = tel.spans.rollup()["serve_sampler_greedy"]
+    assert rollup["count"] == eng.counters["decode_steps"]
+    assert rollup["mean_s"] == 1.0
+
+    mixed = [dict(prompt=[1, 2, 3], max_new=12),
+             dict(prompt=[4, 5], max_new=3, temperature=0.8, seed=1),
+             dict(prompt=[6], max_new=3, temperature=0.8, top_k=3, seed=2),
+             dict(prompt=[7, 8], max_new=3)]
+    tel = Telemetry(watchdog=False)
+    eng, steps = served(mixed, tel)
+    assert sum(steps.values()) == eng.counters["decode_steps"]
+    assert all(steps.values()), steps
+    assert tel.spans.rollup()["serve_sampler_greedy"]["total_s"] \
+        == steps["greedy"]
+
+    eng, steps = served(mixed, None)
+    assert eng.counters["decode_steps"] and not any(steps.values())
+
+    # a slot whose sampled request ended at its eos stays active, but done:
+    # it asks for nothing while its neighbour decodes on
+    first = _offline(params, mixed[1])[0]
+    ended = [mixed[0], dict(mixed[1], eos_id=first)]
+    eng, steps = served(ended, Telemetry(watchdog=False))
+    assert steps == {"greedy": eng.counters["decode_steps"],
+                     "unfiltered": 0, "filtered": 0}
