@@ -91,9 +91,8 @@ def run_budgeted_jobs(jobs: list, argv: list[str], parse_line, *,
     Returns ``(rows, errors)``; failures append ``{"env": job, "errors":
     [...]}``. ``on_result(row_or_None, job, rows, errors)`` fires after
     every job for incremental artifact writes (partial progress must
-    survive a later hang). This is THE driver loop — bench_lm /
-    bench_decode / bench_attention / perf_sweep all share it so the next
-    script can't drift on budget math or error shape.
+    survive a later hang). One driver loop, so that a script with
+    several jobs cannot drift on budget math or error shape.
     """
     rows, errors = [], []
     for i, job in enumerate(jobs):
@@ -129,10 +128,9 @@ def fence(out):
 
 def probe_backend(*, timeout_s: float = 90, env: Optional[dict] = None):
     """Which backend a child of this script would get: one short child,
-    finished before the first measurement child starts. Only for the
-    scripts whose parent picks its job list by the answer (bench_tune,
-    bench_quant, bench_profile, bench_telemetry — ROADMAP C1); the others
-    just start their child, which fails by itself without a chip.
+    finished before the first measurement child starts. Only for a
+    script whose parent picks its job list by the answer; ``bench.py``
+    just starts its child, which fails by itself without a chip.
 
     Returns ``(backend_name_or_None, errors)``.
     """

@@ -2,8 +2,8 @@
 sanctioned constructor (the ``ring_perm`` idiom applied to file writes).
 
 Every host-plane file that another process or thread READS while this one
-writes it — publish manifests, heartbeats, TELEMETRY/DEVICE_PROFILE merge
-artifacts, controller/postmortem jsonl — must be written through this
+writes it — publish manifests, heartbeats, a log directory's merged
+report files, controller/postmortem jsonl — must be written through this
 module. The host soundness pass (``dtf_tpu/analysis/host.py``) fences the
 jax-free control plane for exactly that: a raw ``open(path, "w")`` or bare
 ``os.rename``/``os.replace`` anywhere else is a ``non-atomic-publish``
@@ -25,10 +25,9 @@ Two primitives, matching the two shapes host files take:
   parser skips the last partial line). Multi-writer jsonl is NOT
   supported — each file has one owning process.
 
-Stdlib-only on purpose: ``_dtf_artifact.py``'s parents must never import
-the ``dtf_tpu`` package (a package import pulls jax; a parent whose
-children need the chip stays off jax), so they load this file directly
-via ``importlib`` file-location instead of the package path.
+Stdlib-only on purpose: a parent whose children need the chip stays off
+jax, and a package import pulls jax, so such a process can load this file
+directly via ``importlib`` file-location instead of the package path.
 """
 
 from __future__ import annotations

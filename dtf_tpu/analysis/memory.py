@@ -443,8 +443,7 @@ def lint_program(config, view, lowered, compiled,
 
 
 # ---------------------------------------------------------------------------
-# temp-vs-scale affine model (shared by the fit planner and
-# scripts/bench_pipe_mem.py's predicted_temp_bytes cross-check)
+# temp-vs-scale affine model (the fit planner's)
 # ---------------------------------------------------------------------------
 
 def affine_temp_model(points: Mapping[int, int]) -> tuple[float, float]:

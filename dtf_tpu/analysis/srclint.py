@@ -219,23 +219,23 @@ def lint_file(path: str) -> list[str]:
         problems += _raw_aot_compiles(tree, path, noqa, src)
 
     # ---- backend imports fenced out of telemetry/tune/fault/stream ----
-    # telemetry: reports parse traces on chipless machines. tune: the
-    # bench_tune parent imports the package and then starts children
-    # that need the chip — a parent that has touched jax would hold it.
+    # telemetry: reports parse traces on chipless machines. tune: a
+    # parent may import the package and then start children that need
+    # the chip — a parent that has touched jax would hold it.
     # fault: the run controller supervises possibly-WEDGED backends from
     # a clean chief process — importing the thing it must outlive would
     # be fatal.
     for pkg, why in (("telemetry", "reports parse traces on chipless "
                       "machines"),
-                     ("tune", "bench_tune's parent imports it and then "
-                      "starts children that need the chip — a parent "
-                      "that has imported a backend may hold it"),
+                     ("tune", "a parent may import it and then start "
+                      "children that need the chip — a parent that "
+                      "has imported a backend may hold it"),
                      ("fault", "the run controller supervises a possibly-"
                       "wedged backend from a clean process and must "
                       "never import what it has to outlive"),
                      ("stream", "the mixture stream is pure host IO "
-                      "whose producer thread and bench row must run — "
-                      "and be testable — with no backend present")):
+                      "whose producer thread must run — and be "
+                      "testable — with no backend present")):
         in_pkg = (pkg in dirs if anchored
                   else bool(dirs) and dirs[-1] == pkg)
         if in_pkg:

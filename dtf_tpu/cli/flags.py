@@ -418,12 +418,13 @@ def resolve_lm_loss(FLAGS, *, batch: int, seq_len: int, vocab_size: int,
 
     The vocab-chunked loss is a MEMORY lever, not a speed lever: it costs
     ~9 MFU points on GPT and ~5 on BERT versus the monolithic [B,T,V]
-    matmul+CE that XLA fuses (PERF.md §5). So: when no fused-loss flag
+    matmul+CE that XLA fuses (BENCH_LM_SWEEP.json rows from before PR 1;
+    both train cells run the monolithic path). So: when no fused-loss flag
     is set and the full logits plus their cotangent fit comfortably per
     device, keep the monolithic path; when they don't, take the banked
     loss-path winner from the kernel-tune cache
     (:func:`dtf_tpu.tune.resolver.lm_loss_winner` — seeded from the
-    on-chip BENCH_LM_SWEEP rows, refreshed by ``bench_tune.py``),
+    on-chip BENCH_LM_SWEEP rows),
     defaulting to the token-chunked fused CE — one full-vocab MXU
     matmul per block, the faster chunking axis — never the vocab scan.
 

@@ -416,9 +416,8 @@ class ProfilerHook(Hook):
     enables the ``file:line`` provenance join. The stock launchers do
     NOT pass it (lowering a twin step just for provenance costs a full
     compile); their windows bucket without attribution, and the join
-    runs where the HLO is already in hand — ``scripts/bench_profile.py``
-    (its own compiled program) or ``python -m dtf_tpu.telemetry report
-    --hlo=...`` over the same trace dir. The parse runs on the host
+    runs where the HLO is already in hand: ``python -m dtf_tpu.telemetry
+    report --hlo=...`` over the same trace dir. The parse runs on the host
     after the window closed: it adds zero work to traced steps and
     degrades to a reason dict when the proto bindings or per-op events
     are absent.
@@ -524,8 +523,8 @@ class ProfilerHook(Hook):
             report = profile_mod.parse_logdir(
                 self.logdir, site_map=site_map, **kw)
             path = os.path.join(self.logdir, "device_profile.json")
-            # atomic: bench_profile and the report CLI read this file
-            # from other processes while windows keep closing
+            # atomic: other processes read this file while windows
+            # keep closing
             atomic_replace(path, json.dumps(report, indent=1))
         except Exception as e:  # noqa: BLE001 — see docstring
             report = {"degraded": f"profile parse failed: "

@@ -568,8 +568,8 @@ def _flash_bwd(causal, window, sm_scale, block_q, block_k, interpret,
     q, k, v, mask_bias, out, lse = res
     # The backward's two grids stream the OPPOSITE extents from the
     # forward (_dq scans k; _dkv scans q), so the fwd-optimal block shape
-    # need not be bwd-optimal — 0 inherits the fwd blocks, the sweep
-    # (bench_attention --sweep-blocks bwd rows) picks better ones.
+    # need not be bwd-optimal — 0 inherits the fwd blocks unless the
+    # kernel-tune cache holds a measured pair for the shape.
     dq, dk, dv = _bwd(q, k, v, mask_bias, out, lse, do, sm_scale=sm_scale,
                       causal=causal, window=window,
                       block_q=block_q_bwd or block_q,
@@ -665,9 +665,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``block_q_bwd`` / ``block_k_bwd`` (0 = auto): separate block shape
     for the two backward kernels. The backward streams the opposite
     extents from the forward (``_dq`` scans k-blocks, ``_dkv`` scans
-    q-blocks), so the fwd-optimal shape is not necessarily bwd-optimal;
-    ``bench_attention.py --sweep-blocks`` / ``bench_tune.py`` measure
-    the bwd rows on chip.
+    q-blocks), so the fwd-optimal shape is not necessarily bwd-optimal
+    (the train cells' ``flash_bwd_roofline`` measures the backward).
 
     Block arguments left at 0 resolve through the kernel-tune cache
     (:mod:`dtf_tpu.tune.resolver` — the banked per-shape on-chip

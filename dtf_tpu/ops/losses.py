@@ -121,11 +121,12 @@ def token_chunked_lm_cross_entropy(x: jax.Array, w_head: jax.Array,
     Same memory guarantee as :func:`chunked_lm_cross_entropy` — live
     logits are O(chunk·V) instead of O(N·V) — but each scan step is ONE
     full-vocab matmul ([chunk, D] x [D, V]) followed by a plain CE, with
-    no online-logsumexp carry. The round-5 on-chip rows showed the
+    no online-logsumexp carry. On-chip rows from before PR 1 showed the
     vocab-chunked scan costs ~9 GPT MFU points over the monolithic loss
-    (BENCH_LM_SWEEP.json; PERF.md §5): its per-step [N, chunk] max/
-    rescale/pick passes are VPU traffic over the whole activation set
-    repeated every chunk, and its carries serialize against the matmul.
+    (BENCH_LM_SWEEP.json, another JAX; no cell runs either): its
+    per-step [N, chunk] max/rescale/pick passes are VPU traffic over
+    the whole activation set repeated every chunk, and its carries
+    serialize against the matmul.
     Token chunking does the lse/pick arithmetic ONCE per token on an
     MXU-shaped [chunk, V] tile, so it should sit between the monolithic
     and vocab-chunked points at the same bounded memory. Chunk the vocab

@@ -30,8 +30,7 @@ The communicated-operand ring twins live in
 ``ops/collective_matmul.py`` (``ag_matmul_quant`` / ``matmul_rs_quant``
 — dequant-after-ppermute, ~2x fewer ring bytes); ``core/comms.tp_dense``
 is the single dispatch point that routes between them. Quality bounds
-are pinned by tests/test_quant.py and banked per shape by
-``scripts/bench_quant.py`` rows.
+are pinned by tests/test_quant.py; no cell times a quantized matmul yet.
 """
 
 from __future__ import annotations

@@ -443,8 +443,8 @@ def pipeline_zb_grads(
     wall clock does not shrink; the win is on the MPMD executor the
     schedule targets, where a device's W fills wall-clock holes between
     dependency-gated F/B ops (see :func:`schedule_bubble_model` for the
-    step-count accounting, and ``scripts/bench_pipe_mem.py`` for the
-    banked rows). On this remat-style path W re-runs the stage forward
+    step-count accounting; no cell times a pipelined step). On this
+    remat-style path W re-runs the stage forward
     from the stashed input (same recompute class as 1F1B's fused
     backward, paid once more).
 
@@ -637,7 +637,7 @@ def schedule_bubble_model(n_stages: int, n_microbatches: int,
     ``(S-1)(t_f+t_b+t_w)`` toward ``(S-1)(t_f+t_b-t_w)`` (ZB-H1). The
     lockstep ``lax.scan`` realisation cannot show this (every round waits
     for the slowest sub-slot fleet-wide); this model is the schedule's
-    honest accounting and is asserted in tests + banked into PIPE_MEM.json.
+    honest accounting and is asserted in tests.
 
     Returns ``{"makespan", "busy", "idle_frac", "bubble"}`` — ``busy`` is
     total work per device-timeline (the same for both schedules), so

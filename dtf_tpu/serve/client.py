@@ -4,9 +4,9 @@
 launcher ``scripts/serve_gpt.py``) talks to — it owns a
 :class:`~dtf_tpu.serve.scheduler.Scheduler` and pumps it. ``PoissonLoadGen``
 produces a reproducible open-loop arrival process (exponential
-inter-arrivals, seeded prompt/length sampling) for benching: the A/B
-against static batched ``generate()`` rides
-``scripts/bench_decode.py --sweep-serve``.
+inter-arrivals, seeded prompt/length sampling) for benching (the
+benchmark's serve cells drive a closed loop of their own:
+``benchmarks/lib/loadgen.py``).
 """
 
 from __future__ import annotations

@@ -1153,8 +1153,8 @@ class Router:
                 counters[k] = counters.get(k, 0) + v
         out.update({f"router_{k}": float(v) for k, v in counters.items()})
         # the control-plane tick profiler panel (ISSUE 20): where the
-        # pump's host time goes, per phase — the live view of what
-        # bench_serve_cp fences and the cp_profile events make durable
+        # pump's host time goes, per phase — the live view of what the
+        # cp_profile events make durable
         out["router_ticks"] = float(self._ticks)
         for name, roll in self._cp.rollup().items():
             out[f"{name}_total_s"] = roll["total_s"]

@@ -26,5 +26,5 @@ from dtf_tpu.telemetry.spans import (SpanRecorder,             # noqa: F401
 from dtf_tpu.telemetry.trace import TraceCollector                 # noqa: F401
 
 # NOTE: dtf_tpu.telemetry.xplane / .profile are imported lazily by their
-# consumers (ProfilerHook, the report CLI, bench_profile.py) — they must
+# consumers (ProfilerHook, the report CLI) — they must
 # stay importable without jax OR tensorflow (srclint lazy-import fence).

@@ -1,15 +1,14 @@
 """MFU / goodput accounting — "what fraction of the hardware are we using,
 and what fraction of the wall clock actually trained?".
 
-MFU follows the two conventions the benches already bank (scripts/
-bench_lm.py, PERF.md §5):
+MFU follows two conventions:
 
 - **analytic**: 6 FLOPs per parameter per token (fwd+bwd weight FLOPs)
   plus the attention term ``12·L·d·s`` per token — the "Scalable Training
   of Language Models using JAX pjit and TPUv4" (arxiv 2204.06514)
   accounting, comparable across papers;
 - **XLA cost analysis**: the AOT ``compiled.cost_analysis()`` flops of the
-  actual program (the bench_cost_table.py idiom) — a LOWER bound (scan
+  actual program — a LOWER bound (scan
   bodies counted once, Pallas custom calls report zero).
 
 Goodput = productive step wall time / total run wall time, with the
@@ -92,13 +91,15 @@ def param_count(params) -> int:
 def analytic_lm_flops_per_step(*, n_params: int, layers: int, width: int,
                                seq_len: int, tokens_per_step: int) -> float:
     """Full-step (fwd+bwd) FLOPs for a dense transformer LM step —
-    ``(6·N + 12·L·d·s) · tokens`` (the bench_lm.py mfu_analytic model)."""
+    ``(6·N + 12·L·d·s) · tokens``, ``N`` being every parameter the
+    caller counts (the benchmark's ``mfu_pct`` leaves out tables that are
+    only looked up: ``benchmarks/lib/flops.py``)."""
     return float(6 * n_params + 12 * layers * width * seq_len) \
         * tokens_per_step
 
 
 def cost_analysis_flops(fn, *args) -> Optional[float]:
-    """Best-effort AOT flops of ``fn(*args)`` (bench_cost_table idiom).
+    """Best-effort AOT flops of ``fn(*args)``.
 
     Returns None when the backend/program offers no cost analysis. NOTE:
     lowering here is a fresh trace of ``fn`` — callers that pin trace

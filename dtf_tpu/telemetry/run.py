@@ -11,8 +11,8 @@ Composes the four pillars:
 
 and emits ONE RunReport dict at the end (the bench.py one-JSON-line
 idiom): per-phase p50/p99, tokens/sec, MFU, goodput buckets, trace/compile
-counts. ``merge_artifact`` folds reports into the committed TELEMETRY.json
-(the STATIC_ANALYSIS.json/BENCH_LM.json pattern: sections survive re-runs).
+counts. ``merge_artifact`` folds reports into a ``TELEMETRY.json`` under
+the run's log directory (bounded list, newest last).
 
 Lifecycle: the Trainer calls ``start()``/``stop()`` around ``fit`` (signal
 hook + watchdog live only inside that window); the launcher calls
@@ -102,7 +102,7 @@ class Telemetry:
         steps/sec into tokens/sec and MFU. Optional: absent, the report
         simply omits those fields. ``throughput_name`` relabels the rate
         key for non-token launchers (``examples_per_sec`` for ResNet/
-        WideDeep) so TELEMETRY.json rows stay comparable."""
+        WideDeep) so merged report rows stay comparable."""
         if tokens_per_step is not None:
             self.tokens_per_step = float(tokens_per_step)
         if model_flops_per_step is not None:
@@ -298,7 +298,7 @@ class Telemetry:
 
 def merge_artifact(path: str, report: Mapping, *, keep_runs: int = 20,
                    meta: Optional[Mapping] = None) -> dict:
-    """Fold one RunReport into the committed TELEMETRY.json artifact.
+    """Fold one RunReport into the ``TELEMETRY.json`` at ``path``.
 
     ``{"runs": [...]}`` with the newest LAST, bounded at ``keep_runs``
     (round timestamps ride in ``meta``); a malformed existing file is

@@ -1,15 +1,12 @@
 """Kernel autotuner: persistent block-shape / loss-path winners.
 
-PERF.md round 5 closed with every remaining MFU lever measured but
-hand-tuned: the on-chip block sweep showed 512x1024 flash blocks run the
-same attention 2.75x faster than the old 512x512 default, the backward
-runs ~92 TF/s against the forward's ~170 with its own (separately
-swept) block optimum, and the loss-path data says the monolithic
-[B,T,V] matmul wins while it fits and token chunking is the right
-bounded-memory fallback. Each of those findings used to be flipped into
-a hard-coded literal by hand each round. This package is the mechanism
-that does it automatically — the same static-search-then-pin discipline
-the pjit-TPUv4 work applies to sharding (PAPERS.md, arxiv 2204.06514):
+Flash block shapes (forward and backward each have their own optimum)
+and the LM loss path (the monolithic [B,T,V] matmul while the logits
+fit, token chunking as the bounded-memory fallback) are choices that
+depend on the shape and the chip. This package keeps such winners in a
+file instead of hard-coded literals — the same static-search-then-pin
+discipline the pjit-TPUv4 work applies to sharding (PAPERS.md, arxiv
+2204.06514):
 
 - :mod:`dtf_tpu.tune.cache` — the persistent winner store: a committed
   repo golden ``KERNEL_TUNE.json`` (banked on-chip winners, readable
@@ -28,10 +25,12 @@ the pjit-TPUv4 work applies to sharding (PAPERS.md, arxiv 2204.06514):
   the LM loss path here. Explicit values still win (with a warning when
   they override a measured winner).
 
-``scripts/bench_tune.py`` is the write side: its parent starts one
-child per candidate, so the whole package is jax-free at module level
-(the telemetry/ discipline) — a parent whose children need the chip
-stays off jax, and resolution must work on a backendless machine.
+Nothing in the repo measures for the tuner today (docs/TUNING.md): an
+entry changes in the PR that measures it on the chip, through
+``merge_entries`` or ``python -m dtf_tpu.tune seed``. The whole package
+is jax-free at module level (the telemetry/ discipline) — a parent whose
+children need the chip stays off jax, and resolution must work on a
+backendless machine.
 
 Docs: docs/TUNING.md.
 """

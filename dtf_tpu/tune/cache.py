@@ -5,12 +5,13 @@ Two files, one precedence rule:
 - ``KERNEL_TUNE.json`` at the repo root — the COMMITTED golden: winners
   measured on chip and banked, so a machine without the chip and the
   CPU sim resolve to real data instead of hard-coded literals. Regenerated by
-  ``python -m dtf_tpu.tune seed`` (from the committed sweep artifacts)
-  or by ``scripts/bench_tune.py`` after an on-chip sweep; committed like
-  the STATIC_ANALYSIS.json golden, with the change justified in the PR.
+  ``python -m dtf_tpu.tune seed`` (from the committed sweep artifacts);
+  committed like the STATIC_ANALYSIS.json golden, with the change
+  justified in the PR that measured it on the chip.
 - ``KERNEL_TUNE.local.json`` next to ``.jax_cache/`` (repo root,
   gitignored) — winners measured on THIS machine; shadows the golden on
-  identical (kind, key) and is where ``bench_tune.py`` banks first.
+  identical (kind, key) and is where a sweep on this machine banks
+  first.
 
 Schema (``schema`` pins it; a reader that sees a different version
 treats the file as stale and falls back — never half-parses):
@@ -18,17 +19,18 @@ treats the file as stale and falls back — never half-parses):
 .. code-block:: json
 
     {"schema": 1,
-     "generated_by": "bench_tune.py r6",
+     "generated_by": "python -m dtf_tpu.tune seed",
      "entries": [
        {"kind": "flash_fwd",
         "key": {"seq": 8192, "heads": 8, "head_dim": 128,
                 "dtype": "bfloat16", "causal": true, "window": 0,
                 "n_devices": 1, "backend": "tpu"},
         "winner": {"block_q": 512, "block_k": 1024, "block_h": 1},
-        "metric": {"flash_fwd_s": 0.001614, "flash_fwd_tflops": 170.31},
+        "metric": {"flash_fwd_s": 1.0e-3},
         "source": "banked fwd block-sweep rows (KERNEL_TUNE_SWEEP.json)",
         "measured": true}]}
 
+(The values illustrate the schema; no such entry is banked.)
 Kinds: ``flash_fwd`` (forward block_q/block_k/block_h), ``flash_bwd``
 (the independent backward blocks), ``fused_ce`` (Pallas CE
 block_n/block_v), ``lm_loss`` (the loss-path choice, keyed by whether

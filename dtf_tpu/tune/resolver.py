@@ -76,8 +76,8 @@ class LossPathPlan:
 
 #: speculative-decode draft width when no winner is banked: proposals are
 #: cheap relative to a verify pass and acceptance decays with depth, so a
-#: mid-size default loses little either way (the bench_decode draft-k
-#: sweep banks the measured per-(model, draft, slots) winner over it).
+#: mid-size default loses little either way (a measured
+#: per-(model, draft, slots) winner in the cache goes over it).
 FALLBACK_SPEC_K = 4
 
 
@@ -221,8 +221,9 @@ def _warn_override_once(kind: str, what: str, explicit: str,
         absl_logging.warning(
             "explicit %s %s=%s overrides the measured kernel-tune "
             "winner %s (%s); drop the explicit value to track the "
-            "banked optimum, or re-sweep with scripts/bench_tune.py "
-            "if the shape changed", kind, what, explicit, winner, source)
+            "banked optimum, or bank a new measurement "
+            "(docs/TUNING.md) if the shape changed", kind, what,
+            explicit, winner, source)
     except Exception:  # pragma: no cover
         pass
 

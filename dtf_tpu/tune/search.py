@@ -1,14 +1,14 @@
 """Candidate spaces, deterministic winner selection, artifact seeding.
 
-The WRITE side of the tuner: ``scripts/bench_tune.py`` measures the
-candidate grids below on chip and banks winners through
-:func:`select_winner`; :func:`seed_entries` re-derives the committed
-``KERNEL_TUNE.json`` golden from the sweep artifacts already in the
-repo (KERNEL_TUNE_SWEEP.json block sweeps, BENCH_LM_SWEEP.json loss
-rows) so a run that only banks raw rows still flips defaults the moment
-``python -m dtf_tpu.tune seed`` (or bench_tune itself, which runs the
-selection step whatever the backend does) is run. No hand-transcription
-of winners into literals.
+The WRITE side of the tuner: the candidate grids below are what a sweep
+on the chip times, :func:`select_winner` picks among its rows, and
+:func:`seed_entries` re-derives the committed ``KERNEL_TUNE.json`` golden
+from the sweep artifacts in the repo (KERNEL_TUNE_SWEEP.json block
+sweeps where one exists, BENCH_LM_SWEEP.json loss rows) when
+``python -m dtf_tpu.tune seed`` is run. No hand-transcription of winners
+into literals. Nothing in the repo produces such rows today
+(docs/TUNING.md; ROADMAP C1c): the source strings below that speak of
+queued rows name measurements that were never taken.
 
 Winner selection is DETERMINISTIC on purpose: min metric, ties broken
 by the canonical JSON of the candidate params — two runs over the same
@@ -17,9 +17,9 @@ the ordering (tests/test_tune.py).
 
 How a new kernel registers candidates: add a ``<kind>_candidates()``
 grid here, give the kernel a 0-sentinel block argument resolved through
-a :mod:`dtf_tpu.tune.resolver` plan, teach ``bench_tune.py`` to time
-the grid, and extend :func:`seed_entries` if its rows land in a
-committed artifact (docs/TUNING.md walks an example).
+a :mod:`dtf_tpu.tune.resolver` plan, time the grid on the chip, and
+extend :func:`seed_entries` if its rows land in a committed artifact
+(docs/TUNING.md walks an example).
 
 jax-free at module level (package discipline).
 """
@@ -45,12 +45,12 @@ FLASH_BWD_CANDIDATES = ((512, 512), (1024, 512), (512, 1024),
 #: default (VMEM bound ~8 MB at D<=1024 — fused_ce.py docstring).
 FUSED_CE_CANDIDATES = ((256, 1024), (512, 512), (512, 1024), (512, 2048),
                        (1024, 1024))
-#: LM loss paths A/B'd by bench_tune (chunk values are the banked sweep
-#: shapes: AUTO_LOSS_CHUNK_TOKENS / the vocab ladder's 8192).
+#: LM loss paths to A/B (chunk values are the banked sweep shapes:
+#: AUTO_LOSS_CHUNK_TOKENS / the vocab ladder's 8192).
 LM_LOSS_CANDIDATES = (("monolithic", 0), ("chunk_tokens", 4096),
                       ("chunk_vocab", 8192), ("pallas", 0))
-#: the tp_dense precision axis bench_quant A/Bs per (parallel, shape)
-#: site. bf16 is the control every row is judged against.
+#: the tp_dense precision axis, A/B'd per (parallel, shape) site. bf16
+#: is the control every row is judged against.
 MATMUL_PRECISION_CANDIDATES = ("bf16", "int8", "fp8")
 #: quality ceiling a low-precision row must beat to be ELIGIBLE as a
 #: winner: Frobenius rel-err of the quantized projection output vs the
@@ -138,10 +138,10 @@ def _attn_key(row: dict, backend: str = "tpu") -> dict:
                 window=0, n_devices=1, backend=backend)
 
 
-#: bench_tune.py persists its raw on-chip sweep rows here (committed),
-#: so the golden is ALWAYS re-derivable from artifacts — a re-seed
-#: after a measuring round reproduces the measured winners instead of
-#: reverting them to older data.
+#: raw on-chip sweep rows are persisted here (committed), so the golden
+#: is ALWAYS re-derivable from artifacts — a re-seed after a measuring
+#: round reproduces the measured winners instead of reverting them to
+#: older data.
 SWEEP_ARTIFACT = "KERNEL_TUNE_SWEEP.json"
 
 
@@ -156,8 +156,7 @@ def _is_bwd_row(row: dict) -> bool:
 
 def seed_flash_entries(root: str) -> list[Entry]:
     """flash_fwd/flash_bwd winners per SHAPE from the banked sweeps:
-    bench_tune's persisted rows (KERNEL_TUNE_SWEEP.json) plus, where a
-    run of ``bench_attention.py --sweep-blocks`` has written one,
+    persisted rows (KERNEL_TUNE_SWEEP.json) plus, where one exists,
     ATTN_BENCH.json's ``tpu.block_sweep`` / ``tpu.bwd_block_sweep``.
     Neither file is committed today: no sweep has been taken on the
     present chip and JAX, so this seeds nothing and the kernels run
@@ -247,8 +246,8 @@ def seed_lm_loss_entries(root: str) -> list[Entry]:
     resolver will query. Within the fits=True bucket the data decides
     outright (round 5: monolithic 58.0%% vs vocab-chunk 48.9%%). In the
     fits=False bucket only the vocab scan is measured so far; the
-    token-chunk A/B rides the bench_tune queue, and until it banks, the
-    entry encodes the PERF.md §5 chunk-axis ordering (token chunking:
+    token-chunk A/B was never taken, and until one banks, the
+    entry encodes a chunk-axis ordering from before PR 1 (token chunking:
     one full-vocab MXU matmul per block vs the serialized vocab scan
     that costs ~9 MFU points) as a measured=False policy winner — the
     measured vocab rows are recorded as alternatives in the metric."""
@@ -258,8 +257,8 @@ def seed_lm_loss_entries(root: str) -> list[Entry]:
 
     raw = list(_read_json(
         os.path.join(root, "BENCH_LM_SWEEP.json")).get("rows", []))
-    # bench_tune's own A/B rows (BENCH_LM.json "loss_path") join the
-    # pool — newer rows land later and win ties deterministically only
+    # A/B rows under BENCH_LM.json "loss_path", where that file exists,
+    # join the pool — newer rows land later and win ties deterministically only
     # via the canonical-JSON tie-break, but a real delta decides on data.
     raw += list((_read_json(os.path.join(root, "BENCH_LM.json"))
                  .get("loss_path") or {}).get("rows", []))
@@ -303,8 +302,8 @@ def seed_lm_loss_entries(root: str) -> list[Entry]:
                 measured=True))
         else:
             # only the vocab scan is measured where logits don't fit:
-            # bank the PERF-ordered token-chunk preference until the
-            # bench_tune A/B replaces it with data.
+            # bank the token-chunk preference until an A/B on the chip
+            # replaces it with data.
             entries.append(Entry(
                 kind="lm_loss", key=key,
                 winner={"path": "chunk_tokens",
@@ -321,8 +320,8 @@ def seed_lm_loss_entries(root: str) -> list[Entry]:
 
 def seed_spec_k_entries(root: str) -> list[Entry]:
     """spec_k winners per (model, draft, slots, backend) from the serve
-    sweep rows (``bench_decode --sweep-serve`` draft-k axis, merged under
-    BENCH_LM.json "serve"): best GOODPUT tokens/sec among the swept k
+    sweep rows (a draft-k axis, merged under BENCH_LM.json "serve"; no
+    such file is committed): best GOODPUT tokens/sec among the swept k
     values on the same seeded arrivals. Rows carry the architecture
     labels the engine's resolver queries (``model_arch``/``draft_arch``
     — serve/engine.py ``_cfg_label``), so a banked winner lands exactly
@@ -384,7 +383,7 @@ def spec_policy_entries() -> list[Entry]:
 
 def seed_precision_entries(root: str) -> list[Entry]:
     """matmul_precision winners per (parallel, d_in, d_out, dtype) site
-    from the banked bench_quant rows (KERNEL_TUNE_SWEEP.json
+    from banked precision rows (KERNEL_TUNE_SWEEP.json
     ``precision_rows``): fastest ``matmul_s`` among rows inside the
     rel-err ceiling — a site where nothing beats bf16 banks bf16, which
     is itself useful data (``--matmul_precision=int8`` there warns)."""
@@ -430,8 +429,8 @@ def precision_policy_entries() -> list[Entry]:
     so a draft-side quality miss costs only acceptance rate, never
     correctness; that asymmetry is why the draft gets the first
     low-precision win. measured=False: an explicit --draft_precision
-    never warns about overriding a guess, and the next bench_quant
-    round replaces these with timed rows at the same keys."""
+    never warns about overriding a guess; timed rows at the same keys
+    would replace these."""
     src = ("policy default pending the queued bench_quant precision "
            "rows (draft-side only: the bf16 verifier keeps emitted "
            "tokens byte-identical; re-seed after rows bank)")

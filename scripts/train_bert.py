@@ -142,7 +142,7 @@ def main(argv):
 
     tokens_per_step = model_flops = None
     if tel is not None:
-        # analytic MFU model (bench_lm mfu_analytic convention); an AOT
+        # analytic MFU model (telemetry/accounting.py); an AOT
         # cost_analysis() would re-trace the step and unpin the fence
         from dtf_tpu.telemetry import (analytic_lm_flops_per_step,
                                        param_count)

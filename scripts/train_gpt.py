@@ -462,9 +462,9 @@ def main(argv):
 
     tokens_per_step = model_flops = None
     if tel is not None:
-        # analytic MFU model (the bench_lm mfu_analytic convention): no
-        # extra trace — an AOT cost_analysis() here would re-lower the
-        # step and unpin the compile fence (telemetry/accounting.py)
+        # analytic MFU model: no extra trace — an AOT cost_analysis()
+        # here would re-lower the step and unpin the compile fence
+        # (telemetry/accounting.py)
         from dtf_tpu.telemetry import (analytic_lm_flops_per_step,
                                        param_count)
 

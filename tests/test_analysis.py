@@ -732,8 +732,8 @@ def test_srclint_fences_backend_imports_in_fault(tmp_path):
 
 def test_srclint_fences_backend_imports_in_stream(tmp_path):
     """ISSUE 15 satellite: dtf_tpu/data/stream/ is fenced like fault/ and
-    tune/ — the mixture stream is pure host IO whose producer thread and
-    bench row must run with no backend present. Lazy in-function imports
+    tune/ — the mixture stream is pure host IO whose producer thread must
+    run with no backend present. Lazy in-function imports
     pass; the shipping stream package must be clean."""
     from dtf_tpu.analysis import srclint
 

@@ -6,8 +6,8 @@ the Router's quarantine→requeue→recovery and swap→canary→commit/rollback
 episodes landing on the plane with injectable-clock duration ground
 truth, the tick profiler's phase attribution with the zero-device-
 readback cast-counting proof, Heartbeat per-(replica, excursion) episode
-dedup, controller/publish/stream/checkpoint mirrors, byte-identical
-timeline determinism, and the CONTROL_PLANE.json fence failing closed.
+dedup, controller/publish/stream/checkpoint mirrors, and byte-identical
+timeline determinism.
 
 Everything host-timed runs on injectable clocks; the launcher chaos e2e
 (serve_gpt under DTF_FAULT_INJECT → ``python -m dtf_tpu.telemetry
@@ -573,47 +573,6 @@ def test_timeline_empty_logdir_degrades_with_note(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# CONTROL_PLANE.json fence: fails closed on a seeded regression
-# ---------------------------------------------------------------------------
-
-def _load_bench_cp():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_serve_cp", os.path.join(ROOT, "scripts",
-                                       "bench_serve_cp.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_cp_fence_fails_closed_on_seeded_regression():
-    cp = _load_bench_cp()
-    base = {"bench": "serve_cp", "tiny": True, "replicas": 4,
-            "n_slots": 4, "requests": 64, "max_new": 8,
-            "ticks_per_sec": 10000.0, "ts": 1.0}
-    row = dict(base, ticks_per_sec=4000.0)       # below the 50% floor
-    ok, detail = cp.check_fence([base], row, tol_frac=0.5)
-    assert not ok and detail["fenced"] and detail["floor"] == 5000.0
-    ok, _ = cp.check_fence([base], dict(base, ticks_per_sec=6000.0),
-                           tol_frac=0.5)
-    assert ok                                    # inside tolerance
-    # a different fleet shape is never comparable
-    ok, detail = cp.check_fence(
-        [dict(base, replicas=2)], row, tol_frac=0.5)
-    assert ok and not detail["fenced"]
-    # an errored row is reported, not fenced
-    ok, detail = cp.check_fence([base], {"bench": "serve_cp",
-                                         "error": "child died"})
-    assert ok and not detail["fenced"]
-    # the newest same-config row is the baseline
-    ok, detail = cp.check_fence(
-        [base, dict(base, ticks_per_sec=3000.0, ts=2.0)], row,
-        tol_frac=0.5)
-    assert ok and detail["baseline_ticks_per_sec"] == 3000.0
-
-
-# ---------------------------------------------------------------------------
 # jax-freeness: the plane + timeline run on chipless machines
 # ---------------------------------------------------------------------------
 
@@ -712,24 +671,3 @@ def test_chaos_launcher_event_plane_and_timeline_cli_e2e(tmp_path):
     assert rep["slo"]["requeue"]["requeued"] >= 1
     assert os.path.exists(chrome)
     assert rep["chrome_trace_events"] >= rep["entries"]
-
-
-@pytest.mark.slow
-def test_bench_serve_cp_tiny_child_reports(tmp_path):
-    """DTF_CP_TINY=1 child pin: the measured half emits one SENTINEL
-    report with the phase attribution (the artifact merge path is unit-
-    tested through check_fence — the committed CONTROL_PLANE.json is
-    never touched from tests)."""
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "scripts",
-                                      "bench_serve_cp.py"), "--child"],
-        env=_env(DTF_CP_TINY="1",
-                 XLA_FLAGS="--xla_force_host_platform_device_count=1"),
-        capture_output=True, text=True, timeout=300, cwd=str(tmp_path))
-    assert proc.returncode == 0, (proc.stdout[-1500:], proc.stderr[-1500:])
-    line = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("SERVE_CP ")][-1]
-    rep = json.loads(line[len("SERVE_CP "):])
-    assert rep["tiny"] and rep["completed"] == rep["requests"] == 64
-    assert rep["ticks_per_sec"] > 0
-    assert "cp_pick_total_s" in rep and "cp_engine_tick_total_s" in rep

@@ -1,7 +1,6 @@
 """Device-time attribution (ISSUE 8): the XPlane parser, category buckets,
 the per-collective ``file:line`` provenance join, comm/compute overlap
-efficiency, the device-MFU cross-check, chrome-trace export, and the
-bench-script regression fences.
+efficiency, the device-MFU cross-check and chrome-trace export.
 
 Anchored on the committed synthetic fixture
 (``tests/data/xplane_synthetic.pb``, built by tests/xplane_fixture.py):
@@ -364,125 +363,6 @@ def test_report_cli_one_json_line(tmp_path, cpu_sim_subprocess_env):
     assert json.load(open(chrome))["traceEvents"]
 
 
-# --------------------------------------------------------------------------
-# bench fences: fail closed on regression, pass on justified update
-# --------------------------------------------------------------------------
-
-sys.path.insert(0, os.path.join(ROOT, "scripts"))
-import bench_profile                                    # noqa: E402
-import bench_telemetry                                  # noqa: E402
-
-
-def _tel_row(mfu, backend="tpu", **kw):
-    return {"telemetry": "run_report", "backend": backend, "model": "gpt",
-            "tiny": False, "batch": 8, "seq": 512, "mfu": mfu, "ts": 1.0,
-            **kw}
-
-
-def test_mfu_fence_regression_fails_closed():
-    prev = [_tel_row(0.58)]
-    ok, detail = bench_telemetry.check_mfu_fence(
-        prev, _tel_row(0.45), tol_frac=0.10)
-    assert not ok
-    assert detail["fenced"] and detail["floor"] == pytest.approx(0.522)
-
-
-def test_mfu_fence_within_tolerance_passes():
-    ok, _ = bench_telemetry.check_mfu_fence(
-        [_tel_row(0.58)], _tel_row(0.55), tol_frac=0.10)
-    assert ok
-
-
-def test_mfu_fence_ignores_cpu_rows_and_different_configs():
-    ok, d = bench_telemetry.check_mfu_fence(
-        [_tel_row(0.58)], _tel_row(0.0001, backend="cpu"))
-    assert ok and not d["fenced"]
-    # different seq → not comparable → no baseline → pass
-    ok, d = bench_telemetry.check_mfu_fence(
-        [_tel_row(0.58)], {**_tel_row(0.01), "seq": 1024})
-    assert ok and not d["fenced"]
-
-
-def test_mfu_fence_baseline_skips_error_rows():
-    prev = [_tel_row(0.58), {**_tel_row(None), "error": "child died",
-                             "mfu": None}]
-    base = bench_telemetry.fence_baseline(prev, _tel_row(0.50))
-    assert base["mfu"] == 0.58
-
-
-def _run_bench_telemetry_main(tmp_path, monkeypatch, argv, report):
-    """Drive bench_telemetry.main() with the probe + child stubbed — the
-    full fail-closed / justified-update flow without a backend."""
-    import _dtf_watchdog
-
-    artifact = tmp_path / "TELEMETRY.json"
-    artifact.write_text(json.dumps({"runs": [_tel_row(0.58)]}))
-    monkeypatch.setattr(bench_telemetry, "ARTIFACT", str(artifact))
-    monkeypatch.setattr(_dtf_watchdog, "probe_backend",
-                        lambda **kw: ("tpu", []))
-    monkeypatch.setattr(_dtf_watchdog, "run_watchdogged",
-                        lambda *a, **kw: (report, []))
-    rc = bench_telemetry.main(argv)
-    return rc, json.loads(artifact.read_text())
-
-
-def test_bench_telemetry_seeded_regression_fails_closed(
-        tmp_path, monkeypatch, capsys):
-    rc, artifact = _run_bench_telemetry_main(
-        tmp_path, monkeypatch, [], _tel_row(0.40))
-    assert rc == 1
-    assert len(artifact["runs"]) == 1          # regressed row NOT merged
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["ok"] is False and "regression" in out["error"]
-
-
-def test_bench_telemetry_justified_update_passes(
-        tmp_path, monkeypatch, capsys):
-    rc, artifact = _run_bench_telemetry_main(
-        tmp_path, monkeypatch,
-        ["--allow-mfu-regression=bwd block sweep changed the default"],
-        _tel_row(0.40))
-    assert rc == 0
-    assert len(artifact["runs"]) == 2
-    new = artifact["runs"][-1]
-    assert new["mfu"] == 0.40
-    assert "bwd block sweep" in new["mfu_justification"]
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["ok"] is True
-
-
-def test_bench_telemetry_improvement_merges_clean(tmp_path, monkeypatch):
-    rc, artifact = _run_bench_telemetry_main(
-        tmp_path, monkeypatch, [], _tel_row(0.61))
-    assert rc == 0
-    assert artifact["runs"][-1]["mfu"] == 0.61
-    assert "mfu_justification" not in artifact["runs"][-1]
-
-
-def test_bench_profile_kill_test_one_json_line_rc0(
-        tmp_path, cpu_sim_subprocess_env):
-    """Without a backend: the probe child fails fast, the artifact
-    records a structured error, stdout is EXACTLY one parseable JSON
-    line, rc 0 (this script keeps that contract until the benchmark PR
-    turns it into a cell — ROADMAP C1)."""
-    import subprocess
-
-    artifact = tmp_path / "DEVICE_PROFILE.json"
-    env = dict(cpu_sim_subprocess_env)
-    env["JAX_PLATFORMS"] = "no_such_platform"
-    env["DTF_PROF_ARTIFACT"] = str(artifact)
-    env["DTF_PROF_BUDGET_S"] = "300"
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "scripts", "bench_profile.py")],
-        env=env, capture_output=True, text=True, timeout=60)
-    assert proc.returncode == 0
-    lines = proc.stdout.strip().splitlines()
-    assert len(lines) == 1, lines
-    assert json.loads(lines[0])["error"] == "probe failed"
-    saved = json.loads(artifact.read_text())
-    assert "backend unavailable" in saved["runs"][-1]["error"]
-
-
 @pytest.mark.slow
 def test_profiler_hook_gpt_window_round_trip_on_cpu_sim(tmp_path):
     """ISSUE 8 acceptance, hook edition: a ProfilerHook window inside a
@@ -515,60 +395,3 @@ def test_profiler_hook_gpt_window_round_trip_on_cpu_sim(tmp_path):
     assert out["run_report_has_device_profile"]
     with open(os.path.join(logdir, "device_profile.json")) as f:
         assert json.load(f)["buckets"]
-
-
-@pytest.mark.slow
-def test_bench_profile_gpt_round_trip_on_cpu_sim(tmp_path):
-    """ISSUE 8 acceptance: the GPT train step round-trips capture→parse
-    on the 8-device CPU sim — per-category buckets AND per-collective
-    file:line provenance rows out of a real XPlane window, banked through
-    the full probe-first bench_profile pipeline."""
-    import subprocess
-
-    from _dtf_env import cpu_sim_env
-
-    artifact = tmp_path / "DEVICE_PROFILE.json"
-    env = cpu_sim_env(8, os.environ)
-    env["DTF_PROF_ARTIFACT"] = str(artifact)
-    env["DTF_PROF_TINY"] = "1"
-    env["DTF_PROF_STEPS"] = "3"
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "scripts", "bench_profile.py")],
-        env=env, capture_output=True, text=True, timeout=900)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert out["ok"] is True, out
-    row = json.loads(artifact.read_text())["runs"][-1]
-    assert row["backend"] == "cpu" and row["n_steps"] == 3
-    # per-op device events parsed and bucketed (the parent injected the
-    # CPU xprof-traceme flag) — the GPT step is matmul-heavy
-    assert row["n_op_events"] > 0 and row["buckets"]
-    assert "matmul" in row["buckets"]
-    # per-collective provenance rows joined to repo file:line — the
-    # dp8 gradient mean all-reduce must attribute INSIDE dtf_tpu/
-    locs = [r["loc"] for r in row["collectives"]]
-    assert locs, row.get("collectives")
-    assert any(loc.startswith("dtf_tpu/") for loc in locs), locs
-    # a CPU run names no device utilization: no peak, no mfu_device
-    assert "mfu_device" not in row
-    assert row["steps"]["device_busy_frac"] > 0
-
-
-def _prof_row(mfu_device, ring=0.8, backend="tpu"):
-    return {"telemetry": "device_profile", "backend": backend,
-            "model": "gpt", "tiny": False, "batch": 8, "seq": 512,
-            "mfu_device": mfu_device, "ts": 1.0,
-            "overlap": {"collective-permute": {"hidden_frac": ring}}}
-
-
-def test_profile_fence_mfu_and_overlap():
-    prev = [_prof_row(0.60, ring=0.80)]
-    ok, _ = bench_profile.check_profile_fence(prev, _prof_row(0.58, 0.78))
-    assert ok                                   # inside both tolerances
-    ok, d = bench_profile.check_profile_fence(prev, _prof_row(0.50, 0.80))
-    assert not ok and d["mfu_device"]["got"] == 0.50
-    ok, d = bench_profile.check_profile_fence(prev, _prof_row(0.60, 0.60))
-    assert not ok                               # ring un-hidden by 0.20
-    ok, d = bench_profile.check_profile_fence(
-        prev, _prof_row(0.001, 0.0, backend="cpu"))
-    assert ok and not d["fenced"]               # sim rows never fenced

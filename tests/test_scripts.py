@@ -139,194 +139,6 @@ def test_gpt_train_then_generate_round_trip(tmp_path):
     assert any(ln.startswith("5,9,2,") for ln in gen_sampled.splitlines())
 
 
-def test_bench_lm_child_tiny_pallas_loss():
-    """CI-pin the DTF_LM_LOSS_PALLAS bench path (the fused head+CE row):
-    the kernel runs in interpret mode on the sim, so a wiring typo can't
-    surface for the first time mid-benchmark on the chip."""
-    import json
-
-    env = _env()
-    env.update(DTF_LM_WHICH="gpt", DTF_LM_TINY="1", DTF_LM_STEPS="2",
-               DTF_LM_LOSS_PALLAS="1")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "scripts", "bench_lm.py"),
-         "--child"],
-        env=env, capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stdout[-1500:] + proc.stderr[-1500:]
-    row = next(json.loads(ln[len("BENCH_LM_ROW "):])
-               for ln in proc.stdout.splitlines()
-               if ln.startswith("BENCH_LM_ROW "))
-    assert row["loss_pallas"] is True and row["tokens_per_sec"] > 0
-
-
-@pytest.mark.parametrize("which", ["gpt", "bert", "widedeep"])
-def test_bench_lm_child_tiny_mode(which, tmp_path):
-    """The LM bench children normally execute only on the TPU; tiny-mode
-    CPU runs pin their code paths in CI so a regression can't surface for
-    the first time mid-benchmark on the chip."""
-    env = _env()
-    env["DTF_LM_WHICH"] = which
-    env["DTF_LM_TINY"] = "1"
-    env["DTF_LM_STEPS"] = "2"
-    if which == "widedeep":
-        env["DTF_LM_BATCH"] = "64"
-    elif which == "bert":
-        # tiny default (8) x grad_accum 2 -> microbatch 4, which the
-        # 8-device sim can't shard; the TPU target is a single chip
-        env["DTF_LM_BATCH"] = "32"
-        env["DTF_LM_LOSS_CHUNK"] = "48"   # CI-pin the chunked-MLM path
-        env["DTF_LM_MLM_GATHER"] = "16"   # + the masked-position gather
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "scripts", "bench_lm.py"),
-         "--child"],
-        env=env, capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stdout[-1500:] + proc.stderr[-1500:]
-    import json
-
-    rows = [json.loads(ln[len("BENCH_LM_ROW "):])
-            for ln in proc.stdout.splitlines()
-            if ln.startswith("BENCH_LM_ROW ")]
-    assert len(rows) == 1
-    row = rows[0]
-    assert row["model"] == which and row["sec_per_step"] > 0
-    key = "tokens_per_sec" if which in ("gpt", "bert") else "examples_per_sec"
-    assert row[key] > 0
-
-
-def test_bench_attention_tpu_child_interpret_mode():
-    """CI-pin the TPU attention-bench child (incl. the h-folded forward
-    grid) via its interpret-mode escape hatch — a wiring typo must not
-    surface for the first time on the chip."""
-    import json
-
-    env = _env()
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
-    env.update(DTF_ATTN_SEQ="256", DTF_ATTN_BQ="64", DTF_ATTN_BK="64",
-               DTF_ATTN_BH="2", DTF_ATTN_BQB="128", DTF_ATTN_BKB="64",
-               DTF_ATTN_INTERPRET="1")
-    proc = subprocess.run(
-        [sys.executable,
-         os.path.join(ROOT, "scripts", "bench_attention.py"), "tpu",
-         "--child"],
-        env=env, capture_output=True, text=True, timeout=560)
-    assert proc.returncode == 0, proc.stdout[-1500:] + proc.stderr[-1500:]
-    row = next(json.loads(ln[len("ATTN_TPU_RESULT "):])
-               for ln in proc.stdout.splitlines()
-               if ln.startswith("ATTN_TPU_RESULT "))
-    assert row["seq"] == 256 and row["block_h"] == 2
-    assert row["flash_fwd_s"] > 0 and row["flash_fwdbwd_s"] > 0
-
-
-def test_bench_lm_phase_child_tiny_mode():
-    """CI-pin the fwd/fwdbwd phase-decomposition children: the backward
-    must stay live in the timed graph (its XLA flop count must be well
-    above the forward's), or the MFU attribution run would silently time
-    a dead-code-eliminated graph."""
-    import json
-
-    flops = {}
-    for phase in ("fwd", "fwdbwd"):
-        env = _env()
-        env.update(DTF_LM_WHICH="gpt", DTF_LM_TINY="1", DTF_LM_STEPS="2",
-                   DTF_LM_PHASE=phase)
-        proc = subprocess.run(
-            [sys.executable, os.path.join(ROOT, "scripts", "bench_lm.py"),
-             "--child"],
-            env=env, capture_output=True, text=True, timeout=420)
-        assert proc.returncode == 0, proc.stdout[-1500:] + proc.stderr[-1500:]
-        row = next(json.loads(ln[len("BENCH_LM_ROW "):])
-                   for ln in proc.stdout.splitlines()
-                   if ln.startswith("BENCH_LM_ROW "))
-        assert row["phase"] == phase and row["tokens_per_sec"] > 0
-        flops[phase] = row.get("xla_flops_per_step", 0.0)
-    assert flops["fwdbwd"] > 2.0 * flops["fwd"]
-
-
-@pytest.mark.parametrize("kv,window,chunk",
-                         [("0", "0", "0"), ("2", "8", "0"),
-                          ("2", "8", "4")])
-def test_bench_decode_child_tiny_mode(kv, window, chunk):
-    """CI-pin the decode benchmark children (MHA/full, GQA/rolling, and
-    chunked-prefill corners) so the serving-bench code path can't regress
-    untested until the next on-chip run."""
-    env = _env()
-    env.update(DTF_DECODE_TINY="1", DTF_DEC_KV=kv, DTF_DEC_WINDOW=window,
-               DTF_DEC_PREFILL_CHUNK=chunk)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "scripts", "bench_decode.py"),
-         "--child"],
-        env=env, capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stdout[-1500:] + proc.stderr[-1500:]
-    import json
-
-    rows = [json.loads(ln[len("BENCH_DECODE_ROW "):])
-            for ln in proc.stdout.splitlines()
-            if ln.startswith("BENCH_DECODE_ROW ")]
-    assert len(rows) == 1
-    row = rows[0]
-    assert row["prefill_tokens_per_sec"] > 0
-    # tiny-mode decode deltas may be inside dispatch noise — then the row
-    # must say so instead of carrying a nonsense number
-    if row.get("decode_noise_limited"):
-        assert row["decode_tokens_per_sec"] is None
-    else:
-        assert row["decode_tokens_per_sec"] > 0
-    assert row["kv_heads"] == (int(kv) or 4) and row["window"] == int(window)
-    assert row["prefill_chunk"] == int(chunk)
-
-
-def test_bench_decode_serve_ab_child_tiny_mode():
-    """The continuous-vs-static A/B child (--sweep-serve): one row with
-    both sides' goodput and TTFT percentiles, on the CPU sim."""
-    env = _env()
-    env.update(DTF_DECODE_TINY="1", DTF_SERVE_RATE="500", DTF_SERVE_N="8")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "scripts", "bench_decode.py"),
-         "--child", "--serve"],
-        env=env, capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stdout[-1500:] + proc.stderr[-1500:]
-    import json
-
-    rows = [json.loads(ln[len("BENCH_DECODE_ROW "):])
-            for ln in proc.stdout.splitlines()
-            if ln.startswith("BENCH_DECODE_ROW ")]
-    assert len(rows) == 1
-    row = rows[0]
-    for side in ("serve", "static"):
-        assert row[side]["tokens_per_sec"] > 0
-        assert row[side]["ttft_p50_s"] <= row[side]["ttft_p99_s"]
-    assert 0 < row["serve"]["occupancy_mean"] <= 1
-
-
-def test_bench_decode_serve_prefix_ab_child_tiny_mode():
-    """The prefix-cache A/B (ISSUE 6 acceptance): at hit-ratio > 0 the
-    page cache strictly reduces prefill work (fewer transformer chunks,
-    pages genuinely loaded) and improves TTFT p50 vs the same arrivals
-    with the cache off, on the CPU sim."""
-    env = _env()
-    env.update(DTF_DECODE_TINY="1", DTF_SERVE_RATE="500", DTF_SERVE_N="12",
-               DTF_SERVE_PREFIX="0.75")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "scripts", "bench_decode.py"),
-         "--child", "--serve"],
-        env=env, capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stdout[-1500:] + proc.stderr[-1500:]
-    import json
-
-    rows = [json.loads(ln[len("BENCH_DECODE_ROW "):])
-            for ln in proc.stdout.splitlines()
-            if ln.startswith("BENCH_DECODE_ROW ")]
-    assert len(rows) == 1
-    on, off = rows[0]["serve"], rows[0]["serve_off"]
-    # prefill-work reduction is deterministic (host counters)
-    assert on["prefill_chunks"] < off["prefill_chunks"], (on, off)
-    assert on["pages_loaded"] > 0 and on["prefix_hit_tokens"] > 0
-    assert off["pages_loaded"] == 0
-    # the latency claim (wall clocks — a small margin absorbs CI noise;
-    # the measured gap is ~25-40% in favor of the cache)
-    assert on["ttft_p50_s"] <= off["ttft_p50_s"] * 1.1, (on, off)
-
-
 def test_serve_launcher_round_trip(tmp_path):
     """train_gpt → serve_gpt: the online half of the flagship loop. The
     launcher restores the params-only item, auto-loads the manifest (no
@@ -453,63 +265,3 @@ def test_generate_rejects_sampling_flags_at_greedy(tmp_path):
         env=_env(), capture_output=True, text=True, timeout=120)
     assert proc.returncode != 0
     assert "temperature" in (proc.stdout + proc.stderr)
-
-
-@pytest.mark.parametrize("which", ["gpt", "bert"])
-def test_bench_cost_table_child_tiny_mode(which):
-    """CI-pin the profiler-fallback attribution (bench_cost_table.py):
-    component rows + whole-program anchors emit, percentages computable,
-    so the on-chip run can't be the first execution of this code."""
-    env = _env()
-    env["DTF_COST_WHICH"] = which
-    env["DTF_COST_TINY"] = "1"
-    env["DTF_COST_ITERS"] = "3"
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "scripts",
-                                      "bench_cost_table.py"), "--child"],
-        env=env, capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stdout[-1500:] + proc.stderr[-1500:]
-    import json
-
-    rows = [json.loads(ln[len("BENCH_COST_ROW "):])
-            for ln in proc.stdout.splitlines()
-            if ln.startswith("BENCH_COST_ROW ")]
-    assert len(rows) == 1
-    row = rows[0]
-    names = {c["component"] for c in row["components"]}
-    assert names == {"embed", "attn_layer", "ffn_layer", "head_loss"}
-    assert row["fwd_sec"] > 0 and row["fwdbwd_sec"] > row["fwd_sec"]
-    assert all(c["sec"] > 0 and c["xla_flops"] > 0
-               for c in row["components"])
-
-
-def test_bench_io_tiny_mode():
-    """CI-pin the host-side IO bench (bench_io.py): python + native rows
-    emit for both the IDX epoch path and TFRecord indexing, so the
-    artifact run can't be the first execution of this code. No jax, no
-    device — plain host subprocess."""
-    from dtf_tpu.data.native import native_available
-
-    if not native_available():
-        pytest.skip("no C++ toolchain")  # bench still runs, python-only
-    env = dict(os.environ)
-    env["DTF_IO_TINY"] = "1"
-    env["PYTHONPATH"] = ROOT
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "scripts", "bench_io.py")],
-        env=env, capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stdout[-1500:] + proc.stderr[-1500:]
-    import json
-
-    row = json.loads(proc.stdout.splitlines()[-1])
-    assert row["tiny"] is True
-    assert row["idx_epoch"]["python_images_per_sec"] > 0
-    assert row["idx_epoch"]["native_images_per_sec"] > 0
-    tf = row["tfrecord_index"]
-    assert tf["python_index_mb_per_sec"] > 0
-    assert tf["native_index_mb_per_sec"] > 0
-    assert tf["native_verifies_payload_crc"] is True
-    ms = row["mixture_stream"]          # ISSUE 15: the stream tier's row
-    assert ms["inline_batches_per_sec"] > 0
-    assert ms["producer_depth2_batches_per_sec"] > 0
-    assert abs(ms["realized_frac_a"] - 0.7) < 0.1

@@ -4,8 +4,8 @@ Covers the ISSUE 10 satellite-4 list: cache round-trip, corrupt/stale
 fallback, deterministic winner selection with injected timings, bitwise
 parity of tuned vs default blocks on integer data (fwd + grad over
 causal / windowed / masked / GQA-shaped inputs), the trace-count pin
-(resolver lookups never retrace), the explicit-override warning, the
-bench_tune dead-backend kill-test, and the srclint block-literal fence.
+(resolver lookups never retrace), the explicit-override warning, and the
+srclint block-literal fence.
 """
 
 import json
@@ -196,8 +196,8 @@ def test_seeded_golden_matches_banked_artifacts():
 
 
 def test_reseed_reproduces_persisted_sweep_rows(tmp_path):
-    """bench_tune persists measured rows to KERNEL_TUNE_SWEEP.json; a
-    later re-seed must reproduce the measured winners PER SHAPE (not
+    """Measured rows persisted in KERNEL_TUNE_SWEEP.json: a
+    re-seed must reproduce the measured winners PER SHAPE (not
     revert them to older artifacts, not mix shapes into one winner)."""
     rows = [
         # train shape: (1024, h12, d64) — 256x512 wins fwd, bwd row set
@@ -487,58 +487,7 @@ def test_resolve_lm_loss_explicit_vocab_chunk_warns_measured_slower(
         assert "measured-slower" in warn.call_args[0][0]
 
 
-# ---------------------------------------------------------- bench_tune
-
-
-def _load_bench_tune():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_tune", os.path.join(ROOT, "scripts", "bench_tune.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_tune_skips_already_banked_keys(tune_env):
-    """The zero-re-sweep contract: a key banked in the local cache is
-    skipped by the next invocation (the e2e twin runs in the pipeline's
-    cpu-sim mode; this pins the skip predicate itself)."""
-    bt = _load_bench_tune()
-    shape = dict(bt.CPU_SHAPE)
-    key = bt._attn_key(shape, "cpu")
-    assert not bt._already_banked(cache, "flash_fwd", key)
-    _plant(tune_env["local"], [cache.Entry(
-        kind="flash_fwd", key=key,
-        winner={"block_q": 64, "block_k": 64, "block_h": 1},
-        source="test", measured=False)])
-    assert bt._already_banked(cache, "flash_fwd", key)
-    # nearest-match fuzziness must NOT make the skip fuzzy
-    other = dict(key, seq=key["seq"] * 2)
-    assert not bt._already_banked(cache, "flash_fwd", other)
-
-
-def test_bench_tune_rc0_one_json_line_on_dead_backend(
-        cpu_sim_subprocess_env, tmp_path):
-    """Kill-test: no backend -> rc 0, ONE parseable JSON line last, and
-    the artifact-derived selection still refreshed the golden (this
-    script keeps its exit-0 contract until the benchmark PR turns it
-    into cells — ROADMAP C1)."""
-    env = dict(cpu_sim_subprocess_env)
-    env["JAX_PLATFORMS"] = "no_such_platform"
-    env["DTF_TUNE_BUDGET_S"] = "240"
-    env["DTF_KERNEL_TUNE_PATH"] = str(tmp_path / "local.json")
-    env["DTF_KERNEL_TUNE_GOLDEN"] = str(tmp_path / "golden.json")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "scripts", "bench_tune.py")],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-        text=True, timeout=60)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    last = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert "backend unavailable" in last["probe"]
-    assert last["banked_golden"] > 0
-    banked = cache.load_file(str(tmp_path / "golden.json"))
-    assert any(e.kind == "lm_loss" and e.measured for e in banked)
+# ------------------------------------------------- cache writes, no jax
 
 
 def test_merge_entries_invalidates_resolver_plans(tune_env):
@@ -556,10 +505,10 @@ def test_merge_entries_invalidates_resolver_plans(tune_env):
 
 
 def test_tune_package_resolves_without_jax(cpu_sim_subprocess_env):
-    """The jax-free-at-module-level invariant is load-bearing:
-    bench_tune's parent imports dtf_tpu.tune and then starts children
-    that need the chip, so it must stay off jax itself. Poison jax and
-    prove import + a full resolve still work."""
+    """The jax-free-at-module-level invariant is load-bearing: a parent
+    that imports dtf_tpu.tune and then starts children that need the
+    chip must stay off jax itself. Poison jax and prove import + a full
+    resolve still work."""
     code = (
         "import builtins\n"
         "real = builtins.__import__\n"
