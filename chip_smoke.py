@@ -69,6 +69,9 @@ MEDIUM = {
         "ce_tokens": 2048, "ce_d_model": [768, 1024], "vocab": 50304,
         # a Wide&Deep table and one batch of lookups
         "gather_rows": 1_000_000, "gather_dim": 64, "gather_ids": [4096, 26],
+        # slot-decode attention [slots, kv_heads, group, head_dim, max_len]:
+        # the two serve cells' heads
+        "decode_attn": [[8, 16, 1, 64, 1024], [8, 8, 4, 64, 2048]],
     },
     "fence": {"n": 4096, "reps": 64},
 }
@@ -336,6 +339,7 @@ def check_kernels(spec):
 
     from dtf_tpu.models import gpt
     from dtf_tpu.ops import attention as att
+    from dtf_tpu.ops import decode_attention as da
     from dtf_tpu.ops import embed_gather as eg
     from dtf_tpu.ops import flash_attention as fa
     from dtf_tpu.ops.fused_ce import pallas_lm_cross_entropy
@@ -445,6 +449,37 @@ def check_kernels(spec):
     (_, o_t), g_t = lookup_loss(lambda tb: jnp.take(tb, ids, axis=0))(table)
     record("embed_gather_fwd", o_g, o_t, 0.0)
     record("embed_gather_bwd_scatter_add", g_g, g_t, 1e-6)
+
+    # slot-decode attention: the kernel against the select write + a dense
+    # softmax over the positions up to each slot's index; an inactive slot
+    # (the last) rides untouched. The leaves must agree exactly.
+    for slots, heads, group, d, max_len in k["decode_attn"]:
+        cut = lambda i, *shape: jax.random.normal(  # noqa: E731
+            keys[i], shape, jnp.bfloat16)
+        q, k_new, v_new = (cut(0, slots, heads, group, d),
+                           cut(1, slots, heads, d), cut(2, slots, heads, d))
+        ck, cv = (cut(3, slots, heads, max_len, d),
+                  cut(4, slots, heads, max_len, d))
+        idx = (jnp.arange(slots, dtype=jnp.int32) * 131) % max_len
+        active = jnp.arange(slots) < slots - 1
+        hit = (jnp.arange(max_len)[None] == idx[:, None]) & active[:, None]
+        hit = hit[:, None, :, None]
+        want_k = jnp.where(hit, k_new[:, :, None], ck)
+        want_v = jnp.where(hit, v_new[:, :, None], cv)
+        bias = jnp.where(jnp.arange(max_len)[None] <= idx[:, None], 0.0,
+                         -jnp.inf)[:, None, None]
+        with jax.default_matmul_precision("highest"):
+            p = jax.nn.softmax(jnp.einsum(
+                "bkgd,bkld->bkgl", q.astype(jnp.float32),
+                want_k.astype(jnp.float32)) * d ** -0.5 + bias, axis=-1)
+            want = jnp.einsum("bkgl,bkld->bkgd", p,
+                              want_v.astype(jnp.float32))
+        out, got_k, got_v = jax.jit(da.decode_attention)(
+            q, k_new, v_new, ck, cv, idx, active)
+        tag = f"decode_attn_h{heads}g{group}_l{max_len}"
+        record(f"{tag}_out", out[:-1], want[:-1], 2e-2)
+        record(f"{tag}_key_leaf", got_k, want_k, 0.0)
+        record(f"{tag}_value_leaf", got_v, want_v, 0.0)
 
     # chunked prefill == one-shot prefill, compiled: a windowed GQA stack,
     # so the rolling cache wraps mid-prompt

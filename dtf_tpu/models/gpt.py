@@ -45,6 +45,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from dtf_tpu.core import comms
 from dtf_tpu.core.train import LossAux
 from dtf_tpu.ops import attention as att
+from dtf_tpu.ops import decode_attention
 from dtf_tpu.ops import flash_attention as fa
 from dtf_tpu.ops.losses import softmax_cross_entropy
 from dtf_tpu.parallel import moe as moe_lib
@@ -702,6 +703,23 @@ class CausalSelfAttention(nn.Module):
             idx_b = idx if cfg.slot_decode else idx[None]        # [B] or [1]
             q = rope(q, idx_b[:, None], cfg.rope_theta)
             k = rope(k, idx_b[:, None], cfg.rope_theta)
+            if (is_initialized and cfg.slot_decode
+                    and decode_attention.engages(
+                        cache_dtype=ck.value.dtype, d_head=d_head,
+                        max_len=cache_len, window=self.window,
+                        mesh=self.mesh)):
+                # ONE kernel a layer (ops/decode_attention.py): each active
+                # slot's new K/V row written where it lies in the donated
+                # leaf, its live positions read once. Same contract as the
+                # branch below: an inactive row writes nothing and does not
+                # advance, validity follows from the index alone.
+                active = (jnp.ones((b,), bool) if decode_active is None
+                          else decode_active)
+                out, ck.value, cv.value = decode_attention.decode_attention(
+                    q.reshape(b, kv_heads, group, d_head), k[:, :, 0, :],
+                    v[:, :, 0, :], ck.value, cv.value, idx, active)
+                ci.value = idx + active.astype(jnp.int32)
+                return out_dense()(out.reshape(b, 1, cfg.d_model))
             if is_initialized:
                 slot = jax.lax.rem(idx, jnp.int32(cache_len))
                 if cfg.slot_decode:

@@ -246,15 +246,21 @@ def _pick_rows(subs, logits, temp, top_k, top_p, live):
     return jax.lax.switch(path, (greedy, unfiltered, filtered)), path
 
 
+#: What every decode program returns beside ``token`` and ``done``, still
+#: on the device until somebody asks (``DecodeEngine.take_samples``): the
+#: sampler's path, the valid cache positions summed over the active slots
+#: (what a step must read), and how many slots were active.
+_STEP_OUT_NAMES = ("sampler_path", "cache_positions", "active_slots")
+
+
 def _moe_out_names(cfg: gpt.GPTConfig) -> tuple:
     """What the decode program of a model with routed experts returns
-    beside ``token`` and ``done``, in the same transfer: the (token,
-    expert) pairs an expert layer computed, per expert layer the experts
-    that got a token and the fullest expert's tokens, and the valid cache
-    positions of the active slots (what a step must read)."""
+    besides, in the tokens' transfer: the (token, expert) pairs an expert
+    layer computed, and per expert layer the experts that got a token and
+    the fullest expert's tokens."""
     if cfg.experts is None:
         return ()
-    return ("moe_picks", "moe_touched", "moe_max_load", "cache_positions")
+    return ("moe_picks", "moe_touched", "moe_max_load")
 
 
 def _layers_in_order(tree: dict) -> list:
@@ -298,16 +304,16 @@ def _build_decode_fn(model: gpt.GPT):
             "tok": jnp.where(active, nxt, state["tok"]),
             "done": jnp.where(active, done, state["done"]),
         }
-        out = {"token": nxt, "done": done, "sampler_path": path}
+        out = {"token": nxt, "done": done, "sampler_path": path,
+               "cache_positions": jnp.sum(jnp.where(
+                   active, gpt.cache_index_of(state["cache"]), 0)),
+               "active_slots": jnp.sum(active, dtype=jnp.int32)}
         if with_moe:
             layers = _layers_in_order(mut["moe_stats"])
             for key in ("touched", "max_load"):
                 out[f"moe_{key}"] = jnp.stack(
                     [layer["experts"][key][0] for layer in layers])
-            out["moe_picks"] = (jnp.sum(active, dtype=jnp.int32)
-                                * model.cfg.experts.top_k)
-            out["cache_positions"] = jnp.sum(jnp.where(
-                active, gpt.cache_index_of(state["cache"]), 0))
+            out["moe_picks"] = out["active_slots"] * model.cfg.experts.top_k
         return new_state, out
 
     return decode_fn
@@ -717,14 +723,16 @@ class DecodeEngine:
         #: SpanRecorder as ``serve_moe_<name>`` (docs/OBSERVABILITY.md
         #: section 7) and ``counters`` keeps their sums.
         self.moe_samples: Optional[dict] = None
-        #: the path the last decode step's sampler took (an index into
-        #: ``_SAMPLER_PATHS``), still on the device: :meth:`take_samples`
-        #: reads and counts it, so a step nobody observes transfers nothing.
-        #: Once somebody has asked, a step starts the scalar's copy to the
-        #: host beside its tokens', and the asker does not wait for a
-        #: transfer of its own (~0.5 ms a tick on a v5e's host).
-        self._sampler_path = None
-        self._sampler_watched = False
+        #: what the last decode step left beside its tokens (the scalars of
+        #: ``_STEP_OUT_NAMES``: the sampler's path as an index into
+        #: ``_SAMPLER_PATHS``, the live cache positions, the active slots),
+        #: still on the device: :meth:`take_samples` reads and counts them,
+        #: so a step nobody observes transfers nothing. Once somebody has
+        #: asked, a step starts the scalars' copy to the host beside its
+        #: tokens', and the asker does not wait for a transfer of its own
+        #: (~0.5 ms a tick on a v5e's host).
+        self._step_out = None
+        self._step_watched = False
         self.counters.update(
             {f"sampler_steps_{name}": 0 for name in _SAMPLER_PATHS})
         if base.experts is not None:
@@ -1109,12 +1117,14 @@ class DecodeEngine:
                 return np.asarray(out["token"]), np.asarray(out["done"])
 
     def _note_step(self, out) -> None:
-        """A dispatched decode (or verify) step: counted, and its sampler
-        path kept for :meth:`take_samples`."""
+        """A dispatched decode (or verify) step: counted, and what it left
+        beside its tokens kept for :meth:`take_samples`."""
         self.counters["decode_steps"] += 1
-        self._sampler_path = out["sampler_path"]
-        if self._sampler_watched:
-            self._sampler_path.copy_to_host_async()
+        self._step_out = {name: out[name] for name in _STEP_OUT_NAMES
+                          if name in out}
+        if self._step_watched:
+            for scalar in self._step_out.values():
+                scalar.copy_to_host_async()
 
     def _note_moe(self, out) -> None:
         """A routed-expert model's decode step, from the readback decode
@@ -1142,20 +1152,30 @@ class DecodeEngine:
         """What the last engine call left for a telemetry object, by span
         name less its ``serve_`` (docs/OBSERVABILITY.md section 7), taken
         once: ``moe_samples``, and after a decode step ``sampler_greedy``
-        (1.0 where no live slot sampled, else 0.0). The sampler's path is
-        read from the device HERE, into ``counters["sampler_steps_*"]``
-        too: the scheduler asks only where a telemetry object is attached,
-        so a served step pays no transfer for it (and from the second
-        asking on, the copy started with the step: ``_note_step``)."""
+        (1.0 where no live slot sampled, else 0.0) and
+        ``decode_attn_live_pct`` (the positions the step's attention has to
+        read, each active slot's cached ones and its new one, as a share
+        of active slots x ``max_len``: what ``ops/decode_attention.py``
+        reads where it engages, of what the XLA spelling reads). The
+        step's scalars are read from the device HERE, the sampler's path
+        into ``counters["sampler_steps_*"]`` too: the scheduler asks only
+        where a telemetry object is attached, so a served step pays no
+        transfer for them (and from the second asking on, the copy started
+        with the step: ``_note_step``)."""
         samples = {f"moe_{name}": value
                    for name, value in (self.moe_samples or {}).items()}
         self.moe_samples = None
-        path, self._sampler_path = self._sampler_path, None
-        if path is not None:
-            self._sampler_watched = True
-            path = int(path)
+        step, self._step_out = self._step_out, None
+        if step is not None:
+            self._step_watched = True
+            path = int(step["sampler_path"])
             self.counters[f"sampler_steps_{_SAMPLER_PATHS[path]}"] += 1
             samples["sampler_greedy"] = float(path == 0)
+            active = int(step.get("active_slots", 0))
+            if active:
+                samples["decode_attn_live_pct"] = (
+                    100.0 * (int(step["cache_positions"]) + active)
+                    / (active * self.max_len))
         return samples
 
     def draft_propose(self):
@@ -1539,7 +1559,7 @@ def program_table(cfg: gpt.GPTConfig, *, n_slots: int, max_len: int,
         decode_kw = dict(jit_kw)
         if mesh is not None:
             decode_kw["out_shardings"] = (state_sh, {
-                name: rep for name in ("token", "done", "sampler_path")
+                name: rep for name in ("token", "done") + _STEP_OUT_NAMES
                 + _moe_out_names(cfg)})
         executor.program(
             "decode", _build_decode_fn(models["decode"]),

@@ -207,12 +207,15 @@ def test_kernels_carry_their_names(on_chip, case):
 SERVE = dict(n_slots=32, max_len=1024, prefill_chunk=128)   # the serve cell
 
 
-def _serve_program(on_chip, name, heads):
-    """``program_table``'s ``name`` at the serve cell's widths and slots, cut
-    to 2 layers and a 1024-token vocabulary (neither touches how a cache leaf
-    is written; the full vocabulary's sort alone compiles for 20 s), with
-    every operand on one described chip. Returns ``(compiled, state)``."""
-    cfg = gpt.GPTConfig(d_model=1024, layers=2, heads=heads, d_ff=4096,
+def _gpt_serve_table(on_chip, monkeypatch, heads, layers=2):
+    """``program_table`` at the serve cell's widths and slots with a
+    1024-token vocabulary (the full vocabulary's sort alone compiles for 20
+    s), every operand on one described chip. The model asks
+    ``jax.default_backend()`` whether its decode step runs the Pallas
+    kernel; the answer is the CPU's here, so the test gives the chip's.
+    Returns ``(programs, place, state)``."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = gpt.GPTConfig(d_model=1024, layers=layers, heads=heads, d_ff=4096,
                         vocab_size=1024)
     place = lambda tree: jax.tree.map(  # noqa: E731
         lambda s: on_chip(s.shape, s.dtype), tree)
@@ -223,14 +226,22 @@ def _serve_program(on_chip, name, heads):
         jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32)))["params"])
     programs, _ = serve_engine.program_table(
         cfg, **SERVE, abs_trees={"params": params, "state": state})
+    return programs, place, state
+
+
+def _serve_program(on_chip, monkeypatch, name, heads):
+    """The table's ``name``, cut to 2 layers (depth does not touch how a
+    cache leaf is written). Returns ``(compiled, state)``."""
+    programs, place, state = _gpt_serve_table(on_chip, monkeypatch, heads)
     prog = programs[name]
     return prog.lower(*place(prog.abstract_args)).compile(), state
 
 
 @pytest.mark.parametrize("d_head", [64, 128])
 @pytest.mark.parametrize("name", ["decode", "prefill"])
-def test_serve_programs_update_the_cache_in_place(on_chip, compiled_once,
-                                                  name, d_head):
+def test_serve_programs_update_the_cache_in_place(on_chip, monkeypatch,
+                                                  compiled_once, name,
+                                                  d_head):
     """PR 25's fence. Before it ``jit_decode_fn`` relayouted every cache leaf
     twice a step around a scatter (the compiler keeps a leaf with position
     as the minor dimension and scatters only with position major) and
@@ -241,10 +252,14 @@ def test_serve_programs_update_the_cache_in_place(on_chip, compiled_once,
     (``copy-start``/``copy-done`` pairs are not counted: the compiler's
     prefetch of a leaf into another memory space, the same bytes the
     in-place pass reads and writes.) d_head 128 is fenced beside 64 so
-    neither layout pays for the other."""
+    neither layout pays for the other. Since PR 30 the d_head 64 decode
+    program is the one with the ``dtf_decode_attn`` kernel in it (the leaf
+    goes through it as it lies, a bitcast either side); d_head 128 keeps
+    the select write."""
     heads = 1024 // d_head
     compiled, state = compiled_once(
-        ("gpt", name, heads), lambda: _serve_program(on_chip, name, heads))
+        ("gpt", name, heads),
+        lambda: _serve_program(on_chip, monkeypatch, name, heads))
     leaf = (f"[{SERVE['n_slots']},{heads},{SERVE['max_len']},{d_head}]")
     whole_leaf = re.findall(
         rf"^\s*(?:ROOT )?%\S+ = bf16{re.escape(leaf)}\S* "
@@ -324,6 +339,50 @@ def test_lfm2_serve_programs_update_both_kinds_of_state_in_place(
     assert len(kernels) == 3, kernels
 
 
+# ---- slot-decode attention: one kernel an attention layer, lowered once ----
+
+DECODE_KERNEL = r"^\s*%\w*dtf_decode_attn\w*(?:\.\d+)? = .*tpu_custom_call"
+
+
+@pytest.mark.parametrize("family,heads,kernels", [
+    ("gpt", 16, 2), ("gpt", 8, 0), ("lfm2", None, 1)])
+def test_decode_programs_hold_the_attention_kernel(
+        on_chip, monkeypatch, compiled_once, family, heads, kernels):
+    """``jit_decode_fn`` at both serve cells' shapes holds
+    ``dtf_decode_attn`` as a ``tpu_custom_call``, one an attention layer
+    (the GPT cut has two, the LFM2 cut one beside its conv layer), under
+    the name the profiler's ``XLA Ops`` events start with. Heads of 128
+    lie the other way round in the leaf (d_head minor): that engine keeps
+    the select write, and the in-place fence above covers it."""
+    if family == "gpt":
+        compiled, _ = compiled_once(
+            ("gpt", "decode", heads),
+            lambda: _serve_program(on_chip, monkeypatch, "decode", heads))
+    else:
+        compiled, _ = compiled_once(
+            ("lfm2", "decode"),
+            lambda: _lfm2_program(on_chip, monkeypatch, "decode"))
+    found = re.findall(DECODE_KERNEL, compiled.as_text(), re.M)
+    assert len(found) == kernels, found
+
+
+def test_full_depth_decode_lowers_the_kernel_once(on_chip, monkeypatch):
+    """The kernel sits behind one module-level ``jax.jit``, so the 24
+    attention layers of GPT-2 medium's decode program share one trace and
+    one Mosaic lowering: the LOWERED text holds the kernel's module once,
+    in one function that the layers call. (Bare ``pallas_call`` sites
+    lower once each: 24 copies of the module, which a server pays for at
+    every start. PERF.md section 6, PR 30.)"""
+    programs, place, _ = _gpt_serve_table(on_chip, monkeypatch, 16,
+                                          layers=24)
+    prog = programs["decode"]
+    text = prog.lower(*place(prog.abstract_args)).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(re.findall(r"func\.func private @_decode_attention\b",
+                          text)) == 1
+    assert len(re.findall(r"call @_decode_attention\b", text)) == 24
+
+
 # ---- the sampler: a step pays for the vocabulary sorts only if it asks -----
 
 def _computations(text: str) -> dict:
@@ -363,7 +422,8 @@ def test_serve_programs_sort_only_inside_a_conditional(
     reappear in what the entry computation executes on every call."""
     if family == "gpt":
         compiled, _ = compiled_once(
-            ("gpt", name, 16), lambda: _serve_program(on_chip, name, 16))
+            ("gpt", name, 16),
+            lambda: _serve_program(on_chip, monkeypatch, name, 16))
     else:
         compiled, _ = compiled_once(
             ("lfm2", name), lambda: _lfm2_program(on_chip, monkeypatch, name))

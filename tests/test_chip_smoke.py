@@ -30,6 +30,7 @@ TINY = {
         "flash": [[1, 2, 64, 16], [1, 2, 50, 16]], "window": 24,
         "ce_tokens": 48, "ce_d_model": [32], "vocab": 128,
         "gather_rows": 200, "gather_dim": 16, "gather_ids": [4, 5],
+        "decode_attn": [[3, 2, 2, 16, 256]],
     },
     "fence": {"n": 64, "reps": 2},
 }

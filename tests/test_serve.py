@@ -528,3 +528,112 @@ def test_sampler_path_counters(params):
     eng, steps = served(ended, Telemetry(watchdog=False))
     assert steps == {"greedy": eng.counters["decode_steps"],
                      "unfiltered": 0, "filtered": 0}
+
+
+# ---- slot-decode attention as one kernel (ops/decode_attention.py) ---------
+
+KERNEL_LEN = 128    # whole 128-position tiles: what the kernel asks of a cache
+
+
+def _on_the_kernel_path(monkeypatch) -> list:
+    """Tell the model's gate it runs on a TPU. The kernel itself still asks
+    the real backend, so it runs in interpret mode. Returns the list the
+    kernel's calls are noted in (one a traced attention layer)."""
+    from dtf_tpu.ops import decode_attention
+
+    calls = []
+    real = decode_attention.decode_attention
+
+    def noted(q, *rest):
+        calls.append(q.shape)
+        return real(q, *rest)
+
+    monkeypatch.setattr(decode_attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(decode_attention, "decode_attention", noted)
+    return calls
+
+
+def test_kernel_engine_emits_the_parents_tokens(params, monkeypatch):
+    """An engine whose decode step runs the kernel and one on the XLA branch
+    emit the same greedy tokens: prompts of one to seven ragged chunks, a
+    long prompt prefilling one chunk a tick while its neighbour decodes (its
+    slot rides the kernel inactive), and more requests than slots, so a
+    slot is used again over an earlier request's rows."""
+    reqs = [dict(prompt=[11, 22, 33], max_new=14),
+            dict(prompt=list(range(1, 20)), max_new=10),
+            dict(prompt=list(range(40, 49)), max_new=12),
+            dict(prompt=[5], max_new=6)]
+
+    def served():
+        eng = DecodeEngine(CFG, params, n_slots=2, max_len=KERNEL_LEN,
+                           prefill_chunk=3)
+        sched = Scheduler(eng, None, prefill_chunks_per_tick=1)
+        rids = [sched.submit(Request(**reqs[0]))]
+        sched.tick()                          # the first runs, then the rest
+        rids += [sched.submit(Request(**r)) for r in reqs[1:]]
+        sched.run_until_idle()
+        assert eng.trace_counts == {"prefill": 1, "decode": 1}
+        return [sched.poll(rid)["tokens"] for rid in rids]
+
+    want = served()
+    calls = _on_the_kernel_path(monkeypatch)
+    got = served()
+    assert calls == [(2, CFG.heads, 1, CFG.d_model // CFG.heads)] * CFG.layers
+    assert got == want
+    assert [len(t) for t in got] == [r["max_new"] for r in reqs]
+
+
+@pytest.mark.parametrize("case,calls", [
+    ("plain", CFG.layers), ("int8", 0), ("window", 0), ("verify", 0),
+    ("mesh", 0)])
+def test_which_decode_programs_reach_the_kernel(monkeypatch, case, calls):
+    """On a TPU the plain engine's decode program calls the kernel once an
+    attention layer; an int8 cache, a rolling window (its cache whole tiles
+    too: the window alone refuses), the speculative verify step (t > 1) and
+    a mesh over several devices keep the parent's code."""
+    from dtf_tpu.core.mesh import MeshConfig, make_mesh
+    from dtf_tpu.serve.engine import program_table
+
+    seen = _on_the_kernel_path(monkeypatch)
+    cfg, kw = CFG, {}
+    if case == "int8":
+        cfg = dataclasses.replace(CFG, kv_cache_dtype="int8")
+    elif case == "window":
+        cfg = dataclasses.replace(CFG, attn_window=KERNEL_LEN)
+    elif case == "verify":
+        kw = dict(spec_k=2, draft_cfg=CFG)
+    elif case == "mesh":
+        kw = dict(mesh=make_mesh(MeshConfig(data=2, model=2),
+                                 devices=jax.devices()[:4]))
+    programs, _ = program_table(cfg, n_slots=4, max_len=2 * KERNEL_LEN,
+                                prefill_chunk=4, **kw)
+    prog = programs["decode"]
+    prog.lower(*prog.abstract_args)
+    assert len(seen) == calls, seen
+
+
+def test_decode_attn_live_pct_counter(params):
+    """Every decode program returns the live cache positions of its active
+    slots; the engine files their share of active slots x ``max_len`` where
+    a telemetry object asks, one sample a decode step, and reads nothing
+    where none does."""
+    from dtf_tpu.telemetry import Telemetry
+
+    def served(telemetry):
+        eng = DecodeEngine(CFG, params, n_slots=2, max_len=MAX_LEN,
+                           prefill_chunk=4)
+        sched = Scheduler(eng, telemetry=telemetry)
+        sched.submit(Request(prompt=[1, 2, 3], max_new=6))
+        sched.run_until_idle()
+        return eng
+
+    tel = Telemetry(watchdog=False)
+    eng = served(tel)
+    roll = tel.spans.rollup()["serve_decode_attn_live_pct"]
+    steps = eng.counters["decode_steps"]
+    assert roll["count"] == steps == 5
+    # one active slot; step i reads its 3 + i cached positions and its own
+    reads = [3 + i + 1 for i in range(steps)]
+    assert roll["total_s"] == pytest.approx(
+        sum(100.0 * r / MAX_LEN for r in reads), rel=1e-5)
+    assert served(None)._step_out is not None      # left on the device
