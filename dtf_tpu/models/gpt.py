@@ -35,6 +35,7 @@ ROADMAP.md says what training them still lacks.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 import jax
@@ -49,6 +50,83 @@ from dtf_tpu.ops import decode_attention
 from dtf_tpu.ops import flash_attention as fa
 from dtf_tpu.ops.losses import softmax_cross_entropy
 from dtf_tpu.parallel import moe as moe_lib
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttentionConfig:
+    """Multi-head latent attention as today's large sparse decoders publish
+    it (``q_lora_rank`` / ``kv_lora_rank`` / ``qk_nope_head_dim`` /
+    ``qk_rope_head_dim`` / ``v_head_dim`` and the ``rope_scaling`` block):
+    a low-rank query path, keys and values expanded from ONE latent row a
+    token, a decoupled rotary key part shared by all heads, YaRN rotary
+    frequencies and YaRN's softmax scale. :class:`LatentAttention` has the
+    equations."""
+
+    q_rank: int = 1536
+    kv_rank: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+    #: YaRN: 1 = plain rotary frequencies and the plain softmax scale
+    yarn_factor: float = 1.0
+    yarn_original_len: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+
+    def __post_init__(self):
+        if self.rope_dim % 2 or min(self.q_rank, self.kv_rank, self.nope_dim,
+                                    self.rope_dim, self.v_dim) < 1:
+            raise ValueError(
+                f"{self}: every width must be >= 1 and rope_dim even")
+        if self.yarn_factor < 1:
+            raise ValueError(f"yarn_factor={self.yarn_factor} must be >= 1")
+        if self.yarn_factor > 1 and (self.yarn_mscale
+                                     != self.yarn_mscale_all_dim):
+            raise ValueError(
+                f"yarn_mscale={self.yarn_mscale} != yarn_mscale_all_dim="
+                f"{self.yarn_mscale_all_dim}: YaRN then scales cos and sin "
+                "by m(mscale) / m(mscale_all_dim) != 1, which the rotary "
+                "embedding here does not do (no configuration has needed it)")
+
+    @property
+    def latent_width(self) -> int:
+        """Numbers a cached position holds: the normalised latent row and
+        the rotated shared key part."""
+        return self.kv_rank + self.rope_dim
+
+    def _m(self, mscale: float) -> float:
+        if self.yarn_factor <= 1:
+            return 1.0
+        return 0.1 * mscale * math.log(self.yarn_factor) + 1.0
+
+    @property
+    def softmax_scale(self) -> float:
+        """``(nope + rope width)^(-1/2) m(mscale_all_dim)^2``."""
+        return ((self.nope_dim + self.rope_dim) ** -0.5
+                * self._m(self.yarn_mscale_all_dim) ** 2)
+
+    def frequencies(self, theta: float) -> jax.Array:
+        """[rope_dim / 2] float32: the angle pair ``d`` turns by a position.
+        YaRN leaves the pairs that turn more than ``beta_fast`` times over
+        the original length alone, divides those that turn less than
+        ``beta_slow`` times by ``factor``, and ramps between."""
+        width = self.rope_dim
+        d = jnp.arange(0, width, 2, dtype=jnp.float32)
+        plain = theta ** (-d / width)
+        if self.yarn_factor <= 1:
+            return plain
+
+        def pair_turning(rotations: float) -> float:
+            return (width * math.log(self.yarn_original_len
+                                     / (rotations * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        low = max(math.floor(pair_turning(self.yarn_beta_fast)), 0)
+        high = min(math.ceil(pair_turning(self.yarn_beta_slow)), width - 1)
+        ramp = jnp.clip((d / 2 - low) / max(high - low, 0.001), 0.0, 1.0)
+        return plain * (1 - ramp) + plain / self.yarn_factor * ramp
 
 
 @dataclasses.dataclass(frozen=True)
@@ -155,9 +233,12 @@ class GPTConfig:
     use_bias: bool = True
     #: the head is the token embedding, transposed (no ``lm_head`` leaf).
     tie_head: bool = False
-    #: per-layer operator, ``"attn"`` or ``"conv"`` (the gated short
-    #: convolution); () = attention in every layer.
+    #: per-layer operator: ``"attn"``, ``"conv"`` (the gated short
+    #: convolution) or ``"mla"`` (latent attention, ``latent``); () =
+    #: attention in every layer.
     layer_kinds: tuple = ()
+    #: the widths of the ``"mla"`` layers (:class:`LatentAttention`)
+    latent: Optional[LatentAttentionConfig] = None
     #: taps of the short convolution, and columns of its decode state.
     conv_kernel: int = 3
     #: dropless routed experts (parallel/moe.py DroplessMoE) as the FFN of
@@ -165,6 +246,11 @@ class GPTConfig:
     #: dense FFN of width ``d_ff``. None = no such layer.
     experts: Optional[moe_lib.ExpertsConfig] = None
     dense_layers: int = 0
+    #: width of the SHARED expert: one SwiGLU every token meets, added to
+    #: the routed experts' output in every layer that has them (0 = none).
+    #: A field of the block: it is not routed to, and every chip of an
+    #: expert-parallel deployment computes it alike.
+    shared_expert_ff: int = 0
     #: storage dtype of the matrices (embedding, projections, experts);
     #: norm weights, the router and its bias stay float32.
     param_dtype: jnp.dtype = jnp.float32
@@ -177,10 +263,25 @@ class GPTConfig:
             raise ValueError(f"ffn={self.ffn!r} must be gelu or swiglu")
         if self.layer_kinds and (
                 len(self.layer_kinds) != self.layers
-                or set(self.layer_kinds) - {"attn", "conv"}):
+                or set(self.layer_kinds) - {"attn", "conv", "mla"}):
             raise ValueError(
-                f"layer_kinds={self.layer_kinds} must name 'attn' or "
-                f"'conv' for each of the {self.layers} layers")
+                f"layer_kinds={self.layer_kinds} must name 'attn', 'conv' "
+                f"or 'mla' for each of the {self.layers} layers")
+        if ("mla" in self.layer_kinds) != (self.latent is not None):
+            raise ValueError(
+                "layer_kinds names 'mla' layers exactly when latent gives "
+                "their widths")
+        if self.latent is not None and (self.attn_window
+                                        or self.kv_cache_dtype):
+            raise ValueError(
+                "a latent cache has no rolling window and no int8 form "
+                "(attn_window / kv_cache_dtype): its one row a position "
+                "carries no per-head scale")
+        if self.shared_expert_ff < 0 or (self.shared_expert_ff
+                                         and self.experts is None):
+            raise ValueError(
+                f"shared_expert_ff={self.shared_expert_ff} must be >= 0 and "
+                "needs routed experts beside it")
         if self.conv_kernel < 2:
             raise ValueError(f"conv_kernel={self.conv_kernel} must be >= 2")
         if self.experts is not None and self.moe_every:
@@ -244,6 +345,14 @@ class GPTConfig:
         layer): the serving features that index the cache by POSITION
         (prefix pages, speculative rollback) do not apply to it."""
         return "conv" in self.layer_kinds
+
+    @property
+    def has_latent_cache(self) -> bool:
+        """True when some layer caches latent rows (an ``"mla"`` layer): one
+        ``[rows, width, positions]`` leaf without a head axis, which the
+        prefix page cache, the speculative verify step and the int8 cache
+        do not serve yet (docs/SERVING.md)."""
+        return "mla" in self.layer_kinds
 
     def layer_has_experts(self, layer: int) -> bool:
         return self.experts is not None and layer >= self.dense_layers
@@ -316,14 +425,17 @@ tp_rules = [
 ] + moe_lib.ep_rules()
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def rope(x: jax.Array, positions: jax.Array, theta: float, *,
+         freqs: Optional[jax.Array] = None) -> jax.Array:
     """Rotary embedding. x [B,H,T,D] (D even), positions [T] global indices —
     correct under seq sharding because positions are global, not local.
     ``positions`` may also be PER-ROW [B,T] (the ``slot_decode`` step, where
     every serving slot sits at its own position); the angles then broadcast
-    over heads only."""
+    over heads only. ``freqs`` [D/2] replaces the plain ``theta**(-2i/D)``
+    (YaRN: :meth:`LatentAttentionConfig.frequencies`)."""
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angles = positions[..., None].astype(jnp.float32) * freqs    # [...,T,D/2]
     if angles.ndim == 3:                   # [B,T,D/2] → broadcast over heads
         angles = angles[:, None]
@@ -868,6 +980,247 @@ class CausalSelfAttention(nn.Module):
         return nn.Dropout(cfg.dropout)(out, deterministic=deterministic)
 
 
+class _Kernel(nn.Module):
+    """A bias-free projection's ``kernel`` for a caller that multiplies it
+    in more than one form (the same leaf name ``nn.Dense`` gives)."""
+
+    shape: tuple
+    param_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self):
+        return self.param("kernel", nn.initializers.lecun_normal(),
+                          self.shape, self.param_dtype)
+
+
+def _divisor_at_most(n: int, limit: int) -> int:
+    d = min(n, limit)
+    while n % d:
+        d -= 1
+    return d
+
+
+#: cached positions a chunk's queries meet in one pass of the prefill loop
+_LATENT_PREFILL_BLOCK = 1024
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (:class:`LatentAttentionConfig`), no bias:
+
+    ``c_q = RMSNorm(W_qa h)``; ``q = W_qb c_q`` -> heads x (nope + rope
+    widths); ``[c_kv ; k_r] = W_kva h``; ``c_kv <- RMSNorm(c_kv)``; ``k_r``
+    is rotated and is ONE key part shared by all heads; ``[k_i ; v_i] =
+    W_kvb,i c_kv``; ``score_ij = (q_i^nope . k_i,j + q_i^rope . k_r,j)
+    sigma``; causal softmax; ``out = W_o concat_i sum_j p_ij v_i,j``.
+
+    **The cache** (``decode_len > 0``) is ONE leaf, ``cached_latent`` [rows,
+    kv_rank + rope_dim, positions]: per position the normalised ``c_kv`` and
+    the rotated ``k_r`` (576 numbers at the published widths), never
+    per-head K/V, and no head axis. Positions are the MINOR axis: the lanes
+    of the TPU, the layout the decode kernel reads as it lies and a chunk
+    writes as one slab. Validity follows from ``cache_index`` alone, as for
+    K/V: a stale row is never read, so nothing is cleared.
+
+    **Two forms of one attention.** A multi-token apply (prefill, one-shot
+    or continuing an advanced cache) EXPANDS: the chunk's own keys and
+    values come from its fresh ``c_kv``, and the cached rows it attends to
+    are expanded through ``W_kvb`` a block of positions at a time under a
+    running softmax, up to the index and no further. At 512 queries a chunk
+    that is half the arithmetic of the absorbed form (scores over 192 and
+    values over 128 numbers a head and position plus one expansion a
+    position, against 576 and 512). The single-token step ABSORBS:
+    ``q~_i = W_kvb,i^K^T q_i^nope``, ``score_ij = (q~_i . c_kv,j + q_i^rope
+    . k_r,j) sigma``, ``o_i = W_kvb,i^V (sum_j p_ij c_kv,j)`` — the live
+    latent rows are read once and nothing is expanded
+    (``ops/decode_attention.py: latent_decode_attention`` where it engages,
+    the same arithmetic in XLA over all positions elsewhere).
+    ``prefill_len`` and ``decode_active`` as :class:`CausalSelfAttention`:
+    pad columns of a ragged chunk are never written, an inactive row of the
+    slot step writes nothing and does not advance."""
+
+    cfg: GPTConfig
+    mesh: Optional[Mesh]
+
+    @nn.compact
+    def __call__(self, x, deterministic: bool, prefill_len=None,
+                 decode_active=None):
+        cfg, la = self.cfg, self.cfg.latent
+        b, t, _ = x.shape
+        heads, rank = cfg.heads, la.kv_rank
+        nope, rot, v_dim = la.nope_dim, la.rope_dim, la.v_dim
+        width = la.latent_width
+        if cfg.slot_decode and t != 1:
+            raise ValueError(
+                "the slot VERIFY step is not written for a latent cache: "
+                "speculative decoding does not serve a model with latent "
+                "attention")
+        dense = lambda name, n: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            name=name)
+        norm = lambda name: nn.RMSNorm(  # noqa: E731
+            epsilon=cfg.norm_eps, dtype=jnp.float32, param_dtype=jnp.float32,
+            name=name)
+        f32 = jnp.float32
+
+        c_q = norm("q_a_norm")(dense("q_a", la.q_rank)(x)).astype(cfg.dtype)
+        q = dense("q_b", heads * (nope + rot))(c_q).reshape(
+            b, t, heads, nope + rot).transpose(0, 2, 1, 3)       # [B,H,t,.]
+        kv = dense("kv_a", width)(x)                             # [B,t,W]
+        c_kv = norm("kv_a_norm")(kv[..., :rank]).astype(cfg.dtype)
+        w_kvb = _Kernel((rank, heads * (nope + v_dim)), cfg.param_dtype,
+                        name="kv_b")().astype(cfg.dtype).reshape(
+                            rank, heads, nope + v_dim)
+        w_k, w_v = w_kvb[..., :nope], w_kvb[..., nope:]          # [c,H,d]
+        sigma = la.softmax_scale
+        turn = lambda a, pos: rope(  # noqa: E731
+            a, pos, cfg.rope_theta, freqs=la.frequencies(cfg.rope_theta))
+
+        def project_out(out):                                    # [B,H,t,v]
+            out = out.astype(cfg.dtype).transpose(0, 2, 1, 3).reshape(
+                b, t, heads * v_dim)
+            return dense("attn_out", cfg.d_model)(out)
+
+        def expanded(c):                  # [B,n,rank] -> k^nope, v [B,H,n,.]
+            kv_i = jnp.einsum("bnc,chd->bhnd", c, w_kvb,
+                              preferred_element_type=f32).astype(cfg.dtype)
+            return kv_i[..., :nope], kv_i[..., nope:]
+
+        def fold(carry, q_nope, q_rope, c, k_rope, visible):
+            """One block of keys into the running softmax ``(m, l, acc)`` of
+            the queries: keys and values expanded from the block's latent
+            rows ``c`` [B,n,rank] and its rotated parts ``k_rope`` [B,n,R];
+            ``visible`` [t|1, n] bool."""
+            m, l, acc = carry
+            k_nope, v = expanded(c)
+            s = (jnp.einsum("bhqd,bhkd->bhqk", q_nope, k_nope,
+                            preferred_element_type=f32)
+                 + jnp.einsum("bhqr,bkr->bhqk", q_rope, k_rope,
+                              preferred_element_type=f32)) * sigma
+            s = jnp.where(visible, s, -jnp.inf)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            acc = alpha[..., None] * acc + jnp.einsum(
+                "bhqk,bhkd->bhqd", p.astype(cfg.dtype), v,
+                preferred_element_type=f32)
+            return m_new, alpha * l + jnp.sum(p, axis=-1), acc
+
+        def own_attention(q_nope, q_rope, k_rope):
+            """The tokens of this apply among themselves, causal: the
+            running softmax's first block (every query sees itself, so its
+            maximum is finite from here on)."""
+            at = jnp.arange(t)
+            empty = (jnp.full((b, heads, t), -jnp.inf, f32),
+                     jnp.zeros((b, heads, t), f32),
+                     jnp.zeros((b, heads, t, v_dim), f32))
+            return fold(empty, q_nope, q_rope, c_kv, k_rope,
+                        at[None, :] <= at[:, None])
+
+        if cfg.decode_len <= 0:
+            pos = jnp.arange(t)
+            _, l, acc = own_attention(
+                q[..., :nope], turn(q[..., nope:], pos),
+                turn(kv[:, None, :, rank:], pos)[:, 0])
+            out = project_out(acc / l[..., None])
+            return nn.Dropout(cfg.dropout)(out, deterministic=deterministic)
+
+        ready = self.has_variable("cache", "cached_latent")
+        # NOTE: a new cache variable must also be classified in the
+        # registries below (_LATENT_CACHE_KEYS): beams, the serve engine's
+        # slot slicing and the page cache select leaves by name
+        leaf = self.variable("cache", "cached_latent", jnp.zeros,
+                             (b, width, cfg.decode_len), cfg.dtype)
+        ci = self.variable("cache", "cache_index",
+                           lambda: jnp.zeros((b,) if cfg.slot_decode else (),
+                                             jnp.int32))
+        max_len = cfg.decode_len
+
+        if t != 1:
+            # PREFILL, one-shot or continuing: the expanded form
+            if t > max_len:
+                raise ValueError(
+                    f"a {t}-token apply does not fit the latent cache of "
+                    f"{max_len} positions")
+            start = ci.value if ready else jnp.int32(0)
+            qpos = start + jnp.arange(t)
+            q_nope, q_rope = q[..., :nope], turn(q[..., nope:], qpos)
+            k_rope = turn(kv[:, None, :, rank:], qpos)[:, 0]     # [B,t,R]
+            block = _divisor_at_most(max_len, _LATENT_PREFILL_BLOCK)
+            cached = leaf.value
+
+            def cached_block(j, carry):
+                rows = jnp.swapaxes(jax.lax.dynamic_slice(
+                    cached, (0, 0, j * block), (b, width, block)), 1, 2)
+                return fold(carry, q_nope, q_rope, rows[..., :rank],
+                            rows[..., rank:],
+                            (j * block + jnp.arange(block) < start)[None, :])
+
+            _, l, acc = jax.lax.fori_loop(
+                0, (start + block - 1) // block, cached_block,
+                own_attention(q_nope, q_rope, k_rope))
+            if ready:
+                # the chunk's rows as one slab [B, W, t] at their positions;
+                # a slab that would cross the cache's end is moved back to
+                # fit and its rows moved forward in it, so a position at or
+                # past the end is dropped, never wrapped
+                n_valid = t if prefill_len is None else prefill_len
+                new = jnp.swapaxes(jnp.concatenate(
+                    [c_kv, k_rope.astype(cfg.dtype)], axis=-1), 1, 2)
+                at0 = jnp.minimum(start, max_len - t)
+                shift = start - at0
+                old = jax.lax.dynamic_slice(cached, (0, 0, at0),
+                                            (b, width, t))
+                moved = jax.lax.dynamic_slice(
+                    jnp.concatenate([jnp.zeros_like(new), new], axis=2),
+                    (0, 0, t - shift), (b, width, t))
+                col = jnp.arange(t)
+                keep = (col >= shift) & (col - shift < n_valid)
+                leaf.value = jax.lax.dynamic_update_slice(
+                    cached, jnp.where(keep[None, None, :], moved, old),
+                    (0, 0, at0))
+                ci.value = start + n_valid
+            return project_out(acc / l[..., None])
+
+        # DECODE: one token a row against the latent rows, absorbed
+        idx = ci.value
+        idx_b = idx if cfg.slot_decode else jnp.broadcast_to(idx, (b,))
+        q_abs = jnp.einsum("bhd,chd->bhc", q[:, :, 0, :nope], w_k,
+                           preferred_element_type=f32).astype(cfg.dtype)
+        q_lat = jnp.concatenate(
+            [q_abs, turn(q[..., nope:], idx_b[:, None])[:, :, 0]], axis=-1)
+        new = jnp.concatenate(
+            [c_kv[:, 0], turn(kv[:, None, :, rank:], idx_b[:, None])[
+                :, 0, 0].astype(cfg.dtype)], axis=-1)             # [B,W]
+        active = (jnp.ones((b,), bool) if decode_active is None
+                  else decode_active)
+        if (ready and cfg.slot_decode
+                and decode_attention.latent_engages(
+                    cache_dtype=leaf.value.dtype, width=width, rank=rank,
+                    max_len=max_len, mesh=self.mesh)):
+            o, leaf.value = decode_attention.latent_decode_attention(
+                q_lat, new, leaf.value, idx, active, rank=rank, scale=sigma)
+        else:
+            rows = leaf.value
+            lane = jnp.arange(max_len)
+            if ready:
+                hit = (lane[None, :] == idx_b[:, None]) & active[:, None]
+                rows = jnp.where(hit[:, None, :], new[:, :, None], rows)
+                leaf.value = rows
+            s = jnp.einsum("bhw,bwl->bhl", q_lat, rows,
+                           preferred_element_type=f32) * sigma
+            s = jnp.where(lane[None, None, :] <= idx_b[:, None, None], s,
+                          -jnp.inf)
+            p = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bhl,bcl->bhc", p.astype(cfg.dtype),
+                           rows[:, :rank], preferred_element_type=f32)
+        if ready:
+            ci.value = idx + (active.astype(jnp.int32) if cfg.slot_decode
+                              else 1)
+        out = jnp.einsum("bhc,chd->bhd", o.astype(cfg.dtype), w_v,
+                         preferred_element_type=f32)
+        return project_out(out[:, :, None, :])
+
+
 class ShortConv(nn.Module):
     """Gated short convolution, the hybrid decoders' cheap operator:
     ``[B, C, X] = split3(W_in u)``; ``z = B * X``; ``c_t = sum_j w[j] *
@@ -943,7 +1296,7 @@ class Block(nn.Module):
     use_moe: bool
     window: int  # no default — see CausalSelfAttention.window
     manual_seq: bool = False  # see CausalSelfAttention.manual_seq
-    #: the layer's operator: "attn" or "conv" (GPTConfig.layer_kinds)
+    #: the layer's operator: "attn", "conv" or "mla" (GPTConfig.layer_kinds)
     op: str = "attn"
     #: the FFN is the dropless routed-expert layer (GPTConfig.experts)
     experts: bool = False
@@ -958,6 +1311,9 @@ class Block(nn.Module):
         if self.op == "conv":
             x = x + ShortConv(cfg, name="conv")(h, prefill_len,
                                                 decode_active)
+        elif self.op == "mla":
+            x = x + LatentAttention(cfg, self.mesh, name="attention")(
+                h, deterministic, prefill_len, decode_active)
         else:
             x = x + CausalSelfAttention(cfg, self.mesh, self.window,
                                         manual_seq=self.manual_seq,
@@ -985,6 +1341,13 @@ class Block(nn.Module):
                                     dtype=cfg.dtype,
                                     param_dtype=cfg.param_dtype,
                                     name="experts")(h, live)
+            if cfg.shared_expert_ff:
+                # the shared expert: every token, every chip alike
+                y = y + tp_dense("shared_out", cfg.d_model, "row")(
+                    nn.silu(tp_dense("shared_gate", cfg.shared_expert_ff,
+                                     "column")(h))
+                    * tp_dense("shared_up", cfg.shared_expert_ff,
+                               "column")(h))
         elif self.use_moe:
             y = moe_lib.SwitchFFN(cfg.d_model, cfg.d_ff, cfg.moe,
                                   dtype=cfg.dtype, name="moe")(h)
@@ -1058,6 +1421,14 @@ class GPT(nn.Module):
             # the table at its storage dtype, accumulated in float32
             return jnp.einsum("btd,vd->btv", x.astype(cfg.dtype),
                               embed.embedding.astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)
+        if cfg.param_dtype != jnp.float32:
+            # an untied head stored as the other matrices are, multiplied
+            # as the tied one is: accumulated in float32
+            head = _Kernel((cfg.d_model, cfg.vocab_size), cfg.param_dtype,
+                           name="lm_head")()
+            return jnp.einsum("btd,dv->btv", x.astype(cfg.dtype),
+                              head.astype(cfg.dtype),
                               preferred_element_type=jnp.float32)
         logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
                           param_dtype=jnp.float32, name="lm_head")(x)
@@ -1336,9 +1707,16 @@ _NON_BATCH_CACHE_KEYS = frozenset({"cache_index"})
 #: IS read (admission zeroes it), and there is nothing in it to page or to
 #: roll back to.
 _RECURRENT_CACHE_KEYS = frozenset({"conv_state"})
+#: LATENT leaves ([rows, width, L], LatentAttention's ``cached_latent``): led
+#: by the batch and indexed by position like K/V, so beams reorder them, the
+#: serve engine slices them per slot and a stale row is never read; but
+#: there is no head axis and positions are the MINOR axis, which the page
+#: programs' ``[rows, H, L, D]`` windows do not read yet.
+_LATENT_CACHE_KEYS = frozenset({"cached_latent"})
 #: every leaf whose leading dim is the batch: what beams reorder and the
 #: serve engine slices per slot
-_ROW_LED_CACHE_KEYS = _BATCH_LED_CACHE_KEYS | _RECURRENT_CACHE_KEYS
+_ROW_LED_CACHE_KEYS = (_BATCH_LED_CACHE_KEYS | _RECURRENT_CACHE_KEYS
+                       | _LATENT_CACHE_KEYS)
 
 
 def _path_key(k) -> str:
@@ -1373,6 +1751,12 @@ def _paged_leaf_check(name: str) -> bool:
             f"cache leaf {name!r} is a recurrent state: it has no positions "
             "to page (the prefix page cache does not serve a model with "
             "conv layers)")
+    if name in _LATENT_CACHE_KEYS:
+        raise ValueError(
+            f"cache leaf {name!r} is a latent cache, [rows, width, "
+            "positions]: the page programs copy [rows, heads, positions, "
+            "width] windows and do not page it yet (the prefix page cache "
+            "does not serve a model with latent attention)")
     if name not in _BATCH_LED_CACHE_KEYS:
         raise ValueError(
             f"unknown cache leaf {name!r}: add it to "

@@ -44,6 +44,17 @@ untouched). Everything else of the leaf is never written.
 
 Behind ONE module-level ``jax.jit``: the attention layers of a decode
 program share one trace and one lowering of the kernel.
+
+**The latent cache** (``models/gpt.py: LatentAttention``) has a second
+kernel of the same design, ``dtf_mla_decode_attn``
+(:func:`latent_decode_attention`): ONE leaf ``[slots, width, max_len]``,
+position-minor by its own shape, whose block ``[width, block]`` is the key
+of all heads at once and whose first ``rank`` rows are the value. The
+absorbed queries of all heads are the product's rows as they come (no
+block-diagonal widening: every head reads the same key), so scores are
+``[heads, width] @ [width, block]`` and the output ``[heads, block]`` against
+the block's first ``rank`` rows. The new row is written as a column of the
+one tile that holds the slot's index, and starts the running softmax.
 """
 
 from __future__ import annotations
@@ -90,6 +101,21 @@ def engages(*, cache_dtype, d_head: int, max_len: int, window: int,
             and d_head < TILE and d_head % (32 // dtype.itemsize) == 0
             and max_len % TILE == 0
             and not window
+            and (mesh is None or mesh.size == 1))
+
+
+def latent_engages(*, cache_dtype, width: int, rank: int, max_len: int,
+                   mesh) -> bool:
+    """Whether a latent layer's slot-decode step runs
+    :func:`latent_decode_attention`: the TPU backend, a bfloat16 or float32
+    leaf of whole 128-position tiles whose width and value rank are whole
+    sublane tiles, one device."""
+    dtype = jnp.dtype(cache_dtype)
+    sublanes = 32 // dtype.itemsize
+    return (on_tpu()
+            and dtype in (jnp.bfloat16, jnp.float32)
+            and width % sublanes == 0 and rank % sublanes == 0
+            and max_len % TILE == 0
             and (mesh is None or mesh.size == 1))
 
 
@@ -248,3 +274,125 @@ def _decode_attention(q, k_new, v_new, cached_key, cached_value, index,
       columns(k_new), columns(v_new), kt, vt)
     out = jnp.swapaxes(out.reshape(n_slots, group, kv_heads, d_head), 1, 2)
     return out, jnp.swapaxes(kt, 2, 3), jnp.swapaxes(vt, 2, 3)
+
+
+def _latent_kernel(idx_ref, act_ref, q_ref, new_ref, col_ref, lt_ref, o_ref,
+                   lt_out, m_ref, l_ref, acc_ref, *, block: int,
+                   max_len: int, rank: int, scale: float):
+    s = pl.program_id(0)
+    idx = idx_ref[s]
+    pos = jax.lax.rem(idx, max_len)
+    # idle steps first, as in _kernel: the slot's first block is fetched
+    # under them and under the slot before
+    j = pl.program_id(1) - _idle_steps(idx, block, max_len)
+    cdt = lt_ref.dtype
+    width = lt_ref.shape[0]
+    q = q_ref[...]                                      # [H, W]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _start():
+        new = new_ref[...].astype(cdt).astype(jnp.float32)       # [1, W]
+        m_ref[...] = jnp.sum(q.astype(jnp.float32) * new, axis=-1,
+                             keepdims=True) * scale              # [H, 1]
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc_ref[...] = jnp.broadcast_to(new[:, :rank], acc_ref.shape)
+
+    @pl.when((j >= 0) & (j * block < jnp.minimum(idx, max_len)))
+    def _cached():
+        lt = lt_ref[...]                                         # [W, B]
+        sc = jnp.dot(q, lt, preferred_element_type=jnp.float32) * scale
+        at = j * block + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        sc = jnp.where((at < idx) & (at != pos), sc, -jnp.inf)   # [H, B]
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(sc - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            p.astype(cdt), lt[:rank], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                  # [H, rank]
+        m_ref[...] = m_new
+
+    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+    @pl.when(j == pos // block)
+    def _write():
+        at = pl.ds(pl.multiple_of(jax.lax.rem(pos, block) // TILE * TILE,
+                                  TILE), TILE)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (width, TILE), 1)
+        hit = (lane == jax.lax.rem(pos, TILE)) & (act_ref[s] != 0)
+        # the new row stands in lane 0 as a column [W, 1]
+        new = jnp.broadcast_to(col_ref[...][:, :1], (width, TILE))
+        lt_out[...] = jnp.where(hit, new.astype(cdt), lt_ref[:, at])
+
+
+def latent_decode_attention(q: jax.Array, new: jax.Array,
+                            cached_latent: jax.Array, index: jax.Array,
+                            active: jax.Array, *, rank: int, scale: float):
+    """``q`` [S, heads, width] (each head's absorbed no-position query
+    beside its rotated part), ``new`` [S, width] (the token's normalised
+    latent row beside its rotated key part), the leaf [S, width, max_len],
+    ``index`` [S] int32, ``active`` [S] bool. Returns ``(out [S, heads,
+    rank] in q's dtype: each head's probabilities over the latent rows'
+    first ``rank`` numbers, cached_latent)`` with column ``index`` of every
+    active slot written; the leaf is updated in place where the caller
+    donates it. Off the TPU (the tests) the kernel runs in interpret
+    mode."""
+    return _latent_decode_attention(
+        q, new, cached_latent, index, active, rank=rank, scale=float(scale),
+        interpret=jax.default_backend() != "tpu")
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
+def _latent_decode_attention(q, new, cached_latent, index, active, *,
+                             rank: int, scale: float, interpret: bool):
+    n_slots, heads, width = q.shape
+    max_len = cached_latent.shape[2]
+    block = block_positions(1, width, max_len, cached_latent.dtype.itemsize)
+    # an inactive slot (mid-prefill: thousands of rows, none of them this
+    # step's to read) rides as a slot at index 0: one block fetched, nothing
+    # computed, tile 0 copied back as it was
+    index = jnp.where(active, index, 0)
+    # the new row as a column under TILE lanes, as the leaf takes it
+    column = jnp.pad(new.astype(cached_latent.dtype)[:, :, None],
+                     ((0, 0), (0, 0), (0, TILE - 1)))
+
+    def live_block(s, j, idx, act):
+        return (s, 0, jnp.maximum(j - _idle_steps(idx[s], block, max_len), 0))
+
+    def written_tile(s, j, idx, act):
+        return (s, 0, jax.lax.rem(idx[s], max_len) // TILE)
+
+    def per_slot(*shape):
+        return pl.BlockSpec((None,) + shape,
+                            lambda s, j, idx, act: (s,) + (0,) * len(shape))
+
+    out, cached_latent = pl.pallas_call(
+        functools.partial(_latent_kernel, block=block, max_len=max_len,
+                          rank=rank, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_slots, max_len // block),
+            in_specs=[per_slot(heads, width), per_slot(1, width),
+                      per_slot(width, TILE),
+                      pl.BlockSpec((None, width, block), live_block)],
+            out_specs=[per_slot(heads, rank),
+                       pl.BlockSpec((None, width, TILE), written_tile)],
+            scratch_shapes=[pltpu.VMEM((heads, 1), jnp.float32),
+                            pltpu.VMEM((heads, 1), jnp.float32),
+                            pltpu.VMEM((heads, rank), jnp.float32)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((n_slots, heads, rank), q.dtype),
+                   jax.ShapeDtypeStruct(cached_latent.shape,
+                                        cached_latent.dtype)],
+        # operands count the two scalar-prefetch arrays
+        input_output_aliases={5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="dtf_mla_decode_attn",
+    )(index.astype(jnp.int32), active.astype(jnp.int32), q,
+      new.reshape(n_slots, 1, width), column, cached_latent)
+    return out, cached_latent

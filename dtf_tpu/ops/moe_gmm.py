@@ -14,7 +14,9 @@ so every touched expert's weights cross HBM once per call and an expert no
 row chose is never read — the whole point at decode, where a step is the
 time it takes to stream the chosen experts. Tiles past ``n_used`` (the
 layout's static worst case is ``pairs + groups * (tm - 1)`` rows) keep the
-last used expert's block, so they cost no traffic, and write zeros.
+last used expert's block and the last used row tile, so they cost no
+traffic, and write zeros: a layer that holds a sixteenth of its experts has
+a worst case sixteen times its expected rows.
 """
 
 from __future__ import annotations
@@ -45,11 +47,20 @@ def _kernel(tile_group_ref, n_used_ref, x_ref, w_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
-def column_tile(n: int) -> int:
-    """Two column tiles where the halves stay lane-aligned (a 2048 x 768
-    bfloat16 block is 3 MiB, 6 MiB double-buffered: inside the 16 MiB of
-    scoped VMEM with room for the row tiles), else the whole width."""
-    return n // 2 if n % 256 == 0 else n
+#: bytes of one weight block: two of them (double-buffered) and the row
+#: tiles stay inside the 16 MiB of scoped VMEM
+_WEIGHT_BLOCK_BYTES = 4 * 1024 * 1024
+
+
+def column_tile(k: int, n: int, itemsize: int = 2) -> int:
+    """Columns of a weight block ``[k, tn]``: the widest lane-aligned
+    divisor of ``n``, at most half of it, whose block stays under
+    ``_WEIGHT_BLOCK_BYTES`` (2048 x 1536 -> 768 and 1536 x 2048 -> 1024, 3
+    MiB each; 7168 x 2048 -> 256, 3.5 MiB, and 2048 x 7168 -> 1024, 4 MiB);
+    the whole width where ``n`` has no such divisor."""
+    fits = [tn for tn in range(128, n // 2 + 1, 128)
+            if n % tn == 0 and k * tn * itemsize <= _WEIGHT_BLOCK_BYTES]
+    return max(fits) if fits else n
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "interpret"))
@@ -66,14 +77,19 @@ def grouped_matmul(x: jax.Array, w: jax.Array, tile_group: jax.Array,
         raise ValueError(
             f"rows {m} must be whole tiles of tm={tm}, a multiple of "
             f"{MIN_TILE_ROWS}")
-    tn = column_tile(n)
+    tn = column_tile(k, n, w.dtype.itemsize)
+
+    def row_tile(j, i, tg, nu):
+        # a tile past the used ones names the last used one: no fetch
+        return jnp.maximum(jnp.minimum(i, nu[0] - 1), 0), 0
+
     return pl.pallas_call(
         _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n // tn, m // tm),
             in_specs=[
-                pl.BlockSpec((tm, k), lambda j, i, tg, nu: (i, 0)),
+                pl.BlockSpec((tm, k), row_tile),
                 pl.BlockSpec((None, k, tn),
                              lambda j, i, tg, nu: (tg[i], 0, j)),
             ],

@@ -224,7 +224,8 @@ def moe_aux_loss(mutables: dict, cfg: MoeConfig) -> jax.Array:
 class ExpertsConfig:
     """A dropless routed-expert layer as today's sparse decoders publish it
     (``num_experts`` / ``num_experts_per_tok`` / ``moe_intermediate_size`` /
-    ``norm_topk_prob`` / ``use_expert_bias`` / ``routed_scaling_factor``)."""
+    ``norm_topk_prob`` / ``use_expert_bias`` / ``routed_scaling_factor`` /
+    ``n_group`` / ``topk_group``)."""
 
     num_experts: int = 64
     top_k: int = 4
@@ -238,12 +239,29 @@ class ExpertsConfig:
     #: out — a chip's share of an expert-parallel deployment, without the
     #: exchange. The shares of disjoint ranges add up to the whole layer.
     experts_held: Optional[tuple[int, int]] = None
+    #: group-limited choice: the experts lie in ``n_group`` contiguous
+    #: groups, a group's score is the sum of its two largest choice scores,
+    #: and only the ``topk_group`` best groups' experts can be chosen. One
+    #: group = a plain top-k over all experts.
+    n_group: int = 1
+    topk_group: int = 1
 
     def __post_init__(self):
         if not 1 <= self.top_k <= self.num_experts:
             raise ValueError(
                 f"top_k={self.top_k} must be in [1, num_experts="
                 f"{self.num_experts}]")
+        if (self.n_group < 1 or self.num_experts % self.n_group
+                or not 1 <= self.topk_group <= self.n_group
+                or self.top_k > self.topk_group
+                * (self.num_experts // self.n_group)
+                or (self.n_group > 1
+                    and self.num_experts // self.n_group < 2)):
+            raise ValueError(
+                f"n_group={self.n_group} must divide num_experts="
+                f"{self.num_experts} into groups of at least two, and "
+                f"topk_group={self.topk_group} of them must hold top_k="
+                f"{self.top_k} experts")
         lo, hi = self.held
         if not 0 <= lo < hi <= self.num_experts:
             raise ValueError(
@@ -256,13 +274,30 @@ class ExpertsConfig:
             else tuple(self.experts_held)
 
 
+def router_scores(tokens: jax.Array, w_g: jax.Array) -> jax.Array:
+    """``sigmoid(W_g x)`` [G, E] in float32 at the highest precision: a
+    top-k choice flips on rounding."""
+    return jax.nn.sigmoid(jnp.dot(
+        tokens.astype(jnp.float32), w_g,
+        precision=jax.lax.Precision.HIGHEST))
+
+
 def route_topk(scores: jax.Array, bias: Optional[jax.Array], cfg: ExpertsConfig
                ) -> tuple[jax.Array, jax.Array]:
     """``scores`` [G, E] float32 (after the sigmoid) -> (experts [G, k]
     int32, weights [G, k] float32). The bias moves the CHOICE only; the
     weights are the chosen experts' own scores, normalised over the chosen
-    (``s_i / (sum + 1e-6)``) and scaled."""
+    (``s_i / (sum + 1e-6)``) and scaled. With ``n_group`` > 1 the choice is
+    limited to the ``topk_group`` groups whose two largest choice scores
+    sum highest."""
     choice = scores if bias is None else scores + bias[None, :]
+    if cfg.n_group > 1:
+        grouped = choice.reshape(choice.shape[0], cfg.n_group, -1)
+        group_score = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)   # [G, n]
+        _, kept = jax.lax.top_k(group_score, cfg.topk_group)
+        kept = jnp.any(kept[:, :, None] == jnp.arange(cfg.n_group), axis=1)
+        choice = jnp.where(kept[:, :, None], grouped,
+                           -jnp.inf).reshape(choice.shape)
     _, experts = jax.lax.top_k(choice, cfg.top_k)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if cfg.norm_topk_prob:
@@ -318,9 +353,11 @@ def group_layout(group: jax.Array, counts: jax.Array, tm: int) -> dict:
 
 
 def tile_rows(pairs: int, n_groups: int) -> int:
-    """Rows of a tile for the Pallas product: about twice the mean group,
-    so that most groups fill one tile, between 16 (bfloat16's sublane
-    packing) and 128 (the MXU's edge)."""
+    """Rows of a tile for the Pallas product: about twice the mean group
+    (``pairs`` are the pairs EXPECTED on these ``n_groups`` experts: a
+    layer that holds a share of its experts expects that share of its
+    pairs), so that most groups fill one tile, between 16 (bfloat16's
+    sublane packing) and 128 (the MXU's edge)."""
     tm = moe_gmm.MIN_TILE_ROWS
     while tm < 128 and tm < 2 * pairs // max(n_groups, 1):
         tm *= 2
@@ -341,7 +378,9 @@ class DroplessMoE(nn.Module):
 
     Sows, into the ``moe_stats`` collection when it is mutable, what the
     routing did to the unmasked tokens over ALL experts: ``touched`` (experts
-    with at least one token) and ``max_load`` (tokens on the fullest one).
+    with at least one token) and ``max_load`` (tokens on the fullest one);
+    and over the experts HELD here: ``held_pairs`` (the pairs this layer
+    computes) and ``held_touched`` (held experts with at least one token).
 
     The grouped product is the ``dtf_moe_gmm`` Pallas kernel on a TPU and
     ``jax.lax.ragged_dot`` on any other backend (the rule flash attention
@@ -372,10 +411,8 @@ class DroplessMoE(nn.Module):
         w3 = self.param("w3", init, (n_held, d, cfg.d_ff), self.param_dtype)
         w2 = self.param("w2", init, (n_held, cfg.d_ff, d), self.param_dtype)
 
-        scores = jax.nn.sigmoid(jnp.dot(
-            tokens.astype(jnp.float32), w_g,
-            precision=jax.lax.Precision.HIGHEST))
-        experts, weights = route_topk(scores, bias, cfg)       # [G, k]
+        experts, weights = route_topk(router_scores(tokens, w_g), bias,
+                                      cfg)                     # [G, k]
 
         live = jnp.ones((g,), bool) if token_mask is None \
             else token_mask.reshape(g)
@@ -388,8 +425,11 @@ class DroplessMoE(nn.Module):
         held = (pair_expert >= lo) & (pair_expert < hi)
         group = jnp.where(held, pair_expert - lo, n_held).astype(jnp.int32)
         on_tpu = jax.default_backend() == "tpu"
-        tm = tile_rows(g * k, n_held) if on_tpu else 1
+        tm = tile_rows(g * k * n_held // e, n_held) if on_tpu else 1
         counts = load[lo:hi]
+        self.sow("moe_stats", "held_pairs", jnp.sum(counts))
+        self.sow("moe_stats", "held_touched",
+                 jnp.sum(counts > 0, dtype=jnp.int32))
         lay = group_layout(group, counts, tm)
         if on_tpu:
             def product(a, w):

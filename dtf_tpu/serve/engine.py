@@ -253,14 +253,22 @@ def _pick_rows(subs, logits, temp, top_k, top_p, live):
 _STEP_OUT_NAMES = ("sampler_path", "cache_positions", "active_slots")
 
 
+#: what an expert layer sows into ``moe_stats`` (``DroplessMoE``), in the
+#: order the decode program packs them
+_MOE_LAYER_STATS = ("touched", "max_load", "held_pairs", "held_touched")
+
+
 def _moe_out_names(cfg: gpt.GPTConfig) -> tuple:
     """What the decode program of a model with routed experts returns
-    besides, in the tokens' transfer: the (token, expert) pairs an expert
-    layer computed, and per expert layer the experts that got a token and
-    the fullest expert's tokens."""
-    if cfg.experts is None:
-        return ()
-    return ("moe_picks", "moe_touched", "moe_max_load")
+    besides, in the tokens' transfer: ONE int32 vector, ``moe_stats`` —
+    the (token, expert) pairs an expert layer routed, the cached positions
+    of the active slots, then per expert layer each of
+    :data:`_MOE_LAYER_STATS` (the experts that got a token, the fullest
+    expert's tokens, and of the experts the layer HOLDS the pairs that
+    landed on them and how many got a token). One vector because every
+    array read back is a transfer of its own (~0.4 ms of a tick each on a
+    v5e's host, PERF.md section 6, PR 31)."""
+    return () if cfg.experts is None else ("moe_stats",)
 
 
 def _layers_in_order(tree: dict) -> list:
@@ -310,10 +318,11 @@ def _build_decode_fn(model: gpt.GPT):
                "active_slots": jnp.sum(active, dtype=jnp.int32)}
         if with_moe:
             layers = _layers_in_order(mut["moe_stats"])
-            for key in ("touched", "max_load"):
-                out[f"moe_{key}"] = jnp.stack(
-                    [layer["experts"][key][0] for layer in layers])
-            out["moe_picks"] = out["active_slots"] * model.cfg.experts.top_k
+            out["moe_stats"] = jnp.stack(
+                [out["active_slots"] * model.cfg.experts.top_k,
+                 out["cache_positions"]]
+                + [layer["experts"][key][0] for key in _MOE_LAYER_STATS
+                   for layer in layers]).astype(jnp.int32)
         return new_state, out
 
     return decode_fn
@@ -640,6 +649,20 @@ class DecodeEngine:
                         f"{what} does not serve a model with conv layers: "
                         "their recurrent state has no positions to page, "
                         "roll back or rescale")
+        if cfg.has_latent_cache:
+            # a latent layer caches ONE row a position, [slots, width,
+            # positions]: the page programs copy [slots, heads, positions,
+            # width] windows, the verify step is not written for it, and
+            # the config itself refuses an int8 form (docs/SERVING.md)
+            for asked, what in (
+                    (prefix_pages, "the prefix page cache (prefix_pages)"),
+                    (draft_cfg is not None or spec_k,
+                     "speculative decoding (draft_cfg / spec_k)")):
+                if asked:
+                    raise ValueError(
+                        f"{what} does not serve a model with latent "
+                        "attention yet: its cache leaf has no head axis to "
+                        "page by, and no verify step to roll back")
         base = dataclasses.replace(cfg, decode_len=max_len,
                                    slot_decode=False, chunked_prefill=False)
         # the chunk may not be wider than ANY layer's cache: the rolling-
@@ -1112,7 +1135,7 @@ class DecodeEngine:
                     self._params, self._live(self._state))
             self._note_step(out)
             with self._annotation("dtf.engine.decode.readback"):
-                if "moe_touched" in out:
+                if "moe_stats" in out:
                     self._note_moe(out)
                 return np.asarray(out["token"]), np.asarray(out["done"])
 
@@ -1129,12 +1152,15 @@ class DecodeEngine:
     def _note_moe(self, out) -> None:
         """A routed-expert model's decode step, from the readback decode
         makes anyway: ``picks`` are the (token, expert) pairs one expert
-        layer computed; the rest are means over the expert layers.
+        layer routed; the rest are means over the expert layers
+        (``held_pairs`` / ``held_touched``: the pairs that landed on the
+        experts the layer holds, and how many of those got a token).
         ``counters`` sums over layers (divide by ``decode_steps`` x layers
         for means)."""
-        touched = np.asarray(out["moe_touched"])
-        max_load = np.asarray(out["moe_max_load"])
-        picks = int(out["moe_picks"])
+        stats = np.asarray(out["moe_stats"])
+        picks, cache_positions = int(stats[0]), int(stats[1])
+        touched, max_load, held_pairs, held_touched = stats[2:].reshape(
+            len(_MOE_LAYER_STATS), -1)
         self.counters["moe_decode_picks"] += picks * len(touched)
         self.counters["moe_experts_touched"] += int(touched.sum())
         self.counters["moe_max_expert_load"] += int(max_load.sum())
@@ -1146,7 +1172,9 @@ class DecodeEngine:
             "max_expert_load": float(max_load.mean()),
             "max_load_over_mean": float(max_load.mean())
             * self.cfg.experts.num_experts / picks,
-            "cache_positions": int(out["cache_positions"])}
+            "held_pairs": float(held_pairs.mean()),
+            "held_touched": float(held_touched.mean()),
+            "cache_positions": cache_positions}
 
     def take_samples(self) -> dict:
         """What the last engine call left for a telemetry object, by span

@@ -64,6 +64,7 @@ import collections
 import dataclasses
 import logging
 import time
+import weakref
 from typing import Optional, Sequence
 
 from dtf_tpu.metrics import quantile as _quantile
@@ -197,8 +198,13 @@ class Scheduler:
             # the dump path must not touch a wedged backend). The Router
             # registers ONE aggregate provider instead (postmortem_name
             # None for its replica schedulers).
+            # held WEAKLY: the telemetry object outlives a scheduler its
+            # owner has dropped, and a strong bound method would keep the
+            # engine — its whole KV cache on the device — alive with it
+            # (3.6 GB in the latent-cache cell's traced run, PERF.md PR 31)
+            state = weakref.WeakMethod(self.postmortem_state)
             telemetry.add_postmortem_provider(
-                postmortem_name, self.postmortem_state)
+                postmortem_name, lambda: (state() or dict)())
         #: TTFT service-level objective (0 = untracked): ``stats()`` then
         #: reports the fraction of completed first tokens inside it — the
         #: per-replica SLO rollup the router surfaces (docs/SERVING.md).

@@ -65,6 +65,29 @@ enable_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 
+#: ``test_bench_manifest.test_config_entry_and_file`` refuses a ``reduced``
+#: key that CONTAINS ``hidden``, and the contract makes ``reduced`` name the
+#: source's depth key ``num_hidden_layers``. ``tests/benchmark_suite/`` is the
+#: benchmark's own and not a ``model_config`` PR's to edit, so PR 31's case is
+#: marked from here as ``tests/benchmark_suite/conftest.py`` marks LFM2's:
+#: expected to fail, STRICTLY (the day a ``benchmark`` PR repairs the pattern
+#: the case passes, the marker turns that into a failure, and both markers
+#: go; PERF.md section 7 a). ``test_bench_axk1.py`` makes the same checks
+#: with the widths named by key.
+DEPTH_KEY_READ_AS_WIDTH = (
+    "test_bench_manifest.py::test_config_entry_and_file[ax-k1]")
+
+
+def pytest_collection_modifyitems(items):
+    for item in items:
+        if item.nodeid.endswith(DEPTH_KEY_READ_AS_WIDTH):
+            item.add_marker(pytest.mark.xfail(
+                reason="the accepted width pattern matches 'hidden' inside "
+                       "the depth key num_hidden_layers; "
+                       "test_bench_axk1.py checks the entry instead",
+                strict=True))
+
+
 @pytest.fixture(scope="session")
 def cpu_sim_subprocess_env():
     """A CPU-pinned env for subprocess children (probe/bench tests) —

@@ -339,6 +339,81 @@ def test_lfm2_serve_programs_update_both_kinds_of_state_in_place(
     assert len(kernels) == 3, kernels
 
 
+# ---- latent attention: one latent leaf, a share of the experts -------------
+
+AXK1_SERVE = dict(n_slots=32, max_len=16384, prefill_chunk=512)  # its cell
+
+
+def _axk1_program(on_chip, monkeypatch, name):
+    """The ``axk1-serve-closed32-doc16k`` cell's ``name`` program at its
+    widths and slots, cut to the dense layer and one expert layer (12 of
+    192 routed experts held, the shared expert) and a 1024-token
+    vocabulary. The program asks ``jax.default_backend()`` whether to run
+    its Pallas kernels; the answer is the CPU's here, so the test gives the
+    chip's."""
+    from dtf_tpu.parallel import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = gpt.GPTConfig(
+        vocab_size=1024, d_model=7168, layers=2, heads=64, d_ff=18432,
+        norm="rmsnorm", norm_eps=1e-6, ffn="swiglu", use_bias=False,
+        layer_kinds=("mla", "mla"), dense_layers=1, shared_expert_ff=2048,
+        param_dtype=jnp.bfloat16,
+        latent=gpt.LatentAttentionConfig(yarn_factor=32, yarn_mscale=1,
+                                         yarn_mscale_all_dim=1),
+        experts=moe.ExpertsConfig(
+            num_experts=192, top_k=8, d_ff=2048, use_expert_bias=False,
+            routed_scaling_factor=2.5, n_group=8, topk_group=4,
+            experts_held=(0, 12)))
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda s: on_chip(s.shape, s.dtype), tree)
+    state = place(serve_engine.engine_state_struct(
+        cfg, n_slots=AXK1_SERVE["n_slots"], max_len=AXK1_SERVE["max_len"]))
+    model = gpt.GPT(cfg)
+    params = place(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"])
+    programs, _ = serve_engine.program_table(
+        cfg, **AXK1_SERVE, abs_trees={"params": params, "state": state})
+    prog = programs[name]
+    return prog.lower(*place(prog.abstract_args)).compile(), state
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_axk1_serve_programs_update_the_latent_cache_in_place(
+        on_chip, monkeypatch, compiled_once, name):
+    """PR 25's fence on the model of PR 31. The latent leaf, [32, 576,
+    16384] bfloat16 (604 MB a layer, positions minor), is produced whole by
+    no ``copy``, ``transpose`` or ``scatter``; the donated state is aliased
+    to the output whole; the temporaries stay under one leaf (a copy or a
+    relayout of it would not fit under that). Decode holds one
+    ``dtf_mla_decode_attn`` a layer — the leaf goes through the kernel as
+    it lies — and prefill writes its chunk as one slab; both hold the
+    grouped product three times an expert layer, at the blocks
+    ``moe_gmm.column_tile`` finds for 7168 x 2048 (the LFM2 cell's whole
+    halves would ask for 29 MB of the 16 MiB scoped VMEM)."""
+    compiled, state = compiled_once(
+        ("axk1", name), lambda: _axk1_program(on_chip, monkeypatch, name))
+    text = compiled.as_text()
+    leaf = f"[{AXK1_SERVE['n_slots']},576,{AXK1_SERVE['max_len']}]"
+    assert f"bf16{leaf}" in text
+    whole_leaf = re.findall(
+        rf"^\s*(?:ROOT )?%\S+ = bf16{re.escape(leaf)}\S* "
+        rf"(copy|transpose|scatter)\(", text, re.M)
+    assert not whole_leaf, whole_leaf
+    nbytes = lambda tree: sum(  # noqa: E731
+        s.size * s.dtype.itemsize for s in jax.tree.leaves(tree))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes(state)
+    assert mem.temp_size_in_bytes < nbytes(state["cache"]) / 2
+    gmm = re.findall(
+        r"^\s*%\w*dtf_moe_gmm\w*(?:\.\d+)? = .*tpu_custom_call", text, re.M)
+    assert len(gmm) == 3, gmm
+    attn = re.findall(
+        r"^\s*%\w*dtf_mla_decode_attn\w*(?:\.\d+)? = .*tpu_custom_call",
+        text, re.M)
+    assert len(attn) == (2 if name == "decode" else 0), attn
+
+
 # ---- slot-decode attention: one kernel an attention layer, lowered once ----
 
 DECODE_KERNEL = r"^\s*%\w*dtf_decode_attn\w*(?:\.\d+)? = .*tpu_custom_call"

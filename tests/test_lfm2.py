@@ -420,5 +420,5 @@ def test_no_table_of_the_programs_choices_exists():
     fields = {f.name for f in dataclasses.fields(moe.ExpertsConfig)}
     assert fields == {"num_experts", "top_k", "d_ff", "norm_topk_prob",
                       "use_expert_bias", "routed_scaling_factor",
-                      "experts_held"}
+                      "experts_held", "n_group", "topk_group"}
     assert "hint" not in inspect.signature(ref.forward).parameters
