@@ -16,8 +16,8 @@ IS spec decode; there is no separate single-token program), and the
 draft twins ``draft_prefill`` / ``draft_all``. See the speculative
 section below.
 
-- ``prefill_into_slot(slot, chunk, ...)`` — one fixed-width prompt chunk
-  into one slot. The slot's rows are sliced out of the engine state into a
+- ``prefill_into_slot(ops)`` — one fixed-width prompt chunk into one
+  slot. The slot's rows are sliced out of the engine state into a
   batch-1 PLAIN cache (scalar ``cache_index``) and run through the
   ``chunked_prefill`` cache-continuing model that offline
   ``generate(prefill_chunk=...)`` already uses; the ragged last chunk is
@@ -34,6 +34,18 @@ section below.
   for: :func:`dtf_tpu.models.gpt.filter_logits_dynamic` and its
   vocabulary sorts run only in a step where some live slot filters, the
   noise only where one samples; an all-greedy step is an arg-max.
+
+**A call crosses to the device once each way** (PR 32). Beside params and
+state a prefill call takes ONE host array (:data:`_PREFILL_HEAD`'s
+scalars, the request's seed among them, then the chunk's tokens) and a
+decode step none; nothing runs on the device outside the programs (the
+PRNG key is made inside prefill) and the host blocks on nothing before a
+dispatch. Everything the host reads of a call — tokens, done flags, the
+step's telemetry scalars, a routed-expert model's counts — rides ONE int32
+vector (:func:`_pack_out`) whose copy to the host starts at dispatch and
+is awaited once (:meth:`DecodeEngine._read`); a chunk that is not a
+request's last reads nothing. ``counters["host_operands"]`` /
+``["device_reads"]`` count both and tests/test_serve.py fences them.
 
 With ``prefix_pages > 0`` the engine additionally keeps a device **page
 pool** and two more AOT programs, ``page_save``/``page_load`` (fixed-shape
@@ -57,8 +69,8 @@ decode (greedy and seeded sampling alike — the verifier's samples are
 the stream; proposals only decide how many positions per dispatch are
 worth keeping), pinned by tests/test_serve_spec.py. The draft's cache
 stays in sync through host-mirrored ``(tok, index)`` operands that ride
-readbacks decode performs anyway; the draft never touches the page pool
-(its prefill always covers the full prompt). A draft failure falls back
+the one readback decode performs anyway; the draft never touches the page
+pool (its prefill always covers the full prompt). A draft failure falls back
 to verify-with-null-proposals — plain decode — instead of erroring
 requests.
 
@@ -107,6 +119,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import math
+import operator
 from typing import Any, Optional, Sequence
 
 import jax
@@ -246,29 +259,36 @@ def _pick_rows(subs, logits, temp, top_k, top_p, live):
     return jax.lax.switch(path, (greedy, unfiltered, filtered)), path
 
 
-#: What every decode program returns beside ``token`` and ``done``, still
-#: on the device until somebody asks (``DecodeEngine.take_samples``): the
-#: sampler's path, the valid cache positions summed over the active slots
-#: (what a step must read), and how many slots were active.
+#: What a decode step's packed vector (:func:`_pack_out`) carries behind its
+#: tokens and flags: the sampler's path, the valid cache positions summed
+#: over the active slots (what a step must read), and how many slots were
+#: active. A model with routed experts appends per expert layer each of
+#: :data:`_MOE_LAYER_STATS`.
 _STEP_OUT_NAMES = ("sampler_path", "cache_positions", "active_slots")
 
 
 #: what an expert layer sows into ``moe_stats`` (``DroplessMoE``), in the
-#: order the decode program packs them
+#: order the decode program packs them: the experts that got a token, the
+#: fullest expert's tokens, and of the experts the layer HOLDS the pairs
+#: that landed on them and how many got a token
 _MOE_LAYER_STATS = ("touched", "max_load", "held_pairs", "held_touched")
 
 
-def _moe_out_names(cfg: gpt.GPTConfig) -> tuple:
-    """What the decode program of a model with routed experts returns
-    besides, in the tokens' transfer: ONE int32 vector, ``moe_stats`` —
-    the (token, expert) pairs an expert layer routed, the cached positions
-    of the active slots, then per expert layer each of
-    :data:`_MOE_LAYER_STATS` (the experts that got a token, the fullest
-    expert's tokens, and of the experts the layer HOLDS the pairs that
-    landed on them and how many got a token). One vector because every
+def _pack_out(tokens, done, *tail):
+    """Everything the host reads of one program call as ONE int32 vector
+    (``tokens``, ``done`` as 0/1, then ``tail``, each flattened): every
     array read back is a transfer of its own (~0.4 ms of a tick each on a
-    v5e's host, PERF.md section 6, PR 31)."""
-    return () if cfg.experts is None else ("moe_stats",)
+    v5e's host, PERF.md section 6, PR 31), and one vector's copy can be
+    started where the program is dispatched. :func:`_split_out` is its
+    inverse on the host."""
+    return jnp.concatenate([jnp.ravel(x).astype(jnp.int32)
+                            for x in (tokens, done, *tail)])
+
+
+def _split_out(vec: np.ndarray, n: int):
+    """A :func:`_pack_out` vector on the host: ``(tokens [n], done [n]
+    bool, tail)``."""
+    return vec[:n], vec[n:2 * n].astype(bool), vec[2 * n:]
 
 
 def _layers_in_order(tree: dict) -> list:
@@ -278,7 +298,7 @@ def _layers_in_order(tree: dict) -> list:
 
 def _build_decode_fn(model: gpt.GPT):
     """decode_all: one masked token step across all slots."""
-    with_moe = bool(_moe_out_names(model.cfg))
+    with_moe = model.cfg.experts is not None
     collections_out = ["cache"] + (["moe_stats"] if with_moe else [])
 
     def decode_fn(params, state):
@@ -312,18 +332,15 @@ def _build_decode_fn(model: gpt.GPT):
             "tok": jnp.where(active, nxt, state["tok"]),
             "done": jnp.where(active, done, state["done"]),
         }
-        out = {"token": nxt, "done": done, "sampler_path": path,
-               "cache_positions": jnp.sum(jnp.where(
-                   active, gpt.cache_index_of(state["cache"]), 0)),
-               "active_slots": jnp.sum(active, dtype=jnp.int32)}
+        tail = [path,                                   # _STEP_OUT_NAMES
+                jnp.sum(jnp.where(
+                    active, gpt.cache_index_of(state["cache"]), 0)),
+                jnp.sum(active, dtype=jnp.int32)]
         if with_moe:
             layers = _layers_in_order(mut["moe_stats"])
-            out["moe_stats"] = jnp.stack(
-                [out["active_slots"] * model.cfg.experts.top_k,
-                 out["cache_positions"]]
-                + [layer["experts"][key][0] for key in _MOE_LAYER_STATS
-                   for layer in layers]).astype(jnp.int32)
-        return new_state, out
+            tail += [layer["experts"][key][0] for key in _MOE_LAYER_STATS
+                     for layer in layers]
+        return new_state, _pack_out(nxt, done, *tail)
 
     return decode_fn
 
@@ -441,22 +458,56 @@ def _build_verify_fn(model: gpt.GPT, k: int):
             "tok": jnp.where(active, new_tok, state["tok"]),
             "done": jnp.where(active, new_done, state["done"]),
         }
-        return new_state, {"tokens": toks, "done": dones, "n_emit": n_emit,
-                           "sampler_path": path}
+        return new_state, _pack_out(toks, dones, path, n_emit)
 
     return verify_fn
+
+
+#: The scalars at the head of a prefill call's ONE host operand, an int32
+#: vector; the chunk's ``prefill_chunk`` token columns follow them. One
+#: array because every host array handed to a compiled program is a
+#: transfer of its own (~0.2 ms each on a v5e's host, PERF.md section 6,
+#: PR 32). ``temp`` and ``top_p`` travel as their float32 bit patterns, so
+#: not a bit of a sampling parameter changes on the way; ``seed`` is the
+#: int32 that ``jax.random.PRNGKey`` makes of a request's seed
+#: (:func:`_seed_word`), and the program makes the key of it.
+_PREFILL_HEAD = ("slot", "start", "n_valid", "reset", "is_last", "temp",
+                 "top_k", "top_p", "eos", "pad", "seed")
+
+
+def _seed_word(seed) -> np.int32:
+    """``seed`` as ``jax.random.PRNGKey(seed)`` reads it without 64-bit
+    types (the only mode this package runs in): wrapped to 32 bits, so
+    -1 and 2**32 - 1 seed the same stream and 2**32 seeds 0's. Refuses
+    what ``PRNGKey`` refuses (no integer: TypeError; beyond 64 bits:
+    OverflowError) — on the host, before any dispatch."""
+    return np.int64(operator.index(seed)).astype(np.int32)
+
+
+def _f32_bits(x) -> np.int32:
+    return np.float32(x).view(np.int32)
 
 
 def _build_prefill_fn(model: gpt.GPT):
     """prefill_into_slot: one fixed-width chunk into one slot; on the last
     chunk, sample the request's first token (generate's split-then-pick).
-    ``start`` is the number of already-valid leading positions (0 for a
-    plain request; the prefix-page count × page size after page loads) —
-    the reset lands the slot's index there, so the live chunks CONTINUE
-    the loaded pages exactly like offline chunked prefill continues an
-    advanced cache."""
-    def prefill_fn(params, state, slot, start, chunk, n_valid, reset,
-                   is_last, temp, top_k, top_p, eos, pad, key):
+    ``ops`` is the call's one host operand (:data:`_PREFILL_HEAD`, then
+    the chunk). ``start`` is the number of already-valid leading positions
+    (0 for a plain request; the prefix-page count × page size after page
+    loads) — the reset lands the slot's index there, so the live chunks
+    CONTINUE the loaded pages exactly like offline chunked prefill
+    continues an advanced cache. Returns the state's successor and
+    ``_pack_out(token, done)``."""
+    def prefill_fn(params, state, ops):
+        (slot, start, n_valid, reset, is_last, temp, top_k, top_p, eos, pad,
+         seed) = (ops[i] for i in range(len(_PREFILL_HEAD)))
+        chunk = ops[len(_PREFILL_HEAD):]
+        reset, is_last = reset != 0, is_last != 0
+        temp = jax.lax.bitcast_convert_type(temp, jnp.float32)
+        top_p = jax.lax.bitcast_convert_type(top_p, jnp.float32)
+        # made here, not on the host: an eager PRNGKey is a device program
+        # and a blocking read that waits for whatever chunk is in flight
+        key = jax.random.PRNGKey(seed)
         cache = state["cache"]
         row = _slice_slot_cache(cache, slot)
         # a fresh request starts at index `start` (0 without prefix pages;
@@ -505,7 +556,7 @@ def _build_prefill_fn(model: gpt.GPT):
             # until then it is a masked spectator of the all-slots step
             "active": state["active"].at[slot].set(is_last),
         }
-        return new_state, {"token": tok_new, "done": done_new}
+        return new_state, _pack_out(tok_new, done_new)
 
     return prefill_fn
 
@@ -734,28 +785,31 @@ class DecodeEngine:
                     f"max_len={max_len} cache for a verify window")
 
         #: host-side call counters (plain ints — zero device readbacks):
-        #: the bench/telemetry surface for "how much prefill work ran".
+        #: the bench/telemetry surface for "how much prefill work ran", and
+        #: for what crossed to the device and back: ``host_operands`` are
+        #: the host arrays handed to the engine's compiled programs beside
+        #: params, state and pool (one a prefill chunk, none a decode
+        #: step), ``device_reads`` the blocking device→host reads (one a
+        #: decode step, one a request's last chunk).
         self.counters = {"prefill_chunks": 0, "decode_steps": 0,
                          "pages_loaded": 0, "pages_saved": 0,
                          "prefix_hit_tokens": 0, "prefix_miss_tokens": 0,
-                         "probe_decodes": 0, "param_swaps": 0}
+                         "probe_decodes": 0, "param_swaps": 0,
+                         "host_operands": 0, "device_reads": 0}
         #: what the last engine call's routing did (models with routed
         #: experts; None for every other model and once read): numbers by
-        #: name, host values from a readback the call makes anyway or from
-        #: its own operands. The scheduler drains them into its
+        #: name, host values from the call's one readback or from its own
+        #: operands. The scheduler drains them into its
         #: SpanRecorder as ``serve_moe_<name>`` (docs/OBSERVABILITY.md
         #: section 7) and ``counters`` keeps their sums.
         self.moe_samples: Optional[dict] = None
-        #: what the last decode step left beside its tokens (the scalars of
-        #: ``_STEP_OUT_NAMES``: the sampler's path as an index into
-        #: ``_SAMPLER_PATHS``, the live cache positions, the active slots),
-        #: still on the device: :meth:`take_samples` reads and counts them,
-        #: so a step nobody observes transfers nothing. Once somebody has
-        #: asked, a step starts the scalars' copy to the host beside its
-        #: tokens', and the asker does not wait for a transfer of its own
-        #: (~0.5 ms a tick on a v5e's host).
+        #: what the last decode step's readback carried behind its tokens
+        #: (host ints, in the order of ``_STEP_OUT_NAMES``: the sampler's
+        #: path as an index into ``_SAMPLER_PATHS``, the live cache
+        #: positions, the active slots), until :meth:`take_samples` files
+        #: and counts them; they rode the tokens' transfer, so a traced run
+        #: transfers exactly what a measured run does.
         self._step_out = None
-        self._step_watched = False
         self.counters.update(
             {f"sampler_steps_{name}": 0 for name in _SAMPLER_PATHS})
         if base.experts is not None:
@@ -976,6 +1030,34 @@ class DecodeEngine:
             return NO_SPAN
         return trace_annotation(name, **ids)
 
+    def _chunk_operand(self, slot: int, prompt: Sequence[int], chunk_i: int,
+                       start: int, temperature: float = 0.0, top_k: int = 0,
+                       top_p: float = 1.0, eos: int = -1, pad: int = 0,
+                       seed: int = 0) -> np.ndarray:
+        """The one host operand of a prefill call (:data:`_PREFILL_HEAD`,
+        then chunk ``chunk_i`` of ``prompt[start:]`` right-padded with
+        zeros). Touches the chunk's own tokens only: the host's work a
+        chunk is proportional to the chunk, not to the prompt."""
+        c, head = self.prefill_chunk, len(_PREFILL_HEAD)
+        lo = start + chunk_i * c
+        seg = prompt[lo:lo + c]
+        ops = np.zeros((head + c,), np.int32)
+        ops[:head] = (
+            slot, start, len(seg), chunk_i == 0,
+            chunk_i == self.n_chunks(len(prompt) - start) - 1,
+            _f32_bits(temperature), top_k, _f32_bits(top_p), eos, pad,
+            _seed_word(seed))
+        ops[head:head + len(seg)] = seg
+        self.counters["host_operands"] += 1
+        return ops
+
+    def _read(self, out, n: int):
+        """THE blocking read of a program call: its packed vector
+        (:func:`_pack_out`, copy begun at dispatch) as ``(tokens [n],
+        done [n], tail)`` on the host."""
+        self.counters["device_reads"] += 1
+        return _split_out(np.asarray(out), n)
+
     def prefill_chunk_into(self, slot: int, prompt: Sequence[int],
                            chunk_i: int, *, start: int = 0,
                            temperature: float = 0.0,
@@ -991,7 +1073,6 @@ class DecodeEngine:
         in the slot's cache (prefix pages loaded via
         :meth:`load_prefix_page`) — chunks cover ``prompt[start:]`` only.
         Returns ``(first_token, done)`` on the last chunk, None before."""
-        prompt = list(int(t) for t in prompt)
         if not 1 <= len(prompt) <= self.max_len - 1:
             raise ValueError(
                 f"prompt length {len(prompt)} must be in [1, "
@@ -1004,9 +1085,7 @@ class DecodeEngine:
                 "sampled token comes from the last position's logits)")
         if not 0 <= slot < self.n_slots:
             raise ValueError(f"slot {slot} out of range [0, {self.n_slots})")
-        c = self.prefill_chunk
-        tail = prompt[start:]
-        n = self.n_chunks(len(tail))
+        n = self.n_chunks(len(prompt) - start)
         if not 0 <= chunk_i < n:
             raise ValueError(f"chunk {chunk_i} out of range [0, {n})")
         last = chunk_i == n - 1
@@ -1014,22 +1093,18 @@ class DecodeEngine:
                               chunk=chunk_i,
                               trace_id=-1 if trace_id is None else trace_id):
             with self._annotation("dtf.engine.prefill.dispatch"):
-                seg = tail[chunk_i * c:(chunk_i + 1) * c]
-                buf = np.zeros((c,), np.int32)
-                buf[:len(seg)] = seg
+                ops = self._chunk_operand(
+                    slot, prompt, chunk_i, start, temperature, top_k, top_p,
+                    -1 if eos_id is None else eos_id, pad_id, seed)
                 self._state, out = self._prefill_c(
-                    self._params, self._live(self._state), np.int32(slot),
-                    np.int32(start), buf, np.int32(len(seg)),
-                    np.bool_(chunk_i == 0), np.bool_(last),
-                    np.float32(temperature), np.int32(top_k),
-                    np.float32(top_p),
-                    np.int32(-1 if eos_id is None else eos_id),
-                    np.int32(pad_id),
-                    np.asarray(jax.random.PRNGKey(seed), np.uint32))
+                    self._params, self._live(self._state), ops)
+                if last:
+                    out.copy_to_host_async()
             self.counters["prefill_chunks"] += 1
             if self.cfg.experts is not None:
                 # pad columns choose no expert: the chunk's valid tokens
-                picks = len(seg) * self.cfg.experts.top_k
+                picks = (int(ops[_PREFILL_HEAD.index("n_valid")])
+                         * self.cfg.experts.top_k)
                 self.counters["moe_prefill_picks"] += picks
                 self.moe_samples = {"prefill_picks": picks}
             if self.spec_k:
@@ -1062,7 +1137,8 @@ class DecodeEngine:
             if not last:
                 return None
             with self._annotation("dtf.engine.prefill.readback"):
-                tok, done = int(out["token"]), bool(out["done"])
+                toks, dones, _ = self._read(out, 1)
+                tok, done = int(toks[0]), bool(dones[0])
             if self.spec_k:
                 self._spec_index[slot] = len(prompt)
                 self._spec_tok[slot] = tok
@@ -1076,21 +1152,10 @@ class DecodeEngine:
         already landed the stem in the draft cache). The sampled first
         token is discarded: the request's sampling stream belongs to the
         verifier alone."""
-        c = self.prefill_chunk
         with self._annotation("dtf.engine.prefill.dispatch"):
-            tail = list(int(t) for t in prompt)[start:]
-            n_d = self.n_chunks(len(tail))
-            seg = tail[chunk_i * c:(chunk_i + 1) * c]
-            buf = np.zeros((c,), np.int32)
-            buf[:len(seg)] = seg
             self._draft_state, _ = self._draft_prefill_c(
                 self._draft_params, self._live(self._draft_state),
-                np.int32(slot),
-                np.int32(start), buf, np.int32(len(seg)),
-                np.bool_(chunk_i == 0), np.bool_(chunk_i == n_d - 1),
-                np.float32(0.0), np.int32(0), np.float32(1.0),
-                np.int32(-1), np.int32(0),
-                np.asarray(jax.random.PRNGKey(0), np.uint32))
+                self._chunk_operand(slot, prompt, chunk_i, start))
         self.counters["draft_prefill_chunks"] += 1
         self._draft_chunks[slot] += 1
 
@@ -1133,33 +1198,28 @@ class DecodeEngine:
             with self._annotation("dtf.engine.decode.dispatch"):
                 self._state, out = self._decode_c(
                     self._params, self._live(self._state))
-            self._note_step(out)
+                out.copy_to_host_async()
+            self.counters["decode_steps"] += 1
             with self._annotation("dtf.engine.decode.readback"):
-                if "moe_stats" in out:
-                    self._note_moe(out)
-                return np.asarray(out["token"]), np.asarray(out["done"])
+                toks, done, tail = self._read(out, self.n_slots)
+            n = len(_STEP_OUT_NAMES)
+            path, cache_positions, active = (int(x) for x in tail[:n])
+            self._step_out = (path, cache_positions, active)
+            if self.cfg.experts is not None:
+                self._note_moe(cache_positions, active, tail[n:])
+            return toks, done
 
-    def _note_step(self, out) -> None:
-        """A dispatched decode (or verify) step: counted, and what it left
-        beside its tokens kept for :meth:`take_samples`."""
-        self.counters["decode_steps"] += 1
-        self._step_out = {name: out[name] for name in _STEP_OUT_NAMES
-                          if name in out}
-        if self._step_watched:
-            for scalar in self._step_out.values():
-                scalar.copy_to_host_async()
-
-    def _note_moe(self, out) -> None:
-        """A routed-expert model's decode step, from the readback decode
-        makes anyway: ``picks`` are the (token, expert) pairs one expert
+    def _note_moe(self, cache_positions: int, active: int,
+                  layer_stats: np.ndarray) -> None:
+        """A routed-expert model's decode step, from the step's one
+        readback: ``picks`` are the (token, expert) pairs one expert
         layer routed; the rest are means over the expert layers
         (``held_pairs`` / ``held_touched``: the pairs that landed on the
         experts the layer holds, and how many of those got a token).
         ``counters`` sums over layers (divide by ``decode_steps`` x layers
         for means)."""
-        stats = np.asarray(out["moe_stats"])
-        picks, cache_positions = int(stats[0]), int(stats[1])
-        touched, max_load, held_pairs, held_touched = stats[2:].reshape(
+        picks = active * self.cfg.experts.top_k
+        touched, max_load, held_pairs, held_touched = layer_stats.reshape(
             len(_MOE_LAYER_STATS), -1)
         self.counters["moe_decode_picks"] += picks * len(touched)
         self.counters["moe_experts_touched"] += int(touched.sum())
@@ -1185,24 +1245,20 @@ class DecodeEngine:
         read, each active slot's cached ones and its new one, as a share
         of active slots x ``max_len``: what ``ops/decode_attention.py``
         reads where it engages, of what the XLA spelling reads). The
-        step's scalars are read from the device HERE, the sampler's path
-        into ``counters["sampler_steps_*"]`` too: the scheduler asks only
-        where a telemetry object is attached, so a served step pays no
-        transfer for them (and from the second asking on, the copy started
-        with the step: ``_note_step``)."""
+        step's scalars came with its tokens (:meth:`decode`); the sampler's
+        path is counted into ``counters["sampler_steps_*"]`` HERE, where a
+        telemetry object asks."""
         samples = {f"moe_{name}": value
                    for name, value in (self.moe_samples or {}).items()}
         self.moe_samples = None
         step, self._step_out = self._step_out, None
         if step is not None:
-            self._step_watched = True
-            path = int(step["sampler_path"])
+            path, cache_positions, active = step
             self.counters[f"sampler_steps_{_SAMPLER_PATHS[path]}"] += 1
             samples["sampler_greedy"] = float(path == 0)
-            active = int(step.get("active_slots", 0))
             if active:
                 samples["decode_attn_live_pct"] = (
-                    100.0 * (int(step["cache_positions"]) + active)
+                    100.0 * (cache_positions + active)
                     / (active * self.max_len))
         return samples
 
@@ -1215,6 +1271,7 @@ class DecodeEngine:
         self._draft_state, props = self._draft_c(
             self._draft_params, self._live(self._draft_state),
             self._spec_tok, self._spec_index)
+        self.counters["host_operands"] += 2
         self.counters["draft_steps"] += 1
         return props
 
@@ -1233,13 +1290,18 @@ class DecodeEngine:
                             "decode this tick", e)
                 self.counters["draft_fallbacks"] += 1
                 props = np.zeros((self.n_slots, self.spec_k), np.int32)
+                self.counters["host_operands"] += 1
             self._state, out = self._decode_c(
                 self._params, self._live(self._state), props)
-        self._note_step(out)
+            out.copy_to_host_async()
+        self.counters["decode_steps"] += 1
         with self._annotation("dtf.engine.decode.readback"):
-            toks = np.asarray(out["tokens"])
-            dones = np.asarray(out["done"])
-            n_emit = np.asarray(out["n_emit"]).astype(np.int32)
+            toks, dones, tail = self._read(
+                out, self.n_slots * (self.spec_k + 1))
+        toks = toks.reshape(self.n_slots, -1)
+        dones = dones.reshape(self.n_slots, -1)
+        # the verify step reports its sampler's path alone
+        self._step_out, n_emit = (int(tail[0]), 0, 0), tail[1:]
         # host mirrors advance from values this readback carries anyway
         live = n_emit > 0
         self._spec_index = self._spec_index + n_emit
@@ -1396,6 +1458,7 @@ class DecodeEngine:
         self._state = self._page_load_c(
             self._live(self._state), self._pages, np.int32(slot),
             self._ids_buf(ids), np.int32(len(ids)))
+        self.counters["host_operands"] += 3
         self.counters["pages_loaded"] += len(ids)
         if self.spec_k and self._draft_self:
             # self-speculation: the draft cache is struct-identical, so
@@ -1405,6 +1468,7 @@ class DecodeEngine:
             self._draft_state = self._page_load_c(
                 self._live(self._draft_state), self._pages, np.int32(slot),
                 self._ids_buf(ids), np.int32(len(ids)))
+            self.counters["host_operands"] += 3
             self._draft_pending[slot] = handle.n_tokens
             self.counters["draft_pages_loaded"] += len(ids)
 
@@ -1439,6 +1503,7 @@ class DecodeEngine:
         self._pages = self._page_save_c(
             self._live(self._state), self._pages, np.int32(slot), buf,
             np.int32(have), np.int32(have + len(ids)))
+        self.counters["host_operands"] += 4
         self.counters["pages_saved"] += len(ids)
 
     def release_prefix(self, handle) -> None:
@@ -1504,12 +1569,11 @@ def engine_state_struct(cfg: gpt.GPTConfig, *, n_slots: int, max_len: int,
     return _state_struct(dec, n_slots, mesh)
 
 
-#: the prefill program's operand names (state + scalar tail) in
-#: positional order — the bundling key the analysis views use to turn a
-#: program_table entry's abstract_args into the runner's two-argument
-#: (params, ops) step shape.
-_PREFILL_OPS = ("state", "slot", "start", "chunk", "n_valid", "reset",
-                "is_last", "temp", "top_k", "top_p", "eos", "pad", "key")
+def _prefill_operand_struct(prefill_chunk: int) -> jax.ShapeDtypeStruct:
+    """The prefill programs' one host operand, abstractly
+    (:data:`_PREFILL_HEAD`, then the chunk's token columns)."""
+    return jax.ShapeDtypeStruct((len(_PREFILL_HEAD) + prefill_chunk,),
+                                jnp.int32)
 
 
 def program_table(cfg: gpt.GPTConfig, *, n_slots: int, max_len: int,
@@ -1552,27 +1616,19 @@ def program_table(cfg: gpt.GPTConfig, *, n_slots: int, max_len: int,
         "prefill": gpt.GPT(
             dataclasses.replace(base, chunked_prefill=True), mesh),
     }
-    s_i32 = jax.ShapeDtypeStruct((), jnp.int32)
-    s_f32 = jax.ShapeDtypeStruct((), jnp.float32)
-    s_bool = jax.ShapeDtypeStruct((), jnp.bool_)
-    chunk_abs = jax.ShapeDtypeStruct((prefill_chunk,), jnp.int32)
-    key_abs = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    #: prefill_into_slot's scalar operand tail, shared by both prefill
-    #: programs (and re-bundled by prefill_step_view/disagg_step_view).
-    prefill_tail = (s_i32, s_i32, chunk_abs, s_i32, s_bool, s_bool,
-                    s_f32, s_i32, s_f32, s_i32, s_i32, key_abs)
-    jit_kw, verify_kw = {}, {}
+    #: prefill_into_slot's one host operand, shared by both prefill
+    #: programs (and re-bundled by prefill_step_view/disagg_step_view)
+    ops_abs = _prefill_operand_struct(prefill_chunk)
+    jit_kw = {}
     rep = None
     if mesh is not None:
         # pin the OUTPUT state to the input layout: GSPMD would otherwise
         # pick its own output shardings, and the next call of the AOT
-        # executable would reject the resharded state
+        # executable would reject the resharded state; every program's
+        # second output is its one packed vector (_pack_out)
         rep = NamedSharding(mesh, P())
         state_sh = jax.tree.map(lambda s: s.sharding, abs_state)
-        jit_kw["out_shardings"] = (state_sh, {"token": rep, "done": rep})
-        verify_kw["out_shardings"] = (state_sh,
-                                      {"tokens": rep, "done": rep,
-                                       "n_emit": rep, "sampler_path": rep})
+        jit_kw["out_shardings"] = (state_sh, rep)
     donate_state = {"donate": True, "donate_args": (1,)}
     programs = {}
     if spec_k:
@@ -1580,23 +1636,18 @@ def program_table(cfg: gpt.GPTConfig, *, n_slots: int, max_len: int,
                                          sharding=rep)
         executor.program(
             "decode", _build_verify_fn(models["decode"], spec_k),
-            counts=counts, jit_kw=verify_kw, **donate_state,
+            counts=counts, jit_kw=jit_kw, **donate_state,
             abstract_args=(abs_params, abs_state, props_abs),
             table=programs)
     else:
-        decode_kw = dict(jit_kw)
-        if mesh is not None:
-            decode_kw["out_shardings"] = (state_sh, {
-                name: rep for name in ("token", "done") + _STEP_OUT_NAMES
-                + _moe_out_names(cfg)})
         executor.program(
             "decode", _build_decode_fn(models["decode"]),
-            counts=counts, jit_kw=decode_kw, **donate_state,
+            counts=counts, jit_kw=jit_kw, **donate_state,
             abstract_args=(abs_params, abs_state), table=programs)
     executor.program(
         "prefill", _build_prefill_fn(models["prefill"]),
         counts=counts, jit_kw=jit_kw, **donate_state,
-        abstract_args=(abs_params, abs_state) + prefill_tail,
+        abstract_args=(abs_params, abs_state, ops_abs),
         table=programs)
     if spec_k:
         dbase = dataclasses.replace(draft_cfg, decode_len=max_len,
@@ -1611,21 +1662,19 @@ def program_table(cfg: gpt.GPTConfig, *, n_slots: int, max_len: int,
         abs_dstate = abs_trees.get("draft_state")
         if abs_dstate is None:
             abs_dstate = _state_struct(ddec_cfg, n_slots, mesh)
-        dp_kw, da_kw = {}, {}
+        draft_kw = {}
         if mesh is not None:
             dstate_sh = jax.tree.map(lambda s: s.sharding, abs_dstate)
-            dp_kw["out_shardings"] = (dstate_sh,
-                                      {"token": rep, "done": rep})
-            da_kw["out_shardings"] = (dstate_sh, rep)
+            draft_kw["out_shardings"] = (dstate_sh, rep)
         vec_abs = jax.ShapeDtypeStruct((n_slots,), jnp.int32, sharding=rep)
         executor.program(
             "draft_prefill", _build_prefill_fn(models["draft_prefill"]),
-            counts=counts, jit_kw=dp_kw, **donate_state,
-            abstract_args=(abs_dparams, abs_dstate) + prefill_tail,
+            counts=counts, jit_kw=draft_kw, **donate_state,
+            abstract_args=(abs_dparams, abs_dstate, ops_abs),
             table=programs)
         executor.program(
             "draft", _build_draft_fn(models["draft"], spec_k),
-            counts=counts, jit_kw=da_kw, **donate_state,
+            counts=counts, jit_kw=draft_kw, **donate_state,
             abstract_args=(abs_dparams, abs_dstate, vec_abs, vec_abs),
             table=programs)
     return programs, models
@@ -1712,32 +1761,29 @@ def prefill_step_view(cfg: gpt.GPTConfig, *, n_slots: int, max_len: int,
     ``(jitted_fn, abstract_params, abstract_operand_bundle)`` — the same
     ``prefill_into_slot`` body ``DecodeEngine`` AOT-compiles (slot slice →
     chunked-prefill model → slot write-back → first-token sample), with
-    the scalar operands bundled into one pytree so the analysis runner's
-    two-argument step shape fits. The comms-budget fence this enables
-    covers the known sharded-prefill resharding cost (engine docstring:
-    GSPMD respells the traced-index slot slice as a resharding of the
-    touched cache leaves) — previously documented, now pinned."""
+    the state and the call's one host operand bundled into one pytree so
+    the analysis runner's two-argument step shape fits. The comms-budget
+    fence this enables covers the known sharded-prefill resharding cost
+    (engine docstring: GSPMD respells the traced-index slot slice as a
+    resharding of the touched cache leaves) — previously documented, now
+    pinned."""
     programs, _ = program_table(cfg, n_slots=n_slots, max_len=max_len,
                                 mesh=mesh, prefill_chunk=prefill_chunk)
     prog = programs["prefill"]
-    abs_params, abs_state = prog.abstract_args[:2]
-    ops = dict(zip(_PREFILL_OPS, prog.abstract_args[1:]))
+    abs_params, abs_state, ops_abs = prog.abstract_args
+    ops = {"state": abs_state, "ops": ops_abs}
 
     def step(params, ops):
-        return prog.body(
-            params, ops["state"], ops["slot"], ops["start"], ops["chunk"],
-            ops["n_valid"], ops["reset"], ops["is_last"], ops["temp"],
-            ops["top_k"], ops["top_p"], ops["eos"], ops["pad"], ops["key"])
+        return prog.body(params, ops["state"], ops["ops"])
 
     jit_kw = {}
     if mesh is not None:
         # the engine pins the output state to the input layout (its AOT
         # executables reject resharded state) — the fenced graph must be
         # the SAME pinned program, not GSPMD's free choice
-        rep = NamedSharding(mesh, P())
         jit_kw["out_shardings"] = (
             jax.tree.map(lambda s: s.sharding, abs_state),
-            {"token": rep, "done": rep})
+            NamedSharding(mesh, P()))
     return (executor.program("prefill_view", step, jit_kw=jit_kw,
                              abstract_args=(abs_params, ops)),
             abs_params, ops)
@@ -1831,8 +1877,7 @@ def spec_step_view(cfg: gpt.GPTConfig, draft_cfg: gpt.GPTConfig, *,
         jit_kw["out_shardings"] = {
             "state": jax.tree.map(lambda s: s.sharding, abs_state),
             "draft_state": jax.tree.map(lambda s: s.sharding, abs_dstate),
-            "out": {"tokens": rep, "done": rep, "n_emit": rep,
-                    "sampler_path": rep}}
+            "out": rep}
     return (executor.program("spec_view", step, jit_kw=jit_kw,
                              abstract_args=(bundle, ops)),
             bundle, ops)
@@ -1870,11 +1915,8 @@ def disagg_step_view(cfg: gpt.GPTConfig, *, n_slots: int, max_len: int,
     save_fn = pages["save"].body
 
     def step(bundle, ops):
-        state, out = prefill_fn(
-            bundle["params"], bundle["state"], ops["slot"], ops["start"],
-            ops["chunk"], ops["n_valid"], ops["reset"], ops["is_last"],
-            ops["temp"], ops["top_k"], ops["top_p"], ops["eos"],
-            ops["pad"], ops["key"])
+        state, out = prefill_fn(bundle["params"], bundle["state"],
+                                ops["prefill"])
         pool = save_fn(state, bundle["pool"], ops["slot"], ops["ids"],
                        ops["lo"], ops["hi"])
         return {"state": state, "pool": pool, "out": out}
@@ -1885,19 +1927,10 @@ def disagg_step_view(cfg: gpt.GPTConfig, *, n_slots: int, max_len: int,
         jit_kw["out_shardings"] = {
             "state": jax.tree.map(lambda s: s.sharding, state_abs),
             "pool": jax.tree.map(lambda s: s.sharding, pool_abs),
-            "out": {"token": rep, "done": rep}}
+            "out": rep}
     s_i32 = jax.ShapeDtypeStruct((), jnp.int32)
     ops = {
-        "slot": s_i32, "start": s_i32,
-        "chunk": jax.ShapeDtypeStruct((prefill_chunk,), jnp.int32),
-        "n_valid": s_i32,
-        "reset": jax.ShapeDtypeStruct((), jnp.bool_),
-        "is_last": jax.ShapeDtypeStruct((), jnp.bool_),
-        "temp": jax.ShapeDtypeStruct((), jnp.float32),
-        "top_k": s_i32,
-        "top_p": jax.ShapeDtypeStruct((), jnp.float32),
-        "eos": s_i32, "pad": s_i32,
-        "key": jax.ShapeDtypeStruct((2,), jnp.uint32),
+        "prefill": _prefill_operand_struct(prefill_chunk), "slot": s_i32,
         "ids": jax.ShapeDtypeStruct((max_len // kv_page_size,), jnp.int32),
         "lo": s_i32, "hi": s_i32,
     }

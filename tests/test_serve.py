@@ -350,11 +350,8 @@ def test_refused_request_leaves_the_state_usable(params):
     # an operand the compiled program rejects (wrong chunk width) is
     # rejected before the state is consumed
     with pytest.raises((TypeError, ValueError)):
-        eng._prefill_c(eng._params, eng._state, np.int32(0), np.int32(0),
-                       np.zeros((3,), np.int32), np.int32(3), np.bool_(True),
-                       np.bool_(True), np.float32(0), np.int32(0),
-                       np.float32(1), np.int32(-1), np.int32(0),
-                       np.zeros((2,), np.uint32))
+        eng._prefill_c(eng._params, eng._state,
+                       eng._chunk_operand(0, [1, 2, 3], 0, 0)[:-1])
     assert not any(x.is_deleted() for x in _cache_leaves(eng))
     eng.decode()
 
@@ -530,6 +527,121 @@ def test_sampler_path_counters(params):
                      "unfiltered": 0, "filtered": 0}
 
 
+# ---- what crosses to the device and back in one engine call ---------------
+
+@pytest.mark.parametrize("routed", [False, True], ids=["dense", "routed"])
+def test_transfer_fence(params, routed):
+    """One host operand into a prefill chunk and none into a decode step;
+    one blocking read a decode step, one for a request's last chunk and
+    none for a chunk before it — with routed experts' counters riding the
+    same read. The scheduler's stats carry both counts."""
+    cfg = CFG
+    if routed:
+        from dtf_tpu.parallel import moe
+
+        cfg = dataclasses.replace(CFG, experts=moe.ExpertsConfig(
+            num_experts=4, top_k=2, d_ff=16))
+        _, init_fn = gpt.make_init(cfg, None, seq_len=8)
+        params = init_fn(jax.random.PRNGKey(0))["params"]
+    eng = DecodeEngine(cfg, params, n_slots=2, max_len=MAX_LEN,
+                       prefill_chunk=4)
+    eng.prefill(1, [1, 2, 3])
+    eng.decode()                                   # warmed
+
+    def moved(call):
+        before = dict(eng.counters)
+        call()
+        return (eng.counters["host_operands"] - before["host_operands"],
+                eng.counters["device_reads"] - before["device_reads"])
+
+    prompt = list(range(1, 11))                    # three chunks
+    for chunk_i, reads in ((0, 0), (1, 0), (2, 1)):
+        operands, got = moved(
+            lambda: eng.prefill_chunk_into(0, prompt, chunk_i))
+        assert (operands, got) == (1, reads), chunk_i
+    assert moved(eng.decode) == (0, 1)
+    assert moved(eng.probe) == (0, 1)
+    eng.take_samples()
+    assert moved(eng.take_samples) == (0, 0)       # telemetry reads nothing
+    if routed:
+        assert eng.counters["moe_decode_picks"] > 0
+    stats = Scheduler(eng, None).stats()
+    assert stats["serve_host_operands"] == eng.counters["host_operands"]
+    assert stats["serve_device_reads"] == eng.counters["device_reads"]
+
+
+@pytest.mark.parametrize("seed", [0, 2**31 - 1, 2**31, 2**32, -1])
+def test_prefill_key_is_prngkey_of_the_seed(engine, seed):
+    """The program makes the request's key from one int32 of the operand;
+    it is what ``jax.random.PRNGKey(seed)`` hands the host, which without
+    64-bit types keeps the seed's low 32 bits: 2**32 seeds 0's stream and
+    -1 seeds 2**32 - 1's. A first chunk that is not the request's last
+    leaves the key itself in the slot's rng row."""
+    engine.prefill_chunk_into(3, list(range(1, 9)), 0, seed=seed)
+    want = np.asarray(jax.random.PRNGKey(seed), np.uint32)
+    assert want.tolist() == [0, seed % 2**32]
+    assert np.asarray(engine._state["rng"][3]).tolist() == want.tolist()
+
+
+def test_prefill_refuses_the_seeds_prngkey_refuses(engine):
+    for seed, error in ((2**63, OverflowError), (1.5, TypeError)):
+        with pytest.raises(error):
+            jax.random.PRNGKey(seed)
+        with pytest.raises(error):
+            engine.prefill_chunk_into(3, [1, 2, 3], 0, seed=seed)
+    assert not any(x.is_deleted() for x in _cache_leaves(engine))
+
+
+def test_sampled_request_streams_offline_tokens(params, engine):
+    req = dict(prompt=[5, 6, 7, 8, 9, 10, 11], max_new=12, temperature=0.8,
+               top_k=40, top_p=0.9, seed=2**31 + 17)
+    client = ServeClient(engine)
+    rid = client.submit(**req)
+    client.drain()
+    assert client.result(rid) == _offline(params, req)
+
+
+@pytest.mark.parametrize("value", [0.7, 0.95, 1e-3])
+def test_float_operands_arrive_bit_for_bit(engine, value):
+    """Temperature and top_p ride the int32 operand as their float32 bit
+    patterns: values a rounder type would move land in the state as
+    ``np.float32`` makes them."""
+    engine.prefill_chunk_into(2, [1, 2, 3], 0, temperature=value,
+                              top_p=value)
+    want = np.float32(value).tobytes()
+    assert np.asarray(engine._state["temp"][2]).tobytes() == want
+    assert np.asarray(engine._state["top_p"][2]).tobytes() == want
+
+
+class _CountingPrompt:
+    """A prompt that counts the tokens read from it."""
+
+    def __init__(self, tokens):
+        self.tokens, self.reads = list(tokens), 0
+
+    def __len__(self):
+        return len(self.tokens)
+
+    def __getitem__(self, i):
+        got = self.tokens[i]
+        self.reads += len(got) if isinstance(i, slice) else 1
+        return got
+
+
+@pytest.mark.parametrize("length", [7, 40])
+def test_prefill_chunk_reads_only_its_chunk(engine, length):
+    """A chunk's host work is the chunk's: whatever the prompt's length,
+    a call touches at most ``prefill_chunk`` of its tokens — and serves
+    what the list would have."""
+    prompt = _CountingPrompt(range(1, length + 1))
+    for chunk_i in range(engine.n_chunks(length)):
+        before = prompt.reads
+        out = engine.prefill_chunk_into(1, prompt, chunk_i)
+        assert prompt.reads - before <= engine.prefill_chunk
+    assert prompt.reads == length
+    assert out == engine.prefill(0, prompt.tokens)
+
+
 # ---- slot-decode attention as one kernel (ops/decode_attention.py) ---------
 
 KERNEL_LEN = 128    # whole 128-position tiles: what the kernel asks of a cache
@@ -636,4 +748,4 @@ def test_decode_attn_live_pct_counter(params):
     reads = [3 + i + 1 for i in range(steps)]
     assert roll["total_s"] == pytest.approx(
         sum(100.0 * r / MAX_LEN for r in reads), rel=1e-5)
-    assert served(None)._step_out is not None      # left on the device
+    assert served(None)._step_out is not None      # left for an asker
