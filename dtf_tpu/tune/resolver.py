@@ -11,8 +11,10 @@ argument is left at its 0 sentinel (``flash_attention``,
 2. the nearest banked winner from the cache store
    (``KERNEL_TUNE.local.json`` shadowing the committed
    ``KERNEL_TUNE.json`` — see :mod:`dtf_tpu.tune.cache`);
-3. the built-in defaults (the round-5 sweep picks, same values the
-   kernels carried as literals before the tuner existed).
+3. the built-in defaults. Flash attention has none here: a plan with
+   ``block_q == 0`` (nothing banked) or ``measured=False`` leaves the
+   blocks to ``ops.flash_attention.flash_blocks``, the shape rule read off
+   the on-chip sweep (PERF.md §6, PR 35).
 
 Every resolve is process-cached (``lru_cache``): kernels call this
 inside jit traces and a cache-file re-read per call would be absurd.
@@ -32,13 +34,10 @@ from typing import Optional
 
 from dtf_tpu.tune import cache as _cache
 
-# Built-in fallbacks — the round-5 on-chip sweep picks (see
-# ops/flash_attention.py and ops/fused_ce.py for the measurement
-# provenance). The committed KERNEL_TUNE.json carries the same values
-# WITH their measured rows; these literals only fire when both cache
-# files are missing or stale.
-FALLBACK_BLOCK_Q = 512
-FALLBACK_BLOCK_K = 1024
+# Built-in fallbacks for the fused-CE tile (see ops/fused_ce.py for the
+# measurement provenance); these literals only fire when both cache files
+# are missing or stale. Flash attention's unmeasured blocks come from its
+# own shape rule (``ops.flash_attention.flash_blocks``), not from here.
 FALLBACK_BLOCK_N = 512
 FALLBACK_BLOCK_V = 1024
 FALLBACK_SOURCE = "builtin-default (no kernel-tune cache entry)"
@@ -46,6 +45,8 @@ FALLBACK_SOURCE = "builtin-default (no kernel-tune cache entry)"
 
 @dataclasses.dataclass(frozen=True)
 class FlashPlan:
+    #: 0 = nothing banked: ``flash_attention``'s shape rule decides. The
+    #: kernel also takes the rule over any plan with ``measured=False``.
     block_q: int
     block_k: int
     block_h: int
@@ -128,9 +129,7 @@ def flash_plan(*, seq: int, heads: int, head_dim: int, dtype: str,
     if bh < 1 or (heads and heads % bh):
         bh = 1   # a banked fold from a different head count must not
         # turn into a wrapper ValueError — clamp to the proven kernel
-    return FlashPlan(block_q=bq or FALLBACK_BLOCK_Q,
-                     block_k=bk or FALLBACK_BLOCK_K,
-                     block_h=bh or 1,
+    return FlashPlan(block_q=bq, block_k=bk, block_h=bh or 1,
                      block_q_bwd=bqb, block_k_bwd=bkb,
                      source=src, measured=measured)
 

@@ -83,17 +83,19 @@ def _compiles_to_kernel(fn, *args) -> str:
     return text
 
 
-def _flash(window, **shape):
-    """flash_attention with the blocks the CHIP would resolve: left at 0
-    they follow ``jax.default_backend()``, which is the CPU here."""
-    plan = resolver.flash_plan(dtype="bfloat16", causal=True, window=window,
-                               n_devices=1, backend="tpu", **shape)
+def _flash(window, *, causal=True, **shape):
+    """flash_attention with the blocks the CHIP would resolve. Nothing is
+    banked for flash on a TPU (asserted: a measured entry would follow
+    ``jax.default_backend()``, the CPU's here), so block arguments left at
+    0 take ``flash_blocks``, the shape rule, which asks no backend."""
+    plan = resolver.flash_plan(dtype="bfloat16", causal=causal,
+                               window=window, n_devices=1, backend="tpu",
+                               **shape)
+    assert not plan.measured, plan
 
-    def attn(q, k, v):
-        return fa.flash_attention(
-            q, k, v, causal=True, window=window, block_q=plan.block_q,
-            block_k=plan.block_k, block_q_bwd=plan.block_q_bwd,
-            block_k_bwd=plan.block_k_bwd)
+    def attn(q, k, v, kv_mask=None):
+        return fa.flash_attention(q, k, v, causal=causal, window=window,
+                                  kv_mask=kv_mask)
 
     return attn
 
@@ -126,6 +128,28 @@ def test_flash_backward_compiles(on_chip, name, window):
                         argnums=(0, 1, 2))(q, k, v)
 
     _compiles_to_kernel(grads, q, q, q)
+
+
+@pytest.mark.parametrize("t_q,t_k,window", [
+    (1024, 2048, 0),      # dkv: key block 1 lies past every query
+    (3072, 1024, 512),    # fwd, dq: query block 2's window past every key
+])
+def test_flash_dead_block_in_a_one_step_row_compiles(on_chip, t_q, t_k,
+                                                     window):
+    """Under the shape rule these go in blocks of 1024 whose rows are one
+    grid step (no scratch), and one block is dead: it stores the empty
+    row's zeros itself, a third ``pl.when`` variant — which Mosaic has to
+    take as well as interpret mode does."""
+    b, h, d = 1, 4, 64
+    q = on_chip((b, h, t_q, d), jnp.bfloat16)
+    k = on_chip((b, h, t_k, d), jnp.bfloat16)
+    attn = _flash(window, seq=t_q, heads=h, head_dim=d)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(attn(*a).astype(jnp.float32)),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    _compiles_to_kernel(grads, q, k, k)
 
 
 def _fused_ce_args(on_chip, d_model):
@@ -200,6 +224,51 @@ def test_kernels_carry_their_names(on_chip, case):
     for name in names:
         assert re.search(rf"^\s*%\w*{name}\w*(\.\d+)? = .*tpu_custom_call",
                          text, re.M), name
+
+
+# (batch, heads, seq, head_dim, causal, key mask): the attention of the two
+# one-chip train cells (BERT's per micro-batch of 32) and the sweep's long one
+CELL_ATTENTION = {
+    "gpt2m-train-b8s1024": (8, 16, 1024, 64, True, False),
+    "bert-base-train-b256s512": (32, 12, 512, 64, False, True),
+    "long_s8192_d128": (1, 16, 8192, 128, True, False),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_ATTENTION))
+def test_cells_flash_kernels_are_what_the_readers_match(on_chip, cell):
+    """The three kernels at a cell's attention shape, with the shape
+    rule's blocks, compile for the described v5e — and carry what the
+    benchmark's readers find them by: the names ``dtf_flash_fwd``,
+    ``dtf_flash_dq``, ``dtf_flash_dkv`` (``flash_fwd_roofline`` /
+    ``flash_bwd_roofline``) and a FIRST operand
+    ``bf16[batch*heads, seq, d_head]`` (``flash_attn_roofline``,
+    ``benchmarks/readers/flash_roofline.py``). A kernel renamed, or handed
+    its queries in another layout, would read as a silent roofline on the
+    chip; here it fails on the CPU."""
+    b, h, t, d, causal, masked = CELL_ATTENTION[cell]
+    blocks = fa.flash_blocks(t, t, d, causal=causal)
+    for kernel in ("fwd", "dq", "dkv"):
+        assert (fa.vmem_bytes(kernel, getattr(blocks, kernel), d)
+                <= fa._VMEM_LIMIT), (kernel, blocks)
+    attn = _flash(0, causal=causal, seq=t, heads=h, head_dim=d)
+    q = on_chip((b, h, t, d), jnp.bfloat16)
+    args = (q, q, q) + ((on_chip((b, t), jnp.bool_),) if masked else ())
+
+    def grads(q, k, v, *mask):
+        return jax.grad(
+            lambda *a: jnp.sum(attn(*a, *mask).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiles_to_kernel(grads, *args)
+    first_operand = rf"bf16\[{b * h},{t},{d}\]"
+    for name in ("dtf_flash_fwd", "dtf_flash_dq", "dtf_flash_dkv"):
+        lines = re.findall(
+            rf"^\s*%\w*{name}\w*(?:\.\d+)? = .*tpu_custom_call.*$", text, re.M)
+        assert len(lines) == 1, (name, len(lines))
+        assert re.search(
+            rf"operand_layout_constraints=\{{{first_operand}\{{", lines[0]
+        ), (name, lines[0][:300])
 
 
 # ---- the serve engine's programs: the KV cache is updated in place ---------

@@ -120,8 +120,8 @@ def test_corrupt_cache_falls_back(tune_env):
     plan = resolver.flash_plan(seq=96, heads=4, head_dim=16,
                                dtype="float32", causal=True, window=0,
                                n_devices=8, backend="cpu")
-    assert (plan.block_q, plan.block_k) == (resolver.FALLBACK_BLOCK_Q,
-                                            resolver.FALLBACK_BLOCK_K)
+    # nothing banked: 0s, and flash_attention's shape rule decides
+    assert (plan.block_q, plan.block_k) == (0, 0)
     assert plan.block_q_bwd == 0 and not plan.measured
 
 
@@ -136,7 +136,7 @@ def test_stale_schema_ignored(tune_env):
     plan = resolver.flash_plan(seq=96, heads=4, head_dim=16,
                                dtype="float32", causal=True, window=0,
                                n_devices=8, backend="cpu")
-    assert plan.block_q == resolver.FALLBACK_BLOCK_Q
+    assert plan.block_q == 0 and not plan.measured
 
 
 # ------------------------------------------------------- winner selection
@@ -170,8 +170,9 @@ def test_seeded_golden_matches_banked_artifacts():
     """The committed KERNEL_TUNE.json must stay derivable from the
     committed sweep artifacts: monolithic where logits fit, token-chunk
     where they don't. No flash block sweep has been taken on the present
-    chip and JAX, so no flash entry may claim to be measured — the
-    kernels fall back to their built-in 512x1024 blocks."""
+    chip and JAX, so no flash entry may claim to be measured — and an
+    unmeasured one decides nothing: the kernels take their blocks from
+    the shape rule (``flash_attention.flash_blocks``)."""
     flash = [e for e in search.seed_entries(ROOT)
              if e.kind.startswith("flash")]
     assert flash and not any(e.measured for e in flash)
@@ -180,6 +181,10 @@ def test_seeded_golden_matches_banked_artifacts():
                                dtype="bfloat16", causal=True, window=0,
                                n_devices=1, backend="tpu")
     assert (plan.block_q, plan.block_k, plan.measured) == (512, 1024, False)
+    from dtf_tpu.ops import flash_attention as fa
+    shape = dict(causal=True, itemsize=2)
+    assert (fa.resolve_blocks(1024, 1024, 64, plan=plan, **shape)
+            == fa.flash_blocks(1024, 1024, 64, causal=True))
     lm = [e for e in search.seed_entries(ROOT) if e.kind == "lm_loss"]
     by_fits = {bool(e.key["fits"]): e for e in lm}
     assert by_fits[True].winner["path"] == "monolithic"
@@ -261,8 +266,8 @@ def test_tuned_blocks_bitwise_match_default_blocks(tune_env, case):
     data, fwd + grads, per masking case: (a) BITWISE — resolving
     through the tuner is identical to hand-pinning the same blocks (the
     resolver injects values, nothing else); (b) numeric — the tuned
-    blocks match the old hard-coded defaults to the same tolerance the
-    kernel's own cross-block tests use (different block partitions
+    blocks match the shape rule's (what an unmeasured shape runs) to the
+    tolerance the kernel's own cross-block tests use (block partitions
     legitimately reorder the online-softmax summation, so cross-BLOCK
     bitwise equality is not a thing even on integer inputs)."""
     import jax
@@ -306,8 +311,10 @@ def test_tuned_blocks_bitwise_match_default_blocks(tune_env, case):
     assert (np.asarray(out_t) == np.asarray(out_p)).all()
     for gt, gp in zip(g_t, g_p):
         assert (np.asarray(gt) == np.asarray(gp)).all()
-    out_d, g_d = run(block_q=fa.DEFAULT_BLOCK_Q,
-                     block_k=fa.DEFAULT_BLOCK_K)
+    # what an unmeasured shape gets: the kernel's own shape rule
+    rule = fa.flash_blocks(q.shape[2], k.shape[2], q.shape[3],
+                           causal=case != "masked").fwd
+    out_d, g_d = run(block_q=rule[0], block_k=rule[1])
     np.testing.assert_allclose(np.asarray(out_t), np.asarray(out_d),
                                atol=2e-5, rtol=2e-5)
     for gt, gd in zip(g_t, g_d):
