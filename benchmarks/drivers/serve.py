@@ -44,7 +44,7 @@ def check_tokens(reference_logits, params, sample, pad_to: int) -> dict:
     request: holds prefill and decode-through-the-cache to the full
     forward pass."""
     forward = jax.jit(reference_logits)
-    worst, exact, total, bad = 0.0, 0, 0, 0
+    shorts, exact, total, bad = [0.0], 0, 0, 0
     for prompt, emitted in sample:
         ids = np.zeros((1, pad_to), np.int32)
         seq = list(prompt) + list(emitted)
@@ -53,19 +53,21 @@ def check_tokens(reference_logits, params, sample, pad_to: int) -> dict:
         for j, tok in enumerate(emitted):
             row = logits[len(prompt) - 1 + j]
             short = float(row.max() - row[tok])
-            worst = max(worst, short)
+            shorts.append(short)
             exact += int(short == 0.0)
             bad += int(not short <= TOKEN_LOGIT_TOL)
             total += 1
     return {"ok": total > 0 and bad == 0, "requests": len(sample),
             "tokens": total, "argmax_identical": exact,
-            "outside_tolerance": bad, "worst_logit_shortfall": worst,
+            "outside_tolerance": bad,
+            # NaN where a family's limit failed: it hands over NaN logits
+            "worst_logit_shortfall": float(np.max(shorts)),
             "tolerance": TOKEN_LOGIT_TOL}
 
 
 def run(job) -> dict:
     traffic = job.traffic
-    laps = [("start", time.perf_counter())]   # set-up's phases, for a note
+    laps = [("start", job.t_setup)]   # set-up's phases, from run.py's last
     fam = importlib.import_module(
         f"benchmarks.families.{job.config['family']}").build_serve(job.config)
     # weights on the device from the seed, in one jitted call
@@ -112,11 +114,12 @@ def run(job) -> dict:
     while not (len(loop.ended) >= warm
                and loop.next_request >= n_slots + warm):
         loop.step()
-    laps.append(("warm_s", time.perf_counter()))
 
     # ---- the window: closes at the first tick that ends past --seconds
     job.memory.sample()
+    crossings0 = dict(engine.counters)
     t0 = time.perf_counter()
+    laps.append(("warm_s", t0))             # set-up ends where it opens
     ticks0 = len(loop.ticks)
     span_names = ("serve_decode", "serve_prefill_chunk", "serve_page_load",
                   "serve_page_save")
@@ -126,6 +129,7 @@ def run(job) -> dict:
         loop.step()
         occupancy.append(sched.occupancy)
     t1 = loop.ticks[-1][0]
+    crossings1 = dict(engine.counters)
     job.memory.sample()
     window_ticks = loop.ticks[ticks0:]
     spans = tel.spans.rollup() if tel else {}
@@ -155,6 +159,10 @@ def run(job) -> dict:
     # the engine's two cache copies leave the reference no room: drop them
     del loop, submit, poll, traced_tick, sched, engine
     check = check_tokens(fam.reference_logits, params, sample, max_len)
+    jax.effects_barrier()          # a family's readings come by callback
+    readings = fam.readings() if hasattr(fam, "readings") else {}
+    # a family's limit over the whole sample, where it has one
+    verdict = fam.verdict() if hasattr(fam, "verdict") else True
 
     tick_wall = sum(d for _, d in window_ticks)
     values = {
@@ -165,12 +173,17 @@ def run(job) -> dict:
         / (t1 - t0),
         "slot_occupancy_pct": 100.0 * sum(occupancy) / len(occupancy),
         "ticks": len(window_ticks),
+        # what an engine call hands the device and reads back, a tick
+        **{f"{key}_per_tick": (crossings1[key] - crossings0[key])
+           / len(window_ticks) for key in ("host_operands", "device_reads")},
+        **{f"setup_{name}": t - t_before
+           for (_, t_before), (name, t) in zip(laps, laps[1:])},
     }
     if engine_s is not None:
         values["tick_host_ms_mean"] = (
             1000.0 * (tick_wall - engine_s) / len(window_ticks))
     return {
-        "correct": bool(check["ok"]) and len(done) == len(ended)
+        "correct": bool(check["ok"]) and verdict and len(done) == len(ended)
         and len(done) > 0,
         "attempted": len(ended),
         "failed": len(ended) - len(done),
@@ -179,10 +192,14 @@ def run(job) -> dict:
         "spans": spans,
         "trace": trace,
         "values": values,
+        # each number `correct` compares, as (reading, limit): a family's
+        # own, the worst of its requests' `# check` notes, then the driver's
+        "limits": {**readings,
+                   "worst_logit_shortfall": (check["worst_logit_shortfall"],
+                                             TOKEN_LOGIT_TOL),
+                   "tokens_outside_tolerance": (check["outside_tolerance"], 0),
+                   "requests_failed": (len(ended) - len(done), 0)},
         "notes": {"check": check,
-                  "setup_phases": {name: round(t - t_before, 3)
-                                   for (_, t_before), (name, t)
-                                   in zip(laps, laps[1:])},
                   "window": {
                       "requests_ended": len(ended),
                       # max beside the percentiles: a run that lost seconds

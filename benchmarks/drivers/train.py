@@ -90,7 +90,7 @@ class WindowHook(Hook):
 
 def run(job) -> dict:
     config, traffic = job.config, job.traffic
-    laps = [("start", time.perf_counter())]   # set-up's phases, for a note
+    laps = [("start", job.t_setup)]   # set-up's phases, from run.py's last
     batch, seq_len = traffic["batch"], traffic["seq_len"]
     accum = traffic["grad_accum"]
     mesh = make_mesh(MeshConfig(**traffic.get("mesh", {})),
@@ -124,13 +124,14 @@ def run(job) -> dict:
     for i in range(traffic["warmup_steps"]):
         state, metrics = step(state, shard_batch(data.batch(i), mesh))
     jax.block_until_ready(state)
-    laps.append(("warmup_s", time.perf_counter()))
 
     hook = WindowHook(
         seconds=job.seconds, fence_every=traffic["fence_every"], tel=tel,
         trace_steps=traffic["trace_steps"] if job.trace else 0,
         memory=job.memory)
     Trainer(step, mesh, hooks=[hook], telemetry=tel).fit(state, iter(data))
+    # warm-up runs on to the window's first fence: set-up ends there
+    laps.append(("warmup_s", hook.fences[0][0]))
 
     # seconds a step between neighbouring fences: one long interval is a
     # stall, all of them long is a slow device
@@ -150,18 +151,24 @@ def run(job) -> dict:
                                    for t, n in hook.fences]},
         "spans": hook.spans,
         "trace": hook.trace,
+        # each number `correct` compares, as (reading, limit)
+        "limits": {"loss_abs_err": (check["loss_abs_err"],
+                                    check["loss_abs_tol"]),
+                   "logit_rel_rms_err": (check["logit_rel_rms_err"],
+                                         check["logit_rel_rms_tol"]),
+                   "nonfinite_losses": (bad, 0),
+                   "train_step_traces": (traces, 1)},
         "values": {
             "n_params": n_params, "n_matmul_params": n_matmul,
             "layers": fam.layers, "width": fam.width,
             "seq_len": seq_len, "grad_accum": accum,
             "device_micro_batch": batch // accum // mesh.shape["data"],
             "attention": fam.attention,
+            **{f"setup_{name}": t - t_before
+               for (_, t_before), (name, t) in zip(laps, laps[1:])},
         },
         "notes": {"check": check, "loss_path": fam.loss_path,
                   "params": {"all": n_params, "in_matmuls": n_matmul},
-                  "setup_phases": {name: round(t - t_before, 3)
-                                   for (_, t_before), (name, t)
-                                   in zip(laps, laps[1:])},
                   "train_step_traces": traces,
                   "step_ms_between_fences": {
                       "min": round(1e3 * min(per_step), 3),

@@ -23,6 +23,7 @@ T_PROCESS = time.perf_counter()     # set-up is counted from here
 import argparse                     # noqa: E402
 import importlib                    # noqa: E402
 import json                         # noqa: E402
+import math                         # noqa: E402
 import os                           # noqa: E402
 import sys                          # noqa: E402
 import types                        # noqa: E402
@@ -87,8 +88,6 @@ def main(argv=None, root=ROOT) -> int:
     enable_compile_cache()
     t_imported = time.perf_counter()
     devices = jax.devices()
-    startup = {"imports_s": round(t_imported - T_PROCESS, 3),
-               "devices_s": round(time.perf_counter() - t_imported, 3)}
     dev = devices[0]
     if len(devices) < cell["chips"]:
         print(f"# cell needs {cell['chips']} chips, JAX found "
@@ -96,16 +95,24 @@ def main(argv=None, root=ROOT) -> int:
         return 3
     # a device outside the table raises here: no fallback to the CPU
     peaks = None if args.rehearse else peak_table.peaks_for(dev.device_kind)
-
     memory = MemoryWatch(devices[:cell["chips"]])
+    t_devices = time.perf_counter()
+
+    # set-up's phases tile it, each starting where the one before it ended:
+    # the driver's first (init_s) holds its import and the program's
     driver = importlib.import_module(f"benchmarks.drivers.{traffic['kind']}")
     obs = driver.run(types.SimpleNamespace(
         config=config, traffic=traffic, seed=args.seed, seconds=seconds,
-        trace=bool(args.trace), chips=cell["chips"], memory=memory))
+        trace=bool(args.trace), chips=cell["chips"], memory=memory,
+        t_setup=t_devices))
     obs["peaks"] = peaks
     obs["chips"] = cell["chips"]
-    obs["values"]["setup_s"] = obs["window_start"] - T_PROCESS
-    for key, note in {"startup_phases": startup, **obs["notes"]}.items():
+    values = obs["values"]
+    values["setup_s"] = obs["window_start"] - T_PROCESS
+    values["setup_imports_s"] = t_imported - T_PROCESS
+    values["setup_devices_s"] = t_devices - t_imported
+    phases = {k: v for k, v in values.items() if k.startswith("setup_")}
+    for key, note in {"setup_phases": phases, **obs["notes"]}.items():
         print(f"# {key}: {json.dumps(note)}", flush=True)
 
     declared = manifest["per_layer" if args.trace else "end_to_end"]
@@ -138,7 +145,14 @@ def main(argv=None, root=ROOT) -> int:
         # a rehearsal proves the control flow; it reports no metric
         result["rehearsal"] = {"would_report": sorted(metrics)}
         result["metrics"] = {}
+    # last, in the line and on stderr: what `correct` compared, by limit
+    result["limits"] = {
+        name: {"value": value if math.isfinite(value) else str(value),
+               "limit": limit}
+        for name, (value, limit) in obs["limits"].items()}
     print(json.dumps(result), flush=True)
+    for name, (value, limit) in obs["limits"].items():
+        print(f"# {name}: {value} (limit {limit})", file=sys.stderr)
     return 0
 
 

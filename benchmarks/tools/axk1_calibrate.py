@@ -35,7 +35,7 @@ from benchmarks.tools import axk1_faults  # noqa: E402
 from benchmarks.tools.lfm2_calibrate import _load, serve  # noqa: E402
 
 READINGS = ("layer_error", "rerouted_share", "attn_error",
-            "attn_error_decoded", "within_share", "within_score")
+            "attn_error_decoded", "within_share")
 
 
 def readings(fam, config: dict, params, sample: list, pad: int) -> list:
@@ -68,8 +68,7 @@ def readings(fam, config: dict, params, sample: list, pad: int) -> list:
         rows.append({
             "prompt": len(prompt), "emitted": len(emitted),
             "within_share": within,
-            "within_score": within + family.WITHIN_SMALL_SAMPLE
-            / len(emitted) ** 0.5,
+            "within": int((short <= family.TOKEN_LOGIT_TOL).sum()),
             "worst_shortfall": float(short.max()),
             **{name: float(value) for name, value in got.items()}})
     return rows

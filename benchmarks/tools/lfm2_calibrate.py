@@ -1,15 +1,17 @@
 """Re-take the readings that the ``lfm2_moe`` family's limits lie between,
 in one call on the chip (PERF.md section 6 has the first set):
 
-    python3 benchmarks/tools/lfm2_calibrate.py <requests> <seed> [--rehearse]
+    python3 benchmarks/tools/lfm2_calibrate.py <requests> <seed> [<seed> ...]
+        [--refs sound,int8_weights,...] [--rehearse]
 
-Serves ``requests`` requests of ``lfm2-serve-closed32``'s lengths on the
-cell's engine (not timed), drops the engine, and reads every statistic of
-``families/lfm2_moe.py``'s comparison on the SAME emitted tokens under the
-sound reference and under each fault of ``lfm2_faults.py``. Prints one line
-a reference and writes every request's numbers to
-``chiprun_out/lfm2_calibration.json``. About eleven chip-minutes for 24
-requests. The boundary between prompt and emitted tokens is known here,
+For each seed: the weights from it, ``requests`` requests of
+``lfm2-serve-closed32``'s lengths served on the cell's engine (not timed),
+the engine dropped, and every statistic of ``families/lfm2_moe.py``'s
+comparison read on the SAME emitted tokens under the sound reference and
+under each fault of ``lfm2_faults.py`` (or the ``--refs`` named). Prints one
+line a seed and reference and writes every request's numbers to
+``chiprun_out/lfm2_calibration.json`` (``{"runs": [{"seed", "references"}]}``).
+About eleven chip-minutes for 24 requests and five references. The boundary between prompt and emitted tokens is known here,
 which the family has to find again: the shares are the true ones.
 """
 
@@ -104,8 +106,7 @@ def readings(fam, config: dict, params, sample: list, pad: int) -> list:
         within = float((short <= family.TOKEN_LOGIT_TOL).mean())
         rows.append({
             "emitted": len(emitted), "within_share": within,
-            "within_score": within + family.WITHIN_SMALL_SAMPLE
-            / len(emitted) ** 0.5,
+            "within": int((short <= family.TOKEN_LOGIT_TOL).sum()),
             "worst_shortfall": float(short.max()),
             "layer_error": float(np.median(errors, axis=1).max()),
             "rerouted_share": float(
@@ -115,29 +116,40 @@ def readings(fam, config: dict, params, sample: list, pad: int) -> list:
 
 
 def main(argv) -> int:
-    n, seed, rehearse = int(argv[0]), int(argv[1]), "--rehearse" in argv
+    flags = [a for a in argv if a.startswith("--")]
+    rehearse = "--rehearse" in flags
+    names = ["sound", *lfm2_faults.FAULTS]
+    if "--refs" in argv:
+        names = argv[argv.index("--refs") + 1].split(",")
+        argv = argv[:argv.index("--refs")] + argv[argv.index("--refs") + 2:]
+    n, seeds = int(argv[0]), [int(a) for a in argv[1:] if a[:2] != "--"]
     config = _load("configs/lfm2-24b-a2b.json", rehearse)
     traffic = _load("traffic/serve-closed-32-chat4k.json", rehearse)
     fam = family.build_serve(config)
-    params = fam.init_params(jax.random.PRNGKey(seed))
-    sample = serve(fam, params, traffic, n, seed)
-    pad = -(-max(len(p) + len(t) for p, t in sample) // 256) * 256
-    out = {"seed": seed, "references": {}}
-    for name in ["sound", *lfm2_faults.FAULTS]:
-        undo = lfm2_faults.apply(name) if name != "sound" else None
-        try:
-            rows = readings(fam, config, params, sample, pad)
-        finally:
-            if undo:
-                undo()
-        out["references"][name] = rows
-        print(name, json.dumps({
-            key: [min(r[key] for r in rows), max(r[key] for r in rows)]
-            for key in ("layer_error", "rerouted_share", "within_share",
-                        "within_score")}), flush=True)
+    runs = []
+    for seed in seeds:
+        params = fam.init_params(jax.random.PRNGKey(seed))
+        sample = serve(fam, params, traffic, n, seed)
+        pad = -(-max(len(p) + len(t) for p, t in sample) // 256) * 256
+        run = {"seed": seed, "references": {}}
+        for name in names:
+            undo = lfm2_faults.apply(name) if name != "sound" else None
+            try:
+                rows = readings(fam, config, params, sample, pad)
+            finally:
+                if undo:
+                    undo()
+            run["references"][name] = rows
+            print(seed, name, json.dumps({
+                key: [min(r[key] for r in rows), max(r[key] for r in rows)]
+                for key in ("layer_error", "rerouted_share", "within_share")}
+                | {"pooled_within_share": sum(r["within"] for r in rows)
+                   / sum(r["emitted"] for r in rows)}), flush=True)
+        runs.append(run)
+        del params
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/lfm2_calibration.json", "w") as f:
-        json.dump(out, f)
+        json.dump({"runs": runs}, f)
     return 0
 
 

@@ -4,7 +4,10 @@ per-layer metrics, the cell's traffic letter for letter, and the comparison
 that decides the serve cell's ``correct`` — at the rehearsal sizes on the
 CPU, whole and under each fault of ``tools/axk1_faults.py``."""
 
+import contextlib
+import functools
 import importlib
+import io
 import json
 import os
 
@@ -19,6 +22,7 @@ from benchmarks.families import axk1 as family
 from benchmarks.lib import loadgen, mla_moe_cost, peaks as peak_table, xtrace
 from benchmarks.readers import (latent_attn_roofline, mla_decode_hbm_roofline,
                                 moe_gmm_held_roofline, span)
+from benchmarks.reference import axk1 as reference
 from benchmarks.tools import axk1_faults
 from dtf_tpu.models import gpt
 from dtf_tpu.serve import engine as serve_engine
@@ -65,9 +69,8 @@ WIDTHS = {"hidden_size", "intermediate_size", "moe_intermediate_size",
 
 
 def test_config_entry_and_file_with_widths_named_by_their_keys():
-    """``test_bench_manifest.test_config_entry_and_file`` for this entry,
-    with the width rule spelled by key (tests/conftest.py has why the
-    accepted case is marked)."""
+    """``test_bench_manifest.test_config_entry_file_and_widths`` for this
+    entry, with the published widths named by key."""
     entry = next(c for c in MANIFEST["configs"] if c["name"] == "ax-k1")
     assert set(entry) == {"name", "source", "file", "reduced", "why"}
     assert entry["file"] == CONFIG_FILE and len(entry["why"]) <= 200
@@ -191,22 +194,22 @@ def test_the_cell_is_declared_with_the_issues_traffic_letter_for_letter():
     assert CONFIG["emitted_at_least"] == lengths["output"]["min"]
 
 
-def _metric(name, unit, better, source, layer, moves):
+SERVE_CELLS = ["gpt2m-serve-closed32", "lfm2-serve-closed32", CELL]
+
+
+def _metric(name, unit, better, source, layer, moves, cells=(CELL,)):
     return {"name": name, "unit": unit, "better": better, "source": source,
-            "layer": layer, "moves": moves, "workloads": [CELL]}
+            "layer": layer, "moves": moves, "workloads": list(cells)}
 
 
-#: PR 31's own per-layer metrics: written by the program, read by files of
-#: this PR (``benchmarks/metrics/<name>.json`` and its reader), and NOT
-#: declared in ``BENCHMARK.json`` — an accepted test pins the END of
-#: ``per_layer`` (``test_bench_lfm2.py``), and the contract reads an entry
-#: put in the middle as a change to what was there (PERF.md section 7 j).
-#: These are the entries a ``benchmark`` PR adds once the pin is position-free.
-UNDECLARED = [
+#: PR 31's own per-layer metrics, declared by PR 36 as PR 31 wrote them
+#: (``benchmarks/AXK1.md``), with ``mla_cache_live_pct`` become
+#: ``decode_attn_live_pct``: the counter it reads is every serve cell's.
+DECLARED = [
     _metric("mla_decode_hbm_roofline", "%", "higher", "device_trace",
             "serve engine", "itl_ms_p95"),
-    _metric("mla_cache_live_pct", "%", "lower", "program_counter",
-            "serve engine", "itl_ms_p95"),
+    _metric("decode_attn_live_pct", "%", "lower", "program_counter",
+            "serve engine", "itl_ms_p95", SERVE_CELLS),
     _metric("moe_held_pairs_per_expert", "ratio", "higher", "program_counter",
             "model blocks", "itl_ms_p95"),
     _metric("moe_held_touched_pct", "%", "lower", "program_counter",
@@ -218,52 +221,41 @@ UNDECLARED = [
 ]
 
 
-def laid_over(tmp_path) -> str:
-    """A root whose ``BENCHMARK.json`` declares :data:`UNDECLARED` too, over
-    the benchmark's own files."""
-    root = tmp_path / "laid_over"
-    root.mkdir()
-    os.symlink(os.path.join(ROOT, "benchmarks"), root / "benchmarks")
-    with open(root / "BENCHMARK.json", "w") as f:
-        json.dump({**MANIFEST,
-                   "per_layer": MANIFEST["per_layer"] + UNDECLARED}, f)
-    return str(root)
-
-
 def test_the_cell_reports_the_serve_metrics():
     """By name, wherever a later PR puts its own entries."""
     by_name = {m["name"]: m
                for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]}
     shared = {"serve_tokens_per_s", "ttft_ms_p95", "itl_ms_p95",
-              "decode_step_ms_p50", "prefill_chunk_ms_p50",
-              "slot_occupancy_pct", "tick_host_ms_mean",
-              "idle_ms_per_tick.decode_dispatch",
+              "decode_step_ms_p50", "slot_occupancy_pct",
+              "tick_host_ms_mean", "idle_ms_per_tick.decode_dispatch",
               "idle_ms_per_tick.prefill_dispatch",
               "idle_ms_per_tick.readback", "idle_ms_per_tick.scheduler",
-              "decode_device_ms_p50", "prefill_chunk_device_ms_p50"}
+              "decode_device_ms_p50", "prefill_chunk_device_ms_p50",
+              "decode_attn_live_pct", "sampler_greedy_steps_pct",
+              "host_operands_per_tick", "device_reads_per_tick",
+              "setup_engine_s", "setup_warm_s"}
     for name in shared:
-        assert sorted(by_name[name]["workloads"]) == sorted([
-            "gpt2m-serve-closed32", "lfm2-serve-closed32", CELL])
+        assert sorted(by_name[name]["workloads"]) == sorted(SERVE_CELLS)
     reporting = {m["name"] for m in by_name.values()
                  if CELL in m.get("workloads", [CELL])}
-    assert reporting == shared | {"setup_s"}
+    # a later PR appends metrics of this cell: what is here stays
+    assert reporting >= shared | {m["name"] for m in DECLARED} | {
+        "setup_s", "setup_imports_s", "setup_devices_s", "setup_init_s",
+        "moe_max_load_over_mean"}
     for m in MANIFEST["per_layer"]:
         if CELL in m.get("workloads", []):
-            assert CELL in by_name[m["moves"]]["workloads"]
+            assert CELL in by_name[m["moves"]].get("workloads", [CELL])
 
 
-@pytest.mark.parametrize("entry", UNDECLARED, ids=lambda e: e["name"])
-def test_an_undeclared_metric_waits_with_its_file_and_reader(entry):
-    """What ``test_bench_manifest.test_metric_entry_and_reader_file`` will
-    hold the entry to once it is declared."""
-    assert entry["name"] not in {m["name"] for m in MANIFEST["per_layer"]}
-    assert set(entry) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
+@pytest.mark.parametrize("entry", DECLARED, ids=lambda e: e["name"])
+def test_the_cells_metric_is_declared_as_written(entry):
+    """Each entry stands in ``per_layer`` as ``benchmarks/AXK1.md`` has it,
+    with its file and reader."""
+    assert entry in MANIFEST["per_layer"]
     assert entry["source"] in ("device_trace", "program_counter")
-    assert entry["layer"] in {m["layer"] for m in MANIFEST["per_layer"]}
     moved = next(m for m in MANIFEST["end_to_end"]
                  if m["name"] == entry["moves"])
-    assert entry["workloads"] == [CELL] and CELL in moved["workloads"]
+    assert set(entry["workloads"]) <= set(moved["workloads"])
     if entry["name"].endswith("_roofline"):
         assert (entry["unit"], entry["better"]) == ("%", "higher")
     with open(os.path.join(ROOT, "benchmarks", "metrics",
@@ -402,7 +394,7 @@ def test_counter_metrics_read_the_mean_through_the_span_reader():
     obs = make_obs(SPANS)
     for name, want in (("moe_held_pairs_per_expert", 15.0 / 12),
                        ("moe_held_touched_pct", 75.0),
-                       ("mla_cache_live_pct", 33.0)):
+                       ("decode_attn_live_pct", 33.0)):
         with open(os.path.join(ROOT, "benchmarks", "metrics",
                                name + ".json")) as f:
             spec = json.load(f)
@@ -483,6 +475,9 @@ def test_the_sound_program_is_correct_by_its_family(served, capfd):
         assert n["layer_error"] < family.LAYER_ERROR_LIMIT
         assert n["attn_error"] < family.ATTN_ERROR_LIMIT
         assert n["emitted"] >= 24
+    jax.effects_barrier()
+    assert set(fam.readings()) == set(family.RUN_LIMITS)
+    assert fam.verdict(), fam.readings()
 
 
 @pytest.mark.parametrize("fault,reading", [
@@ -502,8 +497,8 @@ def test_each_fault_in_the_reference_reads_not_correct(served, capfd, fault,
     undo = axk1_faults.apply(fault)
     try:
         # a fresh family: the jitted comparison must trace the faulty module
-        check = check_tokens(family.build_serve(SERVED).reference_logits,
-                             params, sample, 128)
+        fresh = family.build_serve(SERVED)
+        check = check_tokens(fresh.reference_logits, params, sample, 128)
     finally:
         undo()
     assert not check["ok"], check
@@ -514,6 +509,9 @@ def test_each_fault_in_the_reference_reads_not_correct(served, capfd, fault,
              "layer_error": family.LAYER_ERROR_LIMIT,
              "attn_error": family.ATTN_ERROR_LIMIT}[reading]
     assert max(n[reading] for n in notes) > limit, notes
+    # and the result line's `limits` carry it, with its limit
+    jax.effects_barrier()
+    assert fresh.readings()[reading][0] > limit == fresh.readings()[reading][1]
 
 
 def test_8_bit_cached_rows_move_the_attention_reading(served, capfd):
@@ -546,27 +544,70 @@ def test_8_bit_cached_rows_move_the_attention_reading(served, capfd):
                                                    rel=0.1)
 
 
-def rehearse(capfd, root):
-    rc = bench_run.main(["--workload", CELL, "--seed", str(2**31 + 31),
-                         "--seconds", "1", "--trace", "1", "--rehearse",
-                         "1"], root=root)
+def test_a_short_sequence_reads_the_stretches_it_fills(served):
+    """A rehearsal's prompts start at 4 tokens. A stretch of the attention
+    reading that holds fewer than ATTN_MIN_POSITIONS of a sequence's
+    positions is not read (the median of two read up to 0.0113 against the
+    limit of 0.0095), and a sequence that fills none reads 0, never NaN."""
+    fam, params, _ = served
+    ids = jnp.asarray(np.random.default_rng(7).integers(
+        1, fam.vocab_size, (1, 128)), jnp.int32)
+    _, seen = reference.forward(
+        params, ids, SERVED, experts_held=tuple(SERVED["experts_held"]),
+        seen_positions=128, seen_attention=family.ATTN_POSITIONS)
+    read = jax.jit(family.program_readings(fam.cfg, SERVED))
+
+    def reading(n):                # of the sequence's first n positions
+        got = read(params, seen, jnp.arange(128)[None, :] < n)
+        return {name: float(v) for name, v in got.items()}
+
+    # stretches: 0-24 a first chunk, 24-48 a continuing one, 48-64 decoded
+    assert reading(26)["attn_error"] == reading(24)["attn_error"]
+    assert reading(50)["attn_error_decoded"] == 0.0
+    assert reading(56)["attn_error_decoded"] > 0.0
+    assert reading(64)["attn_error"] == pytest.approx(
+        reading(100)["attn_error"])
+    short = reading(5)
+    assert short["attn_error"] == 0.0
+    assert 0.0 <= short["layer_error"] < family.LAYER_ERROR_LIMIT
+
+
+def test_a_layer_or_stretch_with_nothing_to_read_reads_0_and_a_nan_fails():
+    values = jnp.asarray([[0.1, 0.2, 0.3, jnp.nan]])
+    mask = jnp.asarray([[True, True, False, False]])
+    assert float(family.median_where(values, mask, 2)) == pytest.approx(0.15)
+    assert float(family.median_where(values, mask, 3)) == 0.0
+    assert float(family.median_where(values, ~mask, 1)) == float("inf")
+
+
+@functools.cache
+def rehearsal() -> dict:
+    """The last line of one traced rehearsal through ``run.py``, run once
+    for the two tests below."""
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out):
+        rc = bench_run.main(["--workload", CELL, "--seed", str(2**31 + 31),
+                             "--seconds", "1", "--trace", "1", "--rehearse",
+                             "1"])
     assert rc == 0
-    last = json.loads(capfd.readouterr().out.strip().splitlines()[-1])
+    return json.loads(out.getvalue().strip().splitlines()[-1])
+
+
+def test_the_cell_rehearses_through_run_py():
+    last = rehearsal()
     assert last["correct"] and last["failed"] == 0 and last["attempted"] > 0
     assert last["metrics"] == {}                 # a CPU reports no metric
-    return set(last["rehearsal"]["would_report"])
-
-
-def test_the_cell_rehearses_through_run_py(capfd):
-    would_report = rehearse(capfd, ROOT)
     assert {"decode_step_ms_p50", "slot_occupancy_pct",
-            "tick_host_ms_mean"} <= would_report
-    assert not would_report & {m["name"] for m in UNDECLARED}
+            "tick_host_ms_mean"} <= set(last["rehearsal"]["would_report"])
+    # the family's own limits, the worst of its requests, on the line
+    assert set(family.LIMITS) <= set(last["limits"])
 
 
-def test_the_cells_own_metrics_are_read_once_they_are_declared(capfd,
-                                                               tmp_path):
-    """The counters' metrics out of a rehearsal (the three that read the
-    device trace need a chip's)."""
-    assert {"mla_cache_live_pct", "moe_held_pairs_per_expert",
-            "moe_held_touched_pct"} <= rehearse(capfd, laid_over(tmp_path))
+def test_the_cells_own_metrics_are_read_once_they_are_declared():
+    """The counters' metrics out of a rehearsal of the manifest itself (the
+    three that read the device trace need a chip's)."""
+    assert {"decode_attn_live_pct", "sampler_greedy_steps_pct",
+            "moe_max_load_over_mean", "moe_held_pairs_per_expert",
+            "moe_held_touched_pct", "host_operands_per_tick",
+            "device_reads_per_tick"} <= set(
+                rehearsal()["rehearsal"]["would_report"])

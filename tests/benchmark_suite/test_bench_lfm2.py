@@ -51,8 +51,8 @@ WIDTHS = {"hidden_size", "intermediate_size", "moe_intermediate_size",
 
 
 def test_config_entry_and_file_with_widths_named_by_their_keys():
-    """``test_bench_manifest.test_config_entry_and_file`` for this entry,
-    with the width rule spelled by key (conftest.py has why)."""
+    """``test_bench_manifest.test_config_entry_file_and_widths`` for this
+    entry, with the published widths named by key."""
     entry = next(c for c in MANIFEST["configs"] if c["name"] == "lfm2-24b-a2b")
     assert set(entry) == {"name", "source", "file", "reduced", "why"}
     assert entry["file"] == CONFIG_FILE and len(entry["why"]) <= 200
@@ -251,16 +251,28 @@ def test_moe_gmm_roofline_sums_both_programs_calls():
         pytest.approx(100.0 * least / 0.040))
 
 
-def test_new_metrics_are_declared_for_the_new_cell_alone():
+#: the manifest as committed, and with an entry a later PR might append
+THROWAWAY = {"name": "later_pr_metric", "unit": "%", "better": "higher",
+             "source": "program_counter", "layer": "serve engine",
+             "moves": "itl_ms_p95", "workloads": ["lfm2-serve-closed32"]}
+
+
+@pytest.mark.parametrize("per_layer", [
+    MANIFEST["per_layer"], MANIFEST["per_layer"] + [THROWAWAY]],
+    ids=["committed", "appended_to"])
+def test_new_metrics_are_declared_for_the_new_cell_alone(per_layer):
+    """PR 26's four by name, wherever they stand: later PRs append, and
+    ``moe_max_load_over_mean`` reads on A.X-K1's cell too."""
     new = {"moe_experts_touched_pct": "itl_ms_p95",
            "moe_max_load_over_mean": "itl_ms_p95",
            "decode_hbm_roofline": "itl_ms_p95",
            "moe_gmm_roofline": "serve_tokens_per_s"}
-    by_name = {m["name"]: m for m in MANIFEST["per_layer"]}
+    by_name = {m["name"]: m for m in per_layer}
     for name, moves in new.items():
-        assert by_name[name]["workloads"] == ["lfm2-serve-closed32"]
+        assert "lfm2-serve-closed32" in by_name[name]["workloads"]
         assert by_name[name]["moves"] == moves
-    assert [m["name"] for m in MANIFEST["per_layer"]][-4:] == list(new)
+    assert "axk1-serve-closed32-doc16k" in by_name[
+        "moe_max_load_over_mean"]["workloads"]
     cells = {w["name"]: w for w in MANIFEST["workloads"]}
     assert cells["lfm2-serve-closed32"]["chips"] == 1
     assert cells["gpt2m-train-dp4-zero1"]["chips"] == 4
@@ -327,6 +339,16 @@ def test_bfloat16_engine_meets_every_limit(fam, weights, sample, capsys):
         assert 0.002 < note["layer_error"] < family.LAYER_ERROR_LIMIT / 1.5
         assert note["rerouted_share"] == 0.0
         assert note["within_share"] >= 0.9 and 24 <= note["emitted"] <= 28
+    # the worst of each and the pooled share, as the result line's
+    # `limits` carries them
+    jax.effects_barrier()
+    worst = fam.readings()
+    assert set(worst) == set(fam.limits)
+    assert worst["within_share"][1] == SERVED["within_share_limit"]
+    assert worst["within_share"][0] >= SERVED["within_share_limit"]
+    assert all(worst[n][0] <= worst[n][1]
+               for n in ("layer_error", "rerouted_share")), worst
+    assert fam.verdict()
 
 
 #: fault in the reference -> the limit of the family that catches it
@@ -347,15 +369,21 @@ def test_a_faulty_reference_fails_the_same_tokens(fam, weights, sample,
         check, notes = checked(fresh, weights, sample, capsys)
     finally:
         undo()
-    assert not check["ok"] and check["outside_tolerance"] > 0, check
+    jax.effects_barrier()
+    assert not fresh.verdict()
     limit = {"layer_error": family.LAYER_ERROR_LIMIT,
              "rerouted_share": family.REROUTED_SHARE_LIMIT}
     name = CAUGHT_BY[fault]
     if name == "within_share":
-        assert all(n["within_score"] < family.WITHIN_LIMIT
-                   for n in notes), notes
+        # a request's own readings hold: its tokens are handed over lifted,
+        # and the pooled share fails the run
+        assert check["ok"] and all(n["ok"] == 1.0 for n in notes), notes
+        assert fresh.readings()[name][0] < SERVED["within_share_limit"]
     else:
+        assert not check["ok"] and check["outside_tolerance"] > 0, check
         assert any(n[name] > limit[name] for n in notes), notes
+        # the result line's `limits` carry the reading that failed
+        assert fresh.readings()[name][0] > limit[name]
 
 
 def test_emitted_run_is_the_likely_suffix_and_never_under_the_floor():
@@ -371,6 +399,32 @@ def test_emitted_run_is_the_likely_suffix_and_never_under_the_floor():
     assert noise[0].tolist() == [0] * 8 + [1] * 3 + [0, 0]
 
 
+def test_the_within_share_is_pooled_over_the_sample():
+    """One request far under the limit does not fail a sample whose tokens
+    together lie within it; a sample that does not, or a NaN reading, or
+    nothing judged, fails."""
+    limit = 0.6
+    low = limit - 0.2
+
+    def note(within, emitted, layer_error=0.004):
+        return {"within": within, "emitted": emitted,
+                "layer_error": layer_error, "rerouted_share": 0.0}
+
+    def verdict(notes):
+        read = {**family.worst_readings(notes, family.LIMITS),
+                **family.pooled_within(notes, limit)}
+        return family.holds(
+            read, {**family.LIMITS, "within_share": (limit, min)})
+
+    sound = [note(round(low * 100), 100), note(300, 300)]
+    assert family.pooled_within(sound, limit)["within_share"] == (
+        (round(low * 100) + 300) / 400, limit)
+    assert verdict(sound)
+    assert not verdict([note(round(low * 100), 100)] * 2)
+    assert not verdict([note(100, 100, layer_error=float("nan"))])
+    assert not verdict([])
+
+
 def test_a_pad_column_in_the_conv_state_fails_the_comparison(fam, weights,
                                                              monkeypatch):
     """The ragged last chunk's pad enters the state when the conv ignores
@@ -381,9 +435,11 @@ def test_a_pad_column_in_the_conv_state_fails_the_comparison(fam, weights,
         gpt.ShortConv, "__call__",
         lambda self, x, prefill_len=None, decode_active=None: whole(
             self, x, None, decode_active))
-    check = check_tokens(fam.reference_logits, weights,
+    fresh = family.build_serve(SERVED)
+    check = check_tokens(fresh.reference_logits, weights,
                          serve(fam, weights), 96)
-    assert not check["ok"], check
+    jax.effects_barrier()
+    assert not (check["ok"] and fresh.verdict()), check
 
 
 def test_a_conv_state_never_zeroed_fails_the_comparison(fam, weights,
@@ -393,9 +449,11 @@ def test_a_conv_state_never_zeroed_fails_the_comparison(fam, weights,
     cell's prompts, 64 tokens and more, they reach them only through
     attention over the first two positions: PERF.md section 7.)"""
     monkeypatch.setattr(gpt, "_RECURRENT_CACHE_KEYS", frozenset())
-    check = check_tokens(fam.reference_logits, weights,
+    fresh = family.build_serve(SERVED)
+    check = check_tokens(fresh.reference_logits, weights,
                          serve(fam, weights, n=16), 96)
-    assert not check["ok"], check
+    jax.effects_barrier()
+    assert not (check["ok"] and fresh.verdict()), check
 
 
 def test_the_calibration_tool_rehearses(tmp_path, monkeypatch, capsys):
@@ -406,7 +464,7 @@ def test_the_calibration_tool_rehearses(tmp_path, monkeypatch, capsys):
     monkeypatch.chdir(tmp_path)
     assert lfm2_calibrate.main(["3", str(2**31 + 7), "--rehearse"]) == 0
     with open(tmp_path / "chiprun_out" / "lfm2_calibration.json") as f:
-        read = json.load(f)["references"]
+        read = json.load(f)["runs"][0]["references"]
     assert set(read) == {"sound", *lfm2_faults.FAULTS}
     assert all(len(rows) == 3 for rows in read.values())
     assert max(r["layer_error"] for r in read["sound"]) \
