@@ -32,6 +32,12 @@ that owns all four concerns:
 The srclint AOT fence (``raw-aot-compile``) makes this structural: raw
 ``.lower(``/``.compile(`` idioms outside this module (+ tune/ + tests)
 are findings unless pinned with ``# aot-ok: <why>``.
+
+Importing this module installs the compile fence's process-wide
+``jax.monitoring`` listener (:mod:`dtf_tpu.telemetry.fence`), so every
+program born here has a compile record — its trace, lower and
+compile-or-load seconds — under :attr:`Program.trace_name`, whether or not
+a ``Telemetry`` exists yet when it compiles.
 """
 
 from __future__ import annotations
@@ -41,7 +47,11 @@ from typing import Any, Callable, MutableMapping, Optional
 
 import jax
 
+from dtf_tpu.telemetry import fence as _fence
+
 PyTree = Any
+
+_fence.install_listeners()
 
 
 def fenced(name: str, body: Callable, counts: Optional[MutableMapping]):
@@ -80,7 +90,10 @@ class Program:
       :meth:`lower`/:meth:`aot` with no arguments use them;
     - ``arg_shardings`` — the declared input layouts the analysis
       memory pass prices (None = the abstract leaves carry their own);
-    - ``compiled`` — the AOT executable after :meth:`aot` (None before).
+    - ``compiled`` — the AOT executable after :meth:`aot` (None before);
+    - ``trace_name`` — the name JAX traces the fenced body under (the
+      program is ``jit_<trace_name>`` on the device): the key of its
+      compile record, :func:`dtf_tpu.telemetry.fence.program_compile`.
     """
 
     def __init__(self, name: str, jitted: Callable, body: Callable, *,
@@ -92,6 +105,9 @@ class Program:
         self.abstract_args = abstract_args
         self.arg_shardings = arg_shardings
         self.compiled = None
+        # jax.jit names what it traces after the function (fenced's
+        # wrapper carries its body's name)
+        self.trace_name = getattr(body, "__name__", name)
 
     def __call__(self, *args, **kwargs):
         return self.jitted(*args, **kwargs)

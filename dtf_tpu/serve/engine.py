@@ -1229,7 +1229,6 @@ class DecodeEngine:
         self.moe_samples = {
             "picks": picks,
             "experts_touched": float(touched.mean()),
-            "max_expert_load": float(max_load.mean()),
             "max_load_over_mean": float(max_load.mean())
             * self.cfg.experts.num_experts / picks,
             "held_pairs": float(held_pairs.mean()),

@@ -19,14 +19,21 @@ the engine calls are additionally recorded as ``serve_prefill_chunk`` /
 data_wait/dispatch decomposition, serving edition) plus ``router_wait``
 (queue time between submit and a slot accepting the request — the
 admission latency the Router SLO panel watches), and ``stats()`` gains
-their p50/p99. All of it is host clock arithmetic: zero added device
-readbacks (counter-instrumented test, PR 5 idiom). The same gate also
-writes each tick into the profiler's timeline as one
+their p50/p99. A tick also files the host's account of itself:
+``serve_tick_cpu`` (the ticking thread's CPU seconds),
+``serve_tick_preempted`` (its involuntary context switches) and
+``serve_tick_gc`` (seconds of garbage-collection pause since the previous
+tick's sample, wherever in the loop they fell); and construction files
+the compile of each of the engine's programs
+(``Telemetry.file_compiles``). All of it is host clock arithmetic: zero
+added device readbacks (a counter-instrumented test holds it). The same
+gate also writes each tick into the profiler's timeline as one
 ``jax.profiler.TraceAnnotation`` span, ``dtf.serve.tick`` (the engine adds
-its own inside it under ``annotate_traces``; docs/OBSERVABILITY.md section
+its own inside it under ``annotate_traces``, and so does the process's
+garbage-collection hook, ``dtf.host.gc``; docs/OBSERVABILITY.md section
 7 has the table), so that a trace reader can say how much of a tick's idle
 device time lies outside every engine call. Without telemetry a tick
-constructs no annotation.
+constructs no annotation and the garbage-collection hook is not installed.
 
 With an engine built with ``prefix_pages > 0`` admission consults the
 prefix page cache: the pinned page chain lands in ONE batched gather on
@@ -68,7 +75,8 @@ import weakref
 from typing import Optional, Sequence
 
 from dtf_tpu.metrics import quantile as _quantile
-from dtf_tpu.telemetry.spans import trace_annotation
+from dtf_tpu.telemetry.spans import (process_gc_pauses, thread_preemptions,
+                                     trace_annotation)
 
 log = logging.getLogger("dtf_tpu")
 
@@ -172,7 +180,12 @@ class Scheduler:
 
     ``prefill_chunks_per_tick`` bounds how much prefill work may delay the
     next decode step (0 = admit greedily, whole queue's worth per tick).
-    ``clock`` is injectable for deterministic tests.
+    ``clock`` is injectable for deterministic tests, and so are the clocks
+    of a tick's host account under telemetry: ``thread_clock`` (CPU
+    seconds of the calling thread), ``preemptions`` (its involuntary
+    context switches so far) and ``gc_pauses`` (a
+    :class:`~dtf_tpu.telemetry.spans.GcPauses`; None = the process's,
+    whose hook the first scheduler with telemetry installs).
     """
 
     def __init__(self, engine, writer=None, *, log_every: int = 0,
@@ -181,7 +194,9 @@ class Scheduler:
                  ttft_slo_s: float = 0.0, max_queue: int = 0,
                  shed_retry_after_s: float = 0.25,
                  postmortem_name: Optional[str] = "serve_scheduler",
-                 log_sink=None, replica_index: int = 0):
+                 log_sink=None, replica_index: int = 0,
+                 thread_clock=time.thread_time,
+                 preemptions=thread_preemptions, gc_pauses=None):
         self.engine = engine
         self.writer = writer
         #: serve-log sink (ISSUE 19): every terminal ``done`` request is
@@ -205,6 +220,19 @@ class Scheduler:
             state = weakref.WeakMethod(self.postmortem_state)
             telemetry.add_postmortem_provider(
                 postmortem_name, lambda: (state() or dict)())
+        self._thread_clock = thread_clock
+        self._preemptions = preemptions
+        self._gc = None
+        if telemetry is not None:
+            # the engine's programs compiled in its constructor, before
+            # this telemetry object existed: their compile records
+            programs = getattr(engine, "programs", None) or {}
+            telemetry.file_compiles(
+                p.trace_name for p in programs.values())
+            self._gc = (gc_pauses if gc_pauses is not None
+                        else process_gc_pauses())
+            self._gc.watch(self)
+            self._gc_seen = self._gc.total_s
         #: TTFT service-level objective (0 = untracked): ``stats()`` then
         #: reports the fraction of completed first tokens inside it — the
         #: per-replica SLO rollup the router surfaces (docs/SERVING.md).
@@ -348,12 +376,22 @@ class Scheduler:
         decode step. With telemetry the round is a ``dtf.serve.tick`` span
         on the profiler's clock (``jax.profiler.TraceAnnotation``, reached
         through telemetry/spans.py so this module stays jax-free at
-        import); without, the one attribute test is all it costs."""
+        import), and the host's account of the tick is filed after it: one
+        sample each of ``serve_tick_cpu``, ``serve_tick_preempted`` and
+        ``serve_tick_gc``; without, the one attribute test is all it
+        costs."""
         if self.telemetry is None:
             self._round()
             return
+        cpu0, preempted0 = self._thread_clock(), self._preemptions()
         with trace_annotation("dtf.serve.tick"):
             self._round()
+        spans = self.telemetry.spans
+        spans.add("serve_tick_cpu", self._thread_clock() - cpu0)
+        spans.add("serve_tick_preempted", self._preemptions() - preempted0)
+        gc_s = self._gc.total_s
+        spans.add("serve_tick_gc", gc_s - self._gc_seen)
+        self._gc_seen = gc_s
 
     def _round(self) -> None:
         self._tick += 1

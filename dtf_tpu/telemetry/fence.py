@@ -1,7 +1,8 @@
-"""The training compile fence — retraces and backend compiles, counted.
+"""The compile fence — retraces and backend compiles counted, and what each
+program's compile cost.
 
-Two complementary counters, the same pair ``DecodeEngine`` pins
-(dtf_tpu/serve/engine.py):
+Three accounts, the first per run and the other two from ONE process-wide
+``jax.monitoring`` listener:
 
 - **trace counts**: :meth:`CompileFence.count_traces` wraps the PYTHON
   step function before ``jax.jit`` sees it, so the wrapper body runs once
@@ -10,39 +11,134 @@ Two complementary counters, the same pair ``DecodeEngine`` pins
   ``DecodeEngine.trace_counts`` — steady state must stay pinned at 1 per
   program; any increment mid-run is a shape/dtype-driven retrace silently
   recompiling the hot path.
-- **backend compile events**: a ``jax.monitoring`` listener counting
-  compile-related events and summing the ``/jax/core/compile/*_duration``
-  durations — this is what feeds the goodput ``compile`` bucket, and it
-  catches compiles the trace counter cannot see (helper jits inside hooks,
-  donation fallbacks, cache misses).
+- **backend compiles**, while a fence is started: ``compile_events`` counts
+  the executables XLA compiled or loaded from the persistent cache (JAX
+  wraps both in ``/jax/core/compile/backend_compile_duration``), one per
+  executable, of any program — it catches compiles the trace counter cannot
+  see (helper jits inside hooks, donation fallbacks). ``compile_s`` sums
+  the three compile phases (trace, lower to MLIR, backend compile or load)
+  at the top level only: a phase that runs inside another on the same
+  thread (a nested jit's trace inside its parent's, an eager op compiled
+  while a step traces) is already in its parent's duration. It feeds the
+  goodput ``compile`` bucket. The ``/jax/compilation_cache/*`` events are
+  the cache's bookkeeping, not compiles, and are not counted.
+- **per-program compile records** (:func:`program_compile`): for each
+  program compiled at the top level, keyed by the name of the function it
+  traced, its latest compile's trace, lower and compile-or-load seconds
+  and whether the persistent cache served it (``cache_hit`` 1.0 where a
+  ``cache_hits`` event came while its backend compile was in flight, 0.0
+  where ``cache_misses`` or nothing came). Only a program's own events
+  count: ``fun_name`` equal to its name (the trace) or ``jit(<name>)``
+  (lower and compile). :mod:`dtf_tpu.core.executor` installs the listener
+  at import, so programs that compile before any ``Telemetry`` exists (the
+  serve engine's) have their records;
+  :meth:`dtf_tpu.telemetry.run.Telemetry.file_compiles` puts them on the
+  span channel.
 
-jax.monitoring offers no unregister API on this jax, so ONE module-level
-listener is installed lazily and dispatches to the currently-active fences
-— constructing fences per run (tests build many) never stacks listeners.
+The listener runs only when JAX compiles, so no hot path pays for it.
+jax.monitoring offers no unregister API on every jax, so the listener is
+installed once and dispatches to the currently-active fences —
+constructing fences per run (tests build many) never stacks listeners.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import threading
+from typing import Optional
+
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+#: the three compile phases, by event, and the record field each fills
+_PHASES = {TRACE_EVENT: "trace_s", LOWER_EVENT: "lower_s",
+           COMPILE_EVENT: "compile_s"}
 
 _LOCK = threading.Lock()
 _ACTIVE: list["CompileFence"] = []
 _INSTALLED = False
+#: per compiling thread: how many compile phases are open (JAX records a
+#: scalar at a phase's start and its duration at its end), and the cache's
+#: verdict on the backend compile in flight
+_LOCAL = threading.local()
+
+
+@dataclasses.dataclass
+class ProgramCompile:
+    """One compile of one program: seconds by phase, and 1.0 where the
+    persistent cache served the executable."""
+
+    trace_s: float = 0.0
+    lower_s: float = 0.0
+    compile_s: float = 0.0
+    cache_hit: float = 0.0
+
+
+#: top-level phases seen for a program whose backend compile has not come
+_OPEN: dict[str, ProgramCompile] = {}
+#: per program, (compiles so far, the latest compile)
+_DONE: dict[str, tuple[int, ProgramCompile]] = {}
+
+
+def program_compile(key: str) -> Optional[tuple[int, ProgramCompile]]:
+    """``(compiles so far, the latest)`` of the program whose traced
+    function is named ``key``; None before its first compile."""
+    return _DONE.get(key)
+
+
+def _program_key(fun_name: str) -> str:
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        return fun_name[4:-1]
+    return fun_name
+
+
+def _on_scalar(name: str, value, **kw) -> None:
+    if name in _PHASES:
+        _LOCAL.depth = getattr(_LOCAL, "depth", 0) + 1
+        if name == COMPILE_EVENT:
+            _LOCAL.cache_hit = 0.0
 
 
 def _on_event(name: str, **kw) -> None:
-    for f in list(_ACTIVE):
-        f._event(name)
+    if name == CACHE_HIT_EVENT:
+        _LOCAL.cache_hit = 1.0
+    elif name == CACHE_MISS_EVENT:
+        _LOCAL.cache_hit = 0.0
 
 
 def _on_duration(name: str, duration: float, **kw) -> None:
+    field = _PHASES.get(name)
+    if field is None:
+        return
+    depth = max(getattr(_LOCAL, "depth", 1) - 1, 0)
+    _LOCAL.depth = depth
+    top = depth == 0
     for f in list(_ACTIVE):
-        f._duration(name, duration)
+        f._duration(name, duration, top)
+    fun_name = kw.get("fun_name")
+    if not top or fun_name is None:
+        return
+    key = _program_key(fun_name)
+    with _LOCK:
+        rec = _OPEN.get(key)
+        if rec is None or (name == TRACE_EVENT and rec.lower_s):
+            # a lowering that never compiled (an analysis view, a test)
+            # leaves no phase in the next compile's record
+            rec = _OPEN[key] = ProgramCompile()
+        setattr(rec, field, getattr(rec, field) + duration)
+        if name == COMPILE_EVENT:
+            rec.cache_hit = getattr(_LOCAL, "cache_hit", 0.0)
+            _LOCAL.cache_hit = 0.0
+            del _OPEN[key]
+            _DONE[key] = (_DONE.get(key, (0, None))[0] + 1, rec)
 
 
-def _install_listeners() -> bool:
-    """Register the global dispatchers once. Returns whether monitoring is
-    observable on this jax (callers report honestly when it is not)."""
+def install_listeners() -> bool:
+    """Register the process-wide listener once. Returns whether monitoring
+    is observable on this jax (callers report honestly when it is not)."""
     global _INSTALLED
     with _LOCK:
         if _INSTALLED:
@@ -50,11 +146,13 @@ def _install_listeners() -> bool:
         import jax
 
         mon = getattr(jax, "monitoring", None)
-        if mon is None or not hasattr(mon, "register_event_listener"):
+        if mon is None or not hasattr(
+                mon, "register_event_duration_secs_listener"):
             return False
+        if hasattr(mon, "register_scalar_listener"):
+            mon.register_scalar_listener(_on_scalar)
         mon.register_event_listener(_on_event)
-        if hasattr(mon, "register_event_duration_secs_listener"):
-            mon.register_event_duration_secs_listener(_on_duration)
+        mon.register_event_duration_secs_listener(_on_duration)
         _INSTALLED = True
         return True
 
@@ -65,7 +163,9 @@ class CompileFence:
     def __init__(self):
         #: traces per program name — the ``DecodeEngine.trace_counts`` twin
         self.trace_counts: dict[str, int] = {}
+        #: executables compiled or loaded while started, one each
         self.compile_events = 0
+        #: seconds of top-level compile phases while started
         self.compile_s = 0.0
         #: False when jax.monitoring cannot be observed on this jax —
         #: compile_events==0 then means "unobservable", not "no compiles"
@@ -74,7 +174,7 @@ class CompileFence:
     # ------------------------------------------------------------ lifecycle
 
     def start(self) -> None:
-        self.monitoring_available = _install_listeners()
+        self.monitoring_available = install_listeners()
         with _LOCK:
             if self not in _ACTIVE:
                 _ACTIVE.append(self)
@@ -103,12 +203,10 @@ class CompileFence:
 
     # ---------------------------------------------------- event ingestion
 
-    def _event(self, name: str) -> None:
-        if "compil" in name:
+    def _duration(self, name: str, duration: float, top: bool) -> None:
+        if name == COMPILE_EVENT:
             self.compile_events += 1
-
-    def _duration(self, name: str, duration: float) -> None:
-        if "/compile/" in name:
+        if top:
             self.compile_s += duration
 
     def snapshot(self) -> tuple[dict, int]:

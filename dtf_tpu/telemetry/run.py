@@ -33,7 +33,7 @@ from typing import Mapping, Optional
 from dtf_tpu._hostio import atomic_replace
 from dtf_tpu.telemetry.accounting import (GoodputTracker,
                                           device_peak_flops)
-from dtf_tpu.telemetry.fence import CompileFence
+from dtf_tpu.telemetry.fence import CompileFence, program_compile
 from dtf_tpu.telemetry.flight import FlightRecorder, StallWatchdog
 from dtf_tpu.telemetry.spans import SpanRecorder, step_annotation
 
@@ -92,6 +92,8 @@ class Telemetry:
         self._prev_sigterm = None
         self._active = False
         self._compile_accounted = 0.0   # compile_s already in the bucket
+        #: per program key, the compiles already filed (file_compiles)
+        self._filed: dict[str, int] = {}
 
     # -------------------------------------------------------- configuration
 
@@ -119,15 +121,36 @@ class Telemetry:
     def trace_counts(self) -> dict:
         return dict(self.fence.trace_counts)
 
+    def file_compiles(self, keys) -> None:
+        """File the latest compile of each program named in ``keys`` (the
+        names their traced functions have; ``Program.trace_name``) that
+        compiled since this object last filed it: one sample each of
+        ``setup_trace``, ``setup_lower``, ``setup_compile`` (seconds) and
+        ``setup_cache_hit`` (1.0 where the persistent cache served it) in
+        the SpanRecorder. Host dict reads of what the compile fence's
+        listener recorded; nothing touches a device."""
+        for key in dict.fromkeys(keys):
+            found = program_compile(key)
+            if found is None or found[0] <= self._filed.get(key, 0):
+                continue
+            self._filed[key], rec = found
+            self.spans.add("setup_trace", rec.trace_s)
+            self.spans.add("setup_lower", rec.lower_s)
+            self.spans.add("setup_compile", rec.compile_s)
+            self.spans.add("setup_cache_hit", rec.cache_hit)
+
     # ----------------------------------------------------------- lifecycle
 
     def start(self) -> None:
         """Open the run window: fence listeners, watchdog thread, SIGTERM
         postmortem hook (chained AFTER any already-installed handler, e.g.
-        PreemptionHook's — ours dumps, then theirs checkpoints)."""
+        PreemptionHook's — ours dumps, then theirs checkpoints). Files the
+        compile of every fenced program that compiled before it
+        (:meth:`file_compiles`: a train step warmed up ahead of ``fit``)."""
         if self._active:
             return
         self._active = True
+        self.file_compiles(self.fence.trace_counts)
         if self._t_start is None:
             self._t_start = self.clock()
         self._t_stop = None

@@ -19,12 +19,21 @@ step number into the XPlane timeline, so a ProfilerHook trace window lines
 up 1:1 with the host spans recorded for the same steps. Its serving twin is
 :func:`trace_annotation`: the ``dtf.serve.*`` / ``dtf.engine.*`` phases of
 a scheduler tick, written into the same timeline.
+
+The host's own account of a serve tick draws on two more clocks here:
+:func:`thread_preemptions` (the calling thread's involuntary context
+switches) and :func:`process_gc_pauses`, the process's garbage-collection
+pauses from one ``gc.callbacks`` hook, which under ``engine.annotate_traces``
+also writes each collection into the timeline as ``dtf.host.gc``.
 """
 
 from __future__ import annotations
 
 import collections
+import gc
+import resource
 import time
+import weakref
 from contextlib import contextmanager, nullcontext
 from typing import Iterator, Mapping
 
@@ -124,3 +133,71 @@ def trace_annotation(name: str, **ids):
     import jax
 
     return jax.profiler.TraceAnnotation(name, **ids)
+
+
+#: ``getrusage`` of the calling thread alone (Linux); elsewhere None
+_RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)
+
+
+def thread_preemptions() -> int:
+    """Involuntary context switches of the calling thread so far: times the
+    kernel took its core away while it could run (0 where the platform
+    keeps no per-thread count)."""
+    if _RUSAGE_THREAD is None:
+        return 0
+    return resource.getrusage(_RUSAGE_THREAD).ru_nivcsw
+
+
+class GcPauses:
+    """Seconds the process spent in garbage collection, from a
+    ``gc.callbacks`` hook: ``total_s`` is monotone, so a reader takes the
+    difference between two of its own readings, wherever the pauses fell.
+
+    While the engine of any watched scheduler has ``annotate_traces`` set,
+    each collection is also a ``dtf.host.gc`` span (arg ``generation``) in
+    the profiler's timeline, on the thread the collection ran on.
+    Schedulers are held weakly, so one that is dropped stops asking.
+    ``clock`` is injectable; :meth:`on_gc` is the hook (tests call it with
+    made-up phases)."""
+
+    def __init__(self, *, clock=time.perf_counter):
+        self._clock = clock
+        self.total_s = 0.0
+        self._t0 = None
+        self._span = None
+        self._schedulers = weakref.WeakSet()
+
+    def watch(self, scheduler) -> None:
+        """Write collections into the timeline while ``scheduler``'s
+        engine annotates."""
+        self._schedulers.add(scheduler)
+
+    def on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = self._clock()
+            if any(getattr(s.engine, "annotate_traces", False)
+                   for s in list(self._schedulers)):
+                self._span = trace_annotation(
+                    "dtf.host.gc", generation=info.get("generation", -1))
+                self._span.__enter__()
+            return
+        if self._t0 is not None:
+            self.total_s += self._clock() - self._t0
+            self._t0 = None
+        if self._span is not None:
+            span, self._span = self._span, None
+            span.__exit__(None, None, None)
+
+
+_GC_PAUSES = None
+
+
+def process_gc_pauses() -> GcPauses:
+    """The process's one :class:`GcPauses`, its hook installed in
+    ``gc.callbacks`` on the first call (a scheduler with telemetry makes
+    it; a process that never asks installs nothing)."""
+    global _GC_PAUSES
+    if _GC_PAUSES is None:
+        _GC_PAUSES = GcPauses()
+        gc.callbacks.append(_GC_PAUSES.on_gc)
+    return _GC_PAUSES

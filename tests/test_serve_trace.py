@@ -388,8 +388,10 @@ def test_traced_tick_writes_every_dtf_span_nested_under_its_cause(
     for name, start, dur in trace.host:
         by_name.setdefault(name, []).append((start, start + dur))
     assert set(DTF_SPANS) <= set(by_name), set(DTF_SPANS) - set(by_name)
-    # nothing else claims the vocabulary
-    assert {n for n in by_name if n.startswith("dtf.")} == set(DTF_SPANS)
+    # nothing else claims the vocabulary: the table, and the collections
+    # that happened to fall inside the traced stretch
+    assert {n for n in by_name if n.startswith("dtf.")} - {"dtf.host.gc"} \
+        == set(DTF_SPANS)
     for name, parent in DTF_SPANS.items():
         if parent is None:
             continue
@@ -412,6 +414,50 @@ def test_traced_tick_writes_every_dtf_span_nested_under_its_cause(
     assert rolled["serve_prefill_chunk"]["count"] == 10
     assert rolled["serve_decode"]["count"] == len(by_name["dtf.serve.decode"])
     assert not any(k.startswith("dtf.") for k in rolled)
+    # and the tick's host account, one sample of each a tick
+    for name in ("serve_tick_cpu", "serve_tick_preempted", "serve_tick_gc"):
+        assert rolled[name]["count"] == ticks, name
+
+
+def test_a_collection_inside_a_traced_tick_writes_one_dtf_host_gc(
+        tiny_engine, monkeypatch):
+    """A forced ``gc.collect()`` inside a decode step, with automatic
+    collection off so that it is the only one: one ``dtf.host.gc`` span in
+    the trace, inside a ``dtf.serve.tick``, and its pause in that tick's
+    ``serve_tick_gc`` sample."""
+    import gc
+
+    from benchmarks.lib import xtrace
+
+    decode = type(tiny_engine).decode
+    forced = []
+
+    def collecting(self, **kw):
+        if not forced:
+            forced.append(gc.collect())
+        return decode(self, **kw)
+
+    tel = Telemetry(watchdog=False)
+    sched = Scheduler(tiny_engine, telemetry=tel)
+    monkeypatch.setattr(type(tiny_engine), "decode", collecting)
+    monkeypatch.setattr(tiny_engine, "annotate_traces", True)
+    trace_dir = xtrace.start()
+    was_enabled = gc.isenabled()
+    gc.disable()
+    try:
+        _serve_some(sched)
+    finally:
+        if was_enabled:
+            gc.enable()
+        trace = xtrace.stop(trace_dir)
+    assert forced
+    gcs = [(s, s + d) for name, s, d in trace.host if name == "dtf.host.gc"]
+    ticks = [(s, s + d) for name, s, d in trace.host
+             if name == "dtf.serve.tick"]
+    assert len(gcs) == 1, gcs
+    assert any(ts <= gs and ge <= te for ts, te in ticks for gs, ge in gcs)
+    paused = tel.spans.rollup()["serve_tick_gc"]
+    assert paused["total_s"] > 0 and paused["p99_s"] == paused["total_s"]
 
 
 @pytest.mark.parametrize("telemetry,annotate,expect_some", [
@@ -425,7 +471,11 @@ def test_untraced_ticks_construct_no_annotation(tiny_engine, monkeypatch,
     """With ``telemetry=None`` and ``annotate_traces=False`` — how the
     benchmark measures and how a user's default serves — 20 ticks
     construct no ``jax.profiler.TraceAnnotation`` at all; each gate
-    switches on its own layer's spans and not the other's."""
+    switches on its own layer's spans and not the other's. A scheduler
+    without telemetry files nothing and installs no ``gc`` callback, so
+    the garbage-collection hook never annotates for it."""
+    import gc
+
     import jax
 
     made = []
@@ -435,12 +485,18 @@ def test_untraced_ticks_construct_no_annotation(tiny_engine, monkeypatch,
             made.append(name)
             super().__init__(name, **kw)
 
+    gc.collect()                   # no scheduler of an earlier test lingers
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counted)
+    hooks = list(gc.callbacks)
     sched = Scheduler(tiny_engine, telemetry=Telemetry(watchdog=False)
                       if telemetry else None)
     monkeypatch.setattr(tiny_engine, "annotate_traces", annotate)
     _serve_some(sched, n_ticks=20)
+    gc.collect()                   # a collection the hook, if any, sees
     assert sched.stats()["serve_completed"] == 5
+    if not telemetry:
+        assert gc.callbacks == hooks
+        assert not any(k.startswith("serve_tick_") for k in sched.stats())
     if not expect_some:
         assert made == []
         return

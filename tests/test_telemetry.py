@@ -12,6 +12,7 @@ The two contracts that anchor this file:
   idiom): observability must not re-serialize the sync-free loop.
 """
 
+import contextlib
 import json
 import os
 import signal
@@ -670,3 +671,219 @@ def test_quantile_convention():
     xs = list(range(100))
     assert quantile(xs, 0.5) == 50
     assert quantile(xs, 0.99) == 98
+
+
+# --------------------------------------------------------------------------
+# the host's own account: each program's compile, a serve tick's CPU time,
+# preemptions and garbage-collection pauses
+# --------------------------------------------------------------------------
+
+@contextlib.contextmanager
+def _compile_events():
+    """Every compile-phase duration JAX records until the block ends, as
+    ``(event, fun_name, seconds)`` — the raw stream the fence reads."""
+    seen = []
+
+    def listen(name, duration, **kw):
+        if "/jax/core/compile/" in name:
+            seen.append((name, kw.get("fun_name"), duration))
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        yield seen
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+
+
+def test_compile_events_count_each_executable_once():
+    """One jitted program's first call compiles ONE executable, however
+    many ``/jax/compilation_cache/*`` events its compile fires; a repeat
+    call compiles nothing."""
+    from dtf_tpu.core import executor  # noqa: F401  (installs the listener)
+    from dtf_tpu.telemetry.fence import CompileFence, program_compile
+
+    def fence_counts_one_program(x):
+        return x * 2.0 + 1.0
+
+    fence = CompileFence()
+    fence.start()
+    try:
+        f = jax.jit(fence_counts_one_program)
+        x = np.ones((4, 4), np.float32)
+        f(x)
+        assert fence.compile_events == 1
+        f(x)
+        assert fence.compile_events == 1
+    finally:
+        fence.stop()
+    count, rec = program_compile("fence_counts_one_program")
+    assert count == 1 and rec.trace_s > 0 and rec.lower_s > 0
+    assert rec.compile_s > 0
+
+
+@jax.jit
+def _nested_helper(x):
+    return jax.numpy.sin(x) * 2.0
+
+
+def test_nested_jits_are_counted_inside_their_parent():
+    """A step that calls a jitted helper and ``jnp`` functions: each fires
+    its own trace event, nested in the step's. The step's record holds its
+    own trace event alone, and the fence's seconds are the step's three
+    top-level phases — not the old sum, which counted nested traces
+    twice."""
+    from dtf_tpu.core import executor  # noqa: F401
+    from dtf_tpu.telemetry.fence import CompileFence, program_compile
+
+    def fence_step_with_helpers(x):
+        return _nested_helper(x) @ x + jax.numpy.cos(x).sum()
+
+    fence = CompileFence()
+    fence.start()
+    try:
+        with _compile_events() as seen:
+            jax.jit(fence_step_with_helpers)(np.ones((4, 4), np.float32))
+    finally:
+        fence.stop()
+    own = {name.rsplit("/", 1)[1]: d for name, fun, d in seen
+           if fun in ("fence_step_with_helpers",
+                      "jit(fence_step_with_helpers)")}
+    nested = [d for name, fun, d in seen
+              if name.endswith("jaxpr_trace_duration")
+              and fun == "_nested_helper"]
+    assert nested, seen                # the helper traced inside the step
+    _, rec = program_compile("fence_step_with_helpers")
+    assert rec.trace_s == own["jaxpr_trace_duration"]
+    assert rec.lower_s == own["jaxpr_to_mlir_module_duration"]
+    assert rec.compile_s == own["backend_compile_duration"]
+    assert fence.compile_events == 1
+    assert fence.compile_s == pytest.approx(sum(own.values()))
+    assert fence.compile_s < sum(d for _, _, d in seen)
+
+
+@pytest.fixture
+def temp_compile_cache(tmp_path):
+    """A persistent compilation cache of this test's own, caching every
+    program, restored afterwards."""
+    from jax._src import compilation_cache
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    compilation_cache.reset_cache()
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    try:
+        yield
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+
+
+def _cached_step():
+    """A new function object of one body each call: a new trace and
+    lowering, the same program for the persistent cache."""
+    def fence_cached_step(x):
+        return x * 3.0 - 1.0
+
+    return fence_cached_step
+
+
+def test_a_compile_the_cache_served_files_setup_cache_hit(temp_compile_cache):
+    tel = Telemetry(watchdog=False)
+    x = np.ones((8,), np.float32)
+    jax.jit(_cached_step())(x)
+    tel.file_compiles(["fence_cached_step"])
+    assert tel.spans.rollup()["setup_cache_hit"]["total_s"] == 0.0
+    jax.jit(_cached_step())(x)
+    tel.file_compiles(["fence_cached_step"])
+    roll = tel.spans.rollup()
+    # one sample a compile: the miss, then the load
+    assert roll["setup_cache_hit"]["count"] == 2
+    assert roll["setup_cache_hit"]["total_s"] == 1.0
+    for name in ("setup_trace", "setup_lower", "setup_compile"):
+        assert roll[name]["count"] == 2 and roll[name]["total_s"] > 0
+
+
+def test_telemetry_start_files_a_program_compiled_before_it_once(mesh8):
+    """The benchmark's train cell warms its step up before ``fit``: ``fit``'s
+    ``telemetry.start()`` files that compile, and a later start does not
+    file it again."""
+    tel = Telemetry(watchdog=False)
+    counted = tel.count_traces("fence_warmed_step", lambda x: x + 1.0)
+    jax.jit(counted)(np.ones((2,), np.float32))
+    assert "setup_trace" not in tel.spans.rollup()
+    tel.start()
+    tel.stop()
+    tel.start()
+    tel.stop()
+    roll = tel.spans.rollup()
+    for name in ("setup_trace", "setup_lower", "setup_compile",
+                 "setup_cache_hit"):
+        assert roll[name]["count"] == 1, name
+    assert roll["setup_trace"]["total_s"] > 0
+
+
+def test_scheduler_files_its_engines_compiles_at_construction():
+    """The engine's programs compile in its constructor, before the
+    scheduler's telemetry exists: the scheduler files each once."""
+    from dtf_tpu.core import executor
+    from dtf_tpu.serve.scheduler import Scheduler
+
+    engine = _StubEngine()
+    engine.programs = {}
+    for name in ("fence_engine_decode", "fence_engine_prefill"):
+        def body(x):
+            return x + 1.0
+
+        body.__name__ = name
+        prog = executor.program(name, body, table=engine.programs)
+        assert prog.trace_name == name
+        prog.aot(jax.ShapeDtypeStruct((2,), np.float32))
+    tel = Telemetry(watchdog=False)
+    Scheduler(engine, telemetry=tel)
+    roll = tel.spans.rollup()
+    assert roll["setup_compile"]["count"] == 2
+    assert roll["setup_cache_hit"]["count"] == 2
+
+
+def test_a_tick_files_its_exact_host_account():
+    """Fake clocks: the ticking thread's CPU seconds and involuntary
+    switches over each tick, and the garbage-collection pauses since the
+    previous tick's sample — one inside a decode step, one between ticks."""
+    from dtf_tpu.serve.scheduler import Request, Scheduler
+    from dtf_tpu.telemetry.spans import GcPauses
+
+    cpu = iter([10.0, 10.004, 20.0, 20.0025])
+    switches = iter([5, 7, 7, 7])
+    wall = [100.0]
+    pauses = GcPauses(clock=lambda: wall[0])
+
+    class CollectingEngine(_StubEngine):
+        def decode(self):
+            pauses.on_gc("start", {"generation": 2})
+            wall[0] += 0.003
+            pauses.on_gc("stop", {"generation": 2})
+            return super().decode()
+
+    tel = Telemetry(watchdog=False)
+    sched = Scheduler(CollectingEngine(), telemetry=tel,
+                      thread_clock=lambda: next(cpu),
+                      preemptions=lambda: next(switches),
+                      gc_pauses=pauses)
+    sched.submit(Request(prompt=[1, 2, 3], max_new=2))
+    sched.tick()
+    pauses.on_gc("start", {"generation": 0})       # between the two ticks
+    wall[0] += 0.0005
+    pauses.on_gc("stop", {"generation": 0})
+    sched.tick()
+    roll = tel.spans.rollup()
+    assert roll["serve_tick_cpu"]["count"] == 2
+    assert roll["serve_tick_cpu"]["total_s"] == pytest.approx(0.0065)
+    assert roll["serve_tick_preempted"]["total_s"] == 2
+    assert roll["serve_tick_gc"]["p99_s"] == pytest.approx(0.003)
+    assert roll["serve_tick_gc"]["total_s"] == pytest.approx(0.0035)
+    assert pauses.total_s == pytest.approx(0.0035)
