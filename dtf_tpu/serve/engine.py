@@ -45,7 +45,10 @@ step's telemetry scalars, a routed-expert model's counts — rides ONE int32
 vector (:func:`_pack_out`) whose copy to the host starts at dispatch and
 is awaited once (:meth:`DecodeEngine._read`); a chunk that is not a
 request's last reads nothing. ``counters["host_operands"]`` /
-``["device_reads"]`` count both and tests/test_serve.py fences them.
+``["device_reads"]`` count both and tests/test_serve.py fences them. A
+decode step's dispatch and read are two calls (``decode_begin``, then
+``decode(handle=)``), so a scheduler can dispatch the next step before it
+reads this one (the run-ahead tick).
 
 With ``prefix_pages > 0`` the engine additionally keeps a device **page
 pool** and two more AOT programs, ``page_save``/``page_load`` (fixed-shape
@@ -810,6 +813,9 @@ class DecodeEngine:
         #: and counts them; they rode the tokens' transfer, so a traced run
         #: transfers exactly what a measured run does.
         self._step_out = None
+        #: the packed output of the last prefill chunk dispatched, until
+        #: :meth:`prefill_wait` waits for it
+        self._chunk_out = None
         self.counters.update(
             {f"sampler_steps_{name}": 0 for name in _SAMPLER_PATHS})
         if base.experts is not None:
@@ -1098,6 +1104,7 @@ class DecodeEngine:
                     -1 if eos_id is None else eos_id, pad_id, seed)
                 self._state, out = self._prefill_c(
                     self._params, self._live(self._state), ops)
+                self._chunk_out = out
                 if last:
                     out.copy_to_host_async()
             self.counters["prefill_chunks"] += 1
@@ -1144,6 +1151,18 @@ class DecodeEngine:
                 self._spec_tok[slot] = tok
             return tok, done
 
+    def prefill_wait(self) -> None:
+        """Block until the prefill chunks dispatched so far have run on the
+        device: a wait on the last one's output, which copies nothing and
+        counts no read. A scheduler that runs decode ahead calls it last in
+        a tick, after the tick's decode step is dispatched, so a tick still
+        ends with its own prefill done and the next tick's first-token
+        read waits for one decode step at most, never for a tick of
+        chunks."""
+        out, self._chunk_out = self._chunk_out, None
+        if out is not None:
+            out.block_until_ready()
+
     def _draft_prefill_chunk(self, slot: int, prompt: Sequence[int],
                              chunk_i: int, start: int = 0) -> None:
         """One fixed-width chunk of the DRAFT model's prefill into
@@ -1176,38 +1195,72 @@ class DecodeEngine:
                                           **sampling)
         return out
 
-    def decode(self, *, trace_ids: Optional[Sequence[int]] = None):
-        """One masked token step across all slots.
+    def decode(self, *, trace_ids: Optional[Sequence[int]] = None,
+               handle=None):
+        """One masked token step across all slots, read on the host.
 
         Without a draft model: ``(tokens [n_slots], done [n_slots])`` as
         host arrays — the one device→host sync per generated token (EOS
-        and delivery decisions live on the host). With ``spec_k > 0`` the
+        and delivery decisions live on the host). ``handle`` names a step
+        :meth:`decode_begin` dispatched earlier, which this call collects
+        (the run-ahead tick's read of step k-1); without one the step is
+        dispatched here, ``decode_end(decode_begin())``, the synchronous
+        step. Every plain step's tokens reach the host through this one
+        method on either tick, so whatever stands in for ``decode`` (a
+        fault injector, a proxy) sees all of them. With ``spec_k > 0`` the
         step is SPECULATIVE — draft_all proposes k tokens per slot, the
         verify program scores all k+1 positions in one pass — and the
         return is ``(tokens [n_slots, k+1], done [n_slots, k+1],
         n_emit [n_slots])``: the scheduler delivers ``tokens[s, :n_emit
         [s]]`` per slot (still one sync per TICK, now worth up to k+1
         tokens). ``trace_ids`` (scheduler-threaded) names the requests
-        this step serves in the XPlane annotation."""
+        this step serves in the XPlane annotation; a collect writes none
+        (the run-ahead tick annotates its dispatch and collect together)."""
+        if handle is not None:
+            return self.decode_end(handle)
         with self._annotation(
                 "dtf.serve.decode",
                 trace_ids="" if trace_ids is None
                 else ",".join(map(str, trace_ids))):
             if self.spec_k:
                 return self._decode_spec()
-            with self._annotation("dtf.engine.decode.dispatch"):
-                self._state, out = self._decode_c(
-                    self._params, self._live(self._state))
-                out.copy_to_host_async()
-            self.counters["decode_steps"] += 1
-            with self._annotation("dtf.engine.decode.readback"):
-                toks, done, tail = self._read(out, self.n_slots)
-            n = len(_STEP_OUT_NAMES)
-            path, cache_positions, active = (int(x) for x in tail[:n])
-            self._step_out = (path, cache_positions, active)
-            if self.cfg.experts is not None:
-                self._note_moe(cache_positions, active, tail[n:])
-            return toks, done
+            return self.decode_end(self.decode_begin())
+
+    @property
+    def can_run_ahead(self) -> bool:
+        """Whether a scheduler may dispatch step n+1 (:meth:`decode_begin`)
+        before it collects step n (``decode(handle=)``): every plain
+        engine (``spec_k == 0``); the speculative step's acceptance
+        accounting reads each step before the next."""
+        return not self.spec_k
+
+    def decode_begin(self):
+        """Dispatch one masked token step across all slots (non-speculative
+        engines) and start its packed vector's copy to the host; returns
+        the handle ``decode(handle=)`` collects. Nothing is read: the host
+        may dispatch further calls before it collects this one."""
+        with self._annotation("dtf.engine.decode.dispatch"):
+            self._state, out = self._decode_c(
+                self._params, self._live(self._state))
+            out.copy_to_host_async()
+        self.counters["decode_steps"] += 1
+        return out
+
+    def decode_end(self, handle):
+        """Collect the step :meth:`decode_begin` dispatched (for
+        :meth:`decode`, through which every caller collects): THE blocking
+        read of its packed vector, ``(tokens [n_slots], done [n_slots])``
+        as host arrays. The step's telemetry scalars and a routed-expert
+        model's counts are noted here, when the step is collected, for
+        :meth:`take_samples`."""
+        with self._annotation("dtf.engine.decode.readback"):
+            toks, done, tail = self._read(handle, self.n_slots)
+        n = len(_STEP_OUT_NAMES)
+        path, cache_positions, active = (int(x) for x in tail[:n])
+        self._step_out = (path, cache_positions, active)
+        if self.cfg.experts is not None:
+            self._note_moe(cache_positions, active, tail[n:])
+        return toks, done
 
     def _note_moe(self, cache_positions: int, active: int,
                   layer_stats: np.ndarray) -> None:

@@ -295,8 +295,10 @@ def install_serve_fault(plan: ServeFaultPlan, pump, *, sleep=time.sleep,
     """Arm a serve fault on a live Router or Scheduler (``pump``).
 
     - ``wedge_replica@N[:replica=k]`` — from the target engine's N-th
-      decode call on, every decode sleeps ``wedge_s`` (env
-      ``DTF_FAULT_WEDGE_S``, default 0.75): alive but useless, exactly the
+      decode call on (``decode_begin`` on an engine that
+      ``can_run_ahead``, else ``decode``: the call that dispatches every
+      step of the tick and every probe), every decode sleeps ``wedge_s``
+      (env ``DTF_FAULT_WEDGE_S``, default 0.75): alive but useless, exactly the
       signature the health watchdog must quarantine on.
     - ``slow_decode@N[:replica=k]`` — same shape, shorter ``slow_s``
       sleeps (env ``DTF_FAULT_SLOW_S``, default 0.2): degrades without
@@ -510,7 +512,9 @@ def install_serve_fault(plan: ServeFaultPlan, pump, *, sleep=time.sleep,
         if plan.replica is not None and plan.replica != k:
             continue
         eng = s.engine
-        orig = eng.decode
+        name = ("decode_begin" if getattr(eng, "can_run_ahead", False)
+                else "decode")
+        orig = getattr(eng, name)
         calls = [0]
 
         def decode(*, _orig=orig, _calls=calls, _k=k, **kw):
@@ -522,7 +526,7 @@ def install_serve_fault(plan: ServeFaultPlan, pump, *, sleep=time.sleep,
                 sleep(delay)
             return _orig(**kw)
 
-        eng.decode = decode
+        setattr(eng, name, decode)
     return state
 
 

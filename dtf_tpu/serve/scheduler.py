@@ -8,6 +8,19 @@ prefill over several ticks instead of stalling everyone's decode), then one
 ``max_new``, or when the slot's ``max_len`` budget fills; the freed slot is
 immediately reusable next tick — the continuous-batching loop.
 
+The decode step runs one ahead where the engine allows it
+(:attr:`~dtf_tpu.serve.engine.DecodeEngine.can_run_ahead`, true for every
+engine without a draft model): a tick dispatches step k
+(``decode_begin``), then collects step k-1 — dispatched by the tick
+before — through ``decode(handle=)`` and delivers its tokens, so the
+device works through the host's reading and scheduling instead of waiting
+for them. Prefill is the synchronous tick's to the decision: the last
+chunk's first token is read, the slot starts running and the next queued
+request is admitted within the same tick. Token streams are those of the synchronous tick; a request's decode
+tokens arrive one tick later, and a retired slot's one extra decoded token
+is discarded. A speculative engine keeps the synchronous tick, as does an
+engine object that declares no ``can_run_ahead``.
+
 Observability rides :class:`dtf_tpu.metrics.MetricWriter` (the training
 stack's writer): queue depth and slot occupancy per logging interval, plus
 per-request TTFT and per-token latency on completion. ``stats()`` returns
@@ -75,8 +88,8 @@ import weakref
 from typing import Optional, Sequence
 
 from dtf_tpu.metrics import quantile as _quantile
-from dtf_tpu.telemetry.spans import (process_gc_pauses, thread_preemptions,
-                                     trace_annotation)
+from dtf_tpu.telemetry.spans import (NO_SPAN, process_gc_pauses,
+                                     thread_preemptions, trace_annotation)
 
 log = logging.getLogger("dtf_tpu")
 
@@ -265,6 +278,9 @@ class Scheduler:
         self._queue: collections.deque[_Rec] = collections.deque()
         self._admitting: Optional[_Rec] = None
         self._running: dict[int, _Rec] = {}
+        #: the decode step a run-ahead tick dispatched and has not yet
+        #: collected: (engine handle, {slot: record} it serves), or None
+        self._inflight: Optional[tuple] = None
         self._recs: dict[int, _Rec] = {}
         self._done_order: collections.deque[int] = collections.deque()
         self._next_id = 0
@@ -365,18 +381,23 @@ class Scheduler:
 
     @property
     def pending(self) -> int:
-        """Requests not yet finished (queued + prefilling + running)."""
+        """Requests not yet finished (queued + prefilling + running), plus
+        one for a decode step in flight: a run ends with every token
+        delivered."""
         return (len(self._queue) + (self._admitting is not None)
-                + len(self._running))
+                + len(self._running) + (self._inflight is not None))
 
     # ------------------------------------------------------------------ tick
 
     def tick(self) -> None:
         """One scheduling round: deadline sweep, bounded prefill, then one
-        decode step. With telemetry the round is a ``dtf.serve.tick`` span
-        on the profiler's clock (``jax.profiler.TraceAnnotation``, reached
-        through telemetry/spans.py so this module stays jax-free at
-        import), and the host's account of the tick is filed after it: one
+        decode step — on an engine that ``can_run_ahead``, dispatched
+        before the previous tick's step is collected and delivered
+        (docs/SERVING.md, "The tick runs one decode step ahead"). With
+        telemetry the round is a ``dtf.serve.tick`` span on the profiler's
+        clock (``jax.profiler.TraceAnnotation``, reached through
+        telemetry/spans.py so this module stays jax-free at import), and
+        the host's account of the tick is filed after it: one
         sample each of ``serve_tick_cpu``, ``serve_tick_preempted`` and
         ``serve_tick_gc``; without, the one attribute test is all it
         costs."""
@@ -483,55 +504,126 @@ class Scheduler:
                     rec.status = "running"
                     self._running[rec.slot] = rec
 
-        if self._running:
-            if self.telemetry is None \
-                    and not getattr(self.engine, "annotate_traces", False):
-                # hottest loop, telemetry off: no per-token id-list /
-                # targs allocation for data nothing would consume
-                out = self.engine.decode()
-            else:
-                active = [r.trace_id for r in self._running.values()]
-                ekw = ({"trace_ids": active}
-                       if getattr(self.engine, "annotate_traces", False)
-                       else {})
-                out = self._timed(
-                    "serve_decode", self.engine.decode,
-                    targs={"trace_ids": active}, **ekw)
-            now = self.clock()
-            spec_k = getattr(self.engine, "spec_k", 0)
-            if spec_k:
-                # SPECULATIVE tick: up to k+1 tokens per slot, delivered
-                # in order until the row's eos or budget — exactly the
-                # sequence n_emit plain ticks would have delivered.
-                toks, dones, n_emit = out
-                ver = int(getattr(self.engine, "param_version", 0) or 0)
-                bucket = self._accept_by_version.setdefault(ver, [0, 0])
-                for slot, rec in list(self._running.items()):
-                    n = int(n_emit[slot])
-                    self._spec_proposed += spec_k
-                    self._spec_accepted += n - 1
-                    rec.proposed += spec_k
-                    rec.accepted += n - 1
-                    bucket[0] += spec_k
-                    bucket[1] += n - 1
-                    for j in range(n):
-                        rec.tokens.append(int(toks[slot, j]))
-                        if bool(dones[slot, j]) or self._budget_spent(rec):
-                            rec.finish_t = now
-                            self._finish(rec)
-                            break
-            else:
-                toks, dones = out
-                for slot, rec in list(self._running.items()):
-                    rec.tokens.append(int(toks[slot]))
-                    if bool(dones[slot]) or self._budget_spent(rec):
-                        rec.finish_t = now
-                        self._finish(rec)
+        if getattr(self.engine, "can_run_ahead", False):
+            self._decode_ahead()
+        elif self._running:
+            self._decode_sync()
         self._occupancy_sum += self._occupancy()
 
         if (self.writer is not None and self.log_every
                 and self._tick % self.log_every == 0):
             self.writer.write_scalars(self._tick, self.stats(brief=True))
+
+    def _decode_ahead(self) -> None:
+        """The decode half of a run-ahead tick: dispatch step k for the
+        running slots, then collect step k-1, dispatched by the previous
+        tick, and deliver its tokens — so the device has step k queued
+        while the host reads k-1 and schedules; then, outside the decode
+        span, wait for the tick's own prefill chunks (``prefill_wait``),
+        so the next tick's first-token read waits for step k at most. The
+        prefill phase before it is the synchronous tick's, decision for
+        decision. A slot that step k-1 retires was decoded by step k too;
+        that token lands in the slot's own row (the reset contract) and is
+        discarded. No step is dispatched where no running request can
+        still take a token once the step in flight is in (nothing runs,
+        or every row's budget is spent by it): the tick then only
+        collects."""
+        prev = self._inflight
+        ahead = prev[1] if prev is not None else {}
+        dispatch = any(not self._budget_spent(rec, ahead.get(slot) is rec)
+                       for slot, rec in self._running.items())
+        if dispatch or prev is not None:
+            annotate = getattr(self.engine, "annotate_traces", False)
+            if self.telemetry is None and not annotate:
+                # hottest loop, telemetry off: no per-token id-list / targs
+                # allocation for data nothing would consume
+                self._step_ahead(dispatch, prev, NO_SPAN)
+            else:
+                active = [r.trace_id for r in self._running.values()] \
+                    if dispatch else []
+                span = (trace_annotation("dtf.serve.decode",
+                                         trace_ids=",".join(map(str, active)))
+                        if annotate else NO_SPAN)
+                self._timed("serve_decode", self._step_ahead, dispatch, prev,
+                            span, targs={"trace_ids": active})
+        # the tick's own chunks, queued ahead of step k: a tick ends with its
+        # prefill done, as the synchronous tick does, so ticks keep their
+        # length (none absorbs the chunks of the tick before it)
+        self.engine.prefill_wait()
+
+    def _step_ahead(self, dispatch: bool, prev, span) -> None:
+        with span:
+            if dispatch:
+                handle = self.engine.decode_begin()
+                # the {slot: record} the step serves: a record retired
+                # before it is collected gets none of its tokens
+                self._inflight = (handle, dict(self._running))
+                if self.telemetry is not None:
+                    self.telemetry.spans.add("serve_decode_runahead",
+                                             float(prev is not None))
+            else:
+                self._inflight = None
+            if prev is not None:
+                self._deliver(self.engine.decode(handle=prev[0]), prev[1])
+
+    def _decode_sync(self) -> None:
+        """The decode half of a synchronous tick (a speculative engine, or
+        an engine that offers no run-ahead): one step, dispatched and read
+        before the tick ends."""
+        if self.telemetry is None \
+                and not getattr(self.engine, "annotate_traces", False):
+            # hottest loop, telemetry off: no per-token id-list /
+            # targs allocation for data nothing would consume
+            out = self.engine.decode()
+        else:
+            active = [r.trace_id for r in self._running.values()]
+            ekw = ({"trace_ids": active}
+                   if getattr(self.engine, "annotate_traces", False)
+                   else {})
+            out = self._timed(
+                "serve_decode", self.engine.decode,
+                targs={"trace_ids": active}, **ekw)
+            if self.telemetry is not None:
+                self.telemetry.spans.add("serve_decode_runahead", 0.0)
+        spec_k = getattr(self.engine, "spec_k", 0)
+        if not spec_k:
+            self._deliver(out, self._running)
+            return
+        # SPECULATIVE tick: up to k+1 tokens per slot, delivered in order
+        # until the row's eos or budget — exactly the sequence n_emit
+        # plain ticks would have delivered.
+        now = self.clock()
+        toks, dones, n_emit = out
+        ver = int(getattr(self.engine, "param_version", 0) or 0)
+        bucket = self._accept_by_version.setdefault(ver, [0, 0])
+        for slot, rec in list(self._running.items()):
+            n = int(n_emit[slot])
+            self._spec_proposed += spec_k
+            self._spec_accepted += n - 1
+            rec.proposed += spec_k
+            rec.accepted += n - 1
+            bucket[0] += spec_k
+            bucket[1] += n - 1
+            for j in range(n):
+                rec.tokens.append(int(toks[slot, j]))
+                if bool(dones[slot, j]) or self._budget_spent(rec):
+                    rec.finish_t = now
+                    self._finish(rec)
+                    break
+
+    def _deliver(self, out, served: dict) -> None:
+        """One plain decode step's ``(tokens, done)`` to the records of
+        ``served`` (``{slot: record}`` as the step was dispatched) that
+        still hold their slot; eos and budget retire as they land."""
+        toks, dones = out
+        now = self.clock()
+        for slot, rec in list(served.items()):
+            if self._running.get(slot) is not rec:
+                continue                 # retired since: the token is void
+            rec.tokens.append(int(toks[slot]))
+            if bool(dones[slot]) or self._budget_spent(rec):
+                rec.finish_t = now
+                self._finish(rec)
 
     def run_until_idle(self, max_ticks: int = 100000, *,
                        on_tick=None) -> None:
@@ -585,9 +677,12 @@ class Scheduler:
                                 tid="engine" if tid is None else tid,
                                 t0_us=t0, t1_us=tracer.now_us(), args=targs)
 
-    def _budget_spent(self, rec: _Rec) -> bool:
-        return (len(rec.tokens) >= rec.req.max_new
-                or len(rec.req.prompt) + len(rec.tokens) >= self.engine.max_len)
+    def _budget_spent(self, rec: _Rec, ahead: int = 0) -> bool:
+        """Whether ``rec`` has all its tokens — once ``ahead`` more (a
+        step in flight that serves it) are in."""
+        n = len(rec.tokens) + ahead
+        return (n >= rec.req.max_new
+                or len(rec.req.prompt) + n >= self.engine.max_len)
 
     def _occupancy(self) -> float:
         return 1.0 - len(self._free) / self.engine.n_slots
@@ -738,9 +833,12 @@ class Scheduler:
         marked ``requeued`` here as tombstones; their prefix pins are
         released (host-side index work — safe against a wedged engine),
         tokens are cleared (survivors regenerate the full deterministic
-        stream), and every slot is freed. The engine's device state needs
-        no touch: stale slots are masked spectators until re-admission
-        resets them."""
+        stream), and every slot is freed. A decode step still in flight is
+        dropped unread: its tokens belong to records requeued here, and
+        the device orders whatever the engine runs next behind it. The
+        engine's device state needs no touch: stale slots are masked
+        spectators until re-admission resets them."""
+        self._inflight = None
         recs = list(self._queue)
         if self._admitting is not None:
             recs.append(self._admitting)
@@ -769,7 +867,9 @@ class Scheduler:
     def release(self, rid: int) -> None:
         """Drop a completed request's record (tokens included) — call after
         consuming the result to keep a long-running server's host memory
-        flat without relying on the completed_cap backstop."""
+        flat without relying on the completed_cap backstop. A decode step
+        in flight is left to the next tick: it delivers only to records
+        that still hold their slot, which a completed one does not."""
         rec = self._recs.get(rid)
         if rec is not None and rec.status == "done":
             self._recs.pop(rid, None)
@@ -795,6 +895,9 @@ class Scheduler:
             if rec.slot >= 0:
                 slot_ages[str(rec.slot)] = round(now - rec.submit_t, 3)
         return {"in_flight": in_flight,
+                # a run-ahead step dispatched and not yet collected: named,
+                # never read (the read is a device call)
+                "decode_step_in_flight": self._inflight is not None,
                 "queue_depth": len(self._queue),
                 "occupancy": round(self._occupancy(), 4),
                 "slot_ages_s": slot_ages,

@@ -749,3 +749,273 @@ def test_decode_attn_live_pct_counter(params):
     assert roll["total_s"] == pytest.approx(
         sum(100.0 * r / MAX_LEN for r in reads), rel=1e-5)
     assert served(None)._step_out is not None      # left for an asker
+
+
+# ---- the run-ahead tick: decode one step ahead, prefill as it was ----------
+
+class _Clock:
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        return self.t
+
+
+class _Synchronous:
+    """An engine behind an object that declares no ``can_run_ahead``, as
+    a duck-typed engine does: the scheduler runs its synchronous tick."""
+
+    def __init__(self, eng):
+        self._eng = eng
+
+    def __getattr__(self, name):
+        if name == "can_run_ahead":
+            raise AttributeError(name)
+        return getattr(self._eng, name)
+
+
+def _prefill_script(eng):
+    """A running request, then two three-chunk prompts submitted together
+    on a budget of 4 chunks a tick, on a fake clock that moves 1 s a tick:
+    the prefill facts of every request after every tick, and the
+    scheduler."""
+    clk = _Clock()
+    sched = Scheduler(eng, clock=clk, prefill_chunks_per_tick=4)
+    rids = [sched.submit(Request(prompt=[1, 2, 3], max_new=20))]
+    seen = []
+    for tick in range(1, 9):
+        if tick == 2:
+            rids += [sched.submit(Request(prompt=list(range(1, 11)),
+                                          max_new=5)),
+                     sched.submit(Request(prompt=list(range(20, 30)),
+                                          max_new=4, temperature=0.8,
+                                          seed=7))]
+        sched.tick()
+        recs = [sched._recs[rid] for rid in rids]
+        seen.append([(r.chunks_done, r.first_token_tick, r.first_token_t,
+                      r.tokens[:1]) for r in recs])
+        clk.t += 1.0
+    sched.run_until_idle()
+    return seen, sched, rids
+
+
+def test_run_ahead_tick_keeps_the_synchronous_prefill(params):
+    """The guard on first tokens: with a step in flight, a request whose
+    last chunk runs in tick t has its first token in ``poll()`` after tick
+    t (stamped with tick t's clock), and a queued request's chunk 0 runs in
+    that tick's remaining budget — tick for tick what the synchronous
+    tick does. Token streams match offline generate()."""
+    eng = DecodeEngine(CFG, params, n_slots=4, max_len=MAX_LEN,
+                       prefill_chunk=4)
+    assert eng.can_run_ahead
+    seen, sched, (r0, a, b) = _prefill_script(eng)
+    tick2 = seen[1]
+    # tick 2: a's three chunks, the last reads its first token; b's chunk 0
+    assert tick2[1][:3] == (3, 2, 1.0)
+    assert sched.poll(a)["tokens"][:1] == tick2[1][3] != []
+    assert tick2[2][:2] == (1, None)
+    assert seen[2][2][:3] == (3, 3, 2.0)                # b's first: tick 3
+    ref, _, _ = _prefill_script(_Synchronous(DecodeEngine(
+        CFG, params, n_slots=4, max_len=MAX_LEN, prefill_chunk=4)))
+    assert seen == ref
+    for rid, req in ((r0, dict(prompt=[1, 2, 3], max_new=20)),
+                     (a, dict(prompt=list(range(1, 11)), max_new=5)),
+                     (b, dict(prompt=list(range(20, 30)), max_new=4,
+                              temperature=0.8, seed=7))):
+        assert sched.poll(rid)["tokens"] == _offline(params, req), rid
+
+
+def test_run_ahead_tick_order(params):
+    """A run-ahead tick is: the prefill budget (chunks, the last one's
+    first token read in the tick), step k's dispatch, step k-1's
+    collection through ``decode(handle=)``, then a wait for the tick's own
+    chunks — so no tick ends with chunks queued for the next tick's
+    first-token read to absorb."""
+    eng = DecodeEngine(CFG, params, n_slots=2, max_len=MAX_LEN,
+                       prefill_chunk=4)
+    calls = []
+    for name in ("prefill_chunk_into", "decode_begin", "decode",
+                 "prefill_wait"):
+        def wrapped(*a, _f=getattr(eng, name), _n=name, **kw):
+            calls.append(_n)
+            return _f(*a, **kw)
+        setattr(eng, name, wrapped)
+    assert eng.can_run_ahead
+    sched = Scheduler(eng, prefill_chunks_per_tick=2)
+    sched.submit(Request(prompt=[1, 2, 3], max_new=6))
+    sched.submit(Request(prompt=list(range(1, 13)), max_new=3))
+    ticks = []
+    while sched.pending:
+        calls.clear()
+        sched.tick()
+        ticks.append(list(calls))
+        assert eng._chunk_out is None             # the tick's chunks ran
+    chunk, begin, end, wait = ("prefill_chunk_into", "decode_begin",
+                               "decode", "prefill_wait")
+    assert ticks[0] == [chunk, chunk, begin, wait]
+    assert ticks[1] == [chunk, chunk, begin, end, wait]
+    for tick in ticks[2:-1]:
+        assert tick == [begin, end, wait], tick
+    assert ticks[-1] == [end, wait]
+
+
+def test_run_ahead_drains_and_reads_once_a_step(params):
+    """A run ends with no step in flight (``pending`` counts one), every
+    step dispatched is read back once, and what crossed is the transfer
+    fence's: one read a decode step and one a request's last chunk, one
+    operand a chunk and none a step."""
+    eng = DecodeEngine(CFG, params, n_slots=2, max_len=MAX_LEN,
+                       prefill_chunk=4)
+    sched = Scheduler(eng)
+    reqs = [dict(prompt=[5, 9, 2, 44, 1], max_new=7),
+            dict(prompt=[7, 7], max_new=3),
+            dict(prompt=[1, 2, 3], max_new=5)]
+    rids = [sched.submit(Request(**r)) for r in reqs]
+    sched.tick()
+    assert sched._inflight is not None
+    sched.run_until_idle()
+    assert sched._inflight is None and sched.pending == 0
+    c = eng.counters
+    assert c["device_reads"] == c["decode_steps"] + len(reqs)
+    assert c["host_operands"] == c["prefill_chunks"]
+    for r, rid in zip(reqs, rids):
+        assert sched.poll(rid)["tokens"] == _offline(params, r)
+    handle = eng.decode_begin()                    # the halves, one each
+    assert (c["host_operands"], c["device_reads"]) == (
+        c["prefill_chunks"], c["decode_steps"] - 1 + len(reqs))
+    eng.decode(handle=handle)
+    assert c["device_reads"] == c["decode_steps"] + len(reqs)
+
+
+def test_run_ahead_eviction_drops_the_step_and_retired_records_get_nothing(
+        params):
+    """``evict_for_requeue`` drops the step in flight unread (no device
+    call on a replica being drained) and delivers none of its tokens, and
+    the replica serves on; a record retired while a
+    step that serves it is in flight (a deadline here) keeps the tokens it
+    had; ``release`` of a done record leaves the step in flight to the
+    next tick, which delivers to running records only."""
+    clk = _Clock()
+    eng = DecodeEngine(CFG, params, n_slots=2, max_len=MAX_LEN,
+                       prefill_chunk=4)
+    sched = Scheduler(eng, clock=clk)
+    short = dict(prompt=[4, 4, 4], max_new=2)
+    long = dict(prompt=[7, 7], max_new=12)
+    timed = sched.submit(Request(prompt=[5, 9, 2], max_new=20,
+                                 deadline_s=5.0))
+    done = sched.submit(Request(**short))
+    for _ in range(3):
+        sched.tick()
+    assert sched.poll(done)["status"] == "done"
+    assert sched._inflight is not None
+    sched.release(done)                            # the step stays in flight
+    assert sched._inflight is not None
+    n = len(sched.poll(timed)["tokens"])
+    assert sched._running[sched._recs[timed].slot] \
+        is sched._inflight[1][sched._recs[timed].slot]
+    clk.t = 10.0
+    kept = sched.submit(Request(**long))
+    sched.tick()                   # the sweep retires it; step k-1 collected
+    assert sched.poll(timed)["status"] == "timeout"
+    assert len(sched.poll(timed)["tokens"]) == n
+    for _ in range(2):
+        sched.tick()
+    assert sched._inflight is not None
+    reads = eng.counters["device_reads"]
+    recs = sched.evict_for_requeue()
+    assert sched._inflight is None and sched.pending == 0
+    assert eng.counters["device_reads"] == reads
+    assert [r.rid for r in recs] == [kept]
+    assert recs[0].tokens == [] and recs[0].status == "requeued"
+    assert len(sched.poll(timed)["tokens"]) == n
+    # the replica serves on: a fresh request matches offline
+    again = sched.submit(Request(**long))
+    sched.run_until_idle()
+    assert sched.poll(again)["tokens"] == _offline(params, long)
+
+
+def test_decode_runahead_counter(params):
+    """``serve_decode_runahead``, one sample a decode step under
+    ``Scheduler(telemetry=)``: 1 on every step of a busy run after the
+    first (dispatched with its predecessor uncollected), and 0 on every
+    step of a synchronous tick; the tokens are the same."""
+    from dtf_tpu.telemetry import Telemetry
+
+    reqs = [dict(prompt=[1, 2, 3], max_new=8), dict(prompt=[4, 5], max_new=6)]
+
+    def served(telemetry, sync=False):
+        eng = DecodeEngine(CFG, params, n_slots=2, max_len=MAX_LEN,
+                           prefill_chunk=4)
+        sched = Scheduler(_Synchronous(eng) if sync else eng,
+                          telemetry=telemetry)
+        rids = [sched.submit(Request(**r)) for r in reqs]
+        sched.run_until_idle()
+        return eng, [sched.poll(rid)["tokens"] for rid in rids]
+
+    tel = Telemetry(watchdog=False)
+    eng, ahead = served(tel)
+    roll = tel.spans.rollup()["serve_decode_runahead"]
+    assert roll["count"] == eng.counters["decode_steps"] == 7
+    assert roll["total_s"] == roll["count"] - 1
+    tel = Telemetry(watchdog=False)
+    eng, sync = served(tel, sync=True)
+    roll = tel.spans.rollup()["serve_decode_runahead"]
+    assert roll["count"] == eng.counters["decode_steps"]
+    assert roll["total_s"] == 0.0
+    assert ahead == sync == [_offline(params, r) for r in reqs]
+    assert served(None)[1] == ahead
+
+
+def test_slow_decode_fault_wraps_the_run_ahead_dispatch(params):
+    """The serve fault verbs that slow every decode wrap the call that
+    dispatches each step, ``decode_begin`` on an engine that runs ahead
+    (which it still does): one delay a step, counted from the first tick
+    as on the synchronous tick, seen by the tick and by ``probe`` alike."""
+    from dtf_tpu.fault.inject import ServeFaultPlan
+    from dtf_tpu.serve import install_serve_fault
+
+    eng = DecodeEngine(CFG, params, n_slots=2, max_len=MAX_LEN,
+                       prefill_chunk=4)
+    sched = Scheduler(eng)
+    slept = []
+    install_serve_fault(ServeFaultPlan.parse("slow_decode@0"), sched,
+                        sleep=slept.append, slow_s=0.01)
+    assert eng.can_run_ahead and "decode_begin" in vars(eng)
+    req = dict(prompt=[3, 1, 4], max_new=4)
+    rid = sched.submit(Request(**req))
+    sched.tick()
+    assert sched._inflight is not None
+    sched.run_until_idle()
+    assert len(slept) == eng.counters["decode_steps"] == 3
+    eng.probe()
+    assert len(slept) == 4
+    assert sched.poll(rid)["tokens"] == _offline(params, req)
+
+
+def test_a_token_altered_in_decode_reaches_the_run_ahead_delivery(
+        params, monkeypatch):
+    """Every decode token the run-ahead tick delivers comes out of
+    ``DecodeEngine.decode``: a step that hands back the token after the one
+    it chose alters each delivered decode token (the first token, read at
+    the last chunk, is not), and the engine still runs ahead."""
+    reqs = [dict(prompt=[2, 7, 1], max_new=9), dict(prompt=[8, 2], max_new=5)]
+    refs = [_offline(params, r) for r in reqs]
+    decode = DecodeEngine.decode
+
+    def altered(self, **kw):
+        toks, done = decode(self, **kw)
+        return (toks + 1) % self.cfg.vocab_size, done
+
+    monkeypatch.setattr(DecodeEngine, "decode", altered)
+    eng = DecodeEngine(CFG, params, n_slots=2, max_len=MAX_LEN,
+                       prefill_chunk=4)
+    assert eng.can_run_ahead
+    sched = Scheduler(eng)
+    rids = [sched.submit(Request(**r)) for r in reqs]
+    sched.tick()
+    sched.tick()
+    assert sched._inflight is not None
+    sched.run_until_idle()
+    for ref, rid in zip(refs, rids):
+        assert sched.poll(rid)["tokens"] == ref[:1] + [
+            (t + 1) % CFG.vocab_size for t in ref[1:]]

@@ -157,6 +157,26 @@ def test_spec_eos_and_budget_edges(params, spec_engine):
                                                      eos_id=eos), r
 
 
+def test_spec_engine_keeps_the_synchronous_tick(params, spec_engine):
+    """A speculative engine does not run ahead (its acceptance accounting
+    reads each step before the next): every tick reads its own step, and
+    ``serve_decode_runahead`` reads 0 on each."""
+    from dtf_tpu.telemetry import Telemetry
+
+    assert not spec_engine.can_run_ahead
+    tel = Telemetry(watchdog=False)
+    sched = Scheduler(spec_engine, telemetry=tel)
+    steps0 = spec_engine.counters["decode_steps"]
+    req = dict(prompt=[3, 1, 4, 1, 5], max_new=7)
+    rid = sched.submit(Request(**req))
+    sched.run_until_idle()
+    assert sched._inflight is None
+    roll = tel.spans.rollup()["serve_decode_runahead"]
+    assert roll["count"] == spec_engine.counters["decode_steps"] - steps0
+    assert roll["total_s"] == 0.0
+    assert sched.poll(rid)["tokens"] == _offline(params, req)
+
+
 @pytest.mark.slow
 def test_spec_int8_matches_nonspec_int8():
     """int8 KV: the verify branch reads its own in-chunk keys back

@@ -377,6 +377,7 @@ def test_traced_tick_writes_every_dtf_span_nested_under_its_cause(
     from benchmarks.lib import xtrace
 
     sched = Scheduler(tiny_engine, telemetry=Telemetry(watchdog=False))
+    steps0 = tiny_engine.counters["decode_steps"]
     tiny_engine.annotate_traces = True
     try:
         trace_dir = xtrace.start()
@@ -399,11 +400,17 @@ def test_traced_tick_writes_every_dtf_span_nested_under_its_cause(
             assert any(ps <= s and e <= pe for ps, pe in by_name[parent]), (
                 name, parent)
     ticks = len(by_name["dtf.serve.tick"])
-    # at most one decode a tick, and one of each of its phases a decode
+    # at most one decode a tick; the tick runs one step ahead, so a decode
+    # holds at most one dispatch (step k) and one readback (step k-1), and
+    # every step dispatched is read back by the time the run drains
     assert len(by_name["dtf.serve.decode"]) <= ticks
+    for phase in ("dispatch", "readback"):
+        inner = by_name[f"dtf.engine.decode.{phase}"]
+        for ps, pe in by_name["dtf.serve.decode"]:
+            assert sum(ps <= s and e <= pe for s, e in inner) <= 1, phase
     assert (len(by_name["dtf.engine.decode.dispatch"])
             == len(by_name["dtf.engine.decode.readback"])
-            == len(by_name["dtf.serve.decode"]))
+            == tiny_engine.counters["decode_steps"] - steps0)
     # five requests of two chunks: every chunk dispatches, the last of
     # each request reads its first token back
     assert len(by_name["dtf.serve.prefill_chunk"]) == 10
